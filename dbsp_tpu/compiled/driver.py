@@ -44,30 +44,40 @@ from dbsp_tpu.compiled.compiler import (CompiledHandle, CompiledOverflow,
 logger = logging.getLogger(__name__)
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
+#: where compiled programs persist when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed path inside the checkout (the path is part of the cache
+#: key's environment, so a directory that moves never hits)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_bench_cache")
+
+
+def enable_compile_cache() -> str:
     """Wire JAX's persistent compilation cache for compiled pipelines.
 
-    ``path`` (or env ``DBSP_TPU_COMPILE_CACHE_DIR``) names an on-disk cache
-    directory; every XLA program the engine traces (step programs, scan
-    chunks, drain kernels) is serialized there and reused across process
-    restarts — a q4 warmup measured 37 s cold against a 3.1 s measured
-    window (BENCH r05), and all of it is retrace/recompile that a warm
-    cache eliminates. No-op (returns None) when unset, so default deploys
-    keep JAX's stock behavior. Thresholds are zeroed so every program is
-    cached: engine programs are many and individually small."""
-    path = path or os.environ.get("DBSP_TPU_COMPILE_CACHE_DIR")
-    if not path:
-        return None
+    One rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    keeps its cache there and no directory is set in code; where it is
+    not, the cache lives at :data:`DEFAULT_COMPILE_CACHE_DIR`. Every XLA
+    program the engine traces (step programs, scan chunks, drain kernels)
+    is serialized there and reused across process restarts — a cold served
+    run is mostly compilation, and all of it is retrace/recompile that a
+    warm cache eliminates. Thresholds are zeroed so every program is
+    cached: engine programs are many and individually small. Returns the
+    directory in use."""
     import jax
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:  # knob renamed/absent on this jax version
-            logger.debug("compile-cache knob %s unavailable", knob)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        if jax.config.jax_compilation_cache_dir != path:
+            from jax.experimental.compilation_cache import compilation_cache
+
+            jax.config.update("jax_compilation_cache_dir", path)
+            # JAX decides once per process whether the cache is in use, at
+            # its first compile: make it decide again
+            compilation_cache.reset_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
@@ -84,7 +94,7 @@ class CompiledCircuitDriver:
 
         self.host_handle = handle
         self.circuit = handle.circuit
-        enable_compile_cache()  # DBSP_TPU_COMPILE_CACHE_DIR, if set
+        enable_compile_cache()
         self.ch = compiled or compile_circuit(handle)
         self._tick = 0
         self.validate_every = max(1, validate_every if validate_every
@@ -132,7 +142,17 @@ class CompiledCircuitDriver:
         (at the validation cadence) validate, grow + exact replay of the
         retained interval on overflow, maintain, and deliver the buffered
         outputs to the host output operators."""
-        feeds: Dict = {op: drain() for op, drain in self._inputs}
+        from dbsp_tpu.circuit.runtime import Runtime
+
+        # the drain runs under the circuit's runtime like the host handle's
+        # step (CircuitHandle.step): on a worker mesh ZSetInput.eval reads
+        # it to key-hash-shard the tick's batch, and the serving thread has
+        # no current runtime of its own
+        prev = Runtime._swap(self.host_handle.runtime)
+        try:
+            feeds: Dict = {op: drain() for op, drain in self._inputs}
+        finally:
+            Runtime._swap(prev)
         spans = self.spans
         if spans is not None:
             spans.begin(f"tick[{self._tick}]", cat="step")

@@ -10,16 +10,10 @@ from __future__ import annotations
 
 import numpy as np
 import jax
+from jax import shard_map  # noqa: F401 — re-exported to the SPMD builders
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 WORKER_AXIS = "workers"
-
-# version compat: shard_map graduated from jax.experimental to the jax
-# top level; support both so multi-worker circuits run on either jax
-try:
-    from jax import shard_map  # noqa: F401  (jax >= 0.6)
-except ImportError:  # pragma: no cover — depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 def make_mesh(workers: int) -> Mesh:

@@ -95,11 +95,23 @@ def spawn_child(cfg: dict, cfg_path: str) -> "subprocess.Popen":
         os.path.abspath(dbsp_tpu.__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-    return subprocess.Popen(
-        [sys.executable, "-m", "dbsp_tpu.testing.faults", "--serve",
-         cfg_path],
-        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
-        text=True)
+    # stderr goes to a FILE beside the config, never a pipe: nobody drains
+    # a pipe while the child runs, and a child that logs more than the
+    # pipe holds (XLA warns once per stale compile-cache entry, ~3 KB
+    # each) blocks in write() forever — read it back with child_stderr()
+    with open(cfg_path + ".stderr", "w") as errf:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "dbsp_tpu.testing.faults", "--serve",
+             cfg_path],
+            env=env, stdout=subprocess.DEVNULL, stderr=errf)
+    proc.stderr_path = errf.name
+    return proc
+
+
+def child_stderr(proc: "subprocess.Popen") -> str:
+    """What a :func:`spawn_child` child wrote to stderr so far."""
+    with open(proc.stderr_path, errors="replace") as f:
+        return f.read()
 
 
 def read_status(status_path: str) -> Optional[dict]:
@@ -120,7 +132,7 @@ def wait_for_tick(status_path: str, tick: int, proc=None,
         if st is not None and st.get("tick", -1) >= tick:
             return st
         if proc is not None and proc.poll() is not None:
-            err = proc.stderr.read() if proc.stderr else ""
+            err = child_stderr(proc)
             raise RuntimeError(
                 f"pipeline child exited rc={proc.returncode} before tick "
                 f"{tick}: {err[-2000:]}")
@@ -143,7 +155,7 @@ def run_child(cfg: dict, cfg_path: str, timeout_s: float = 600.0) -> dict:
         proc.kill()
         raise
     if rc != 0:
-        err = proc.stderr.read() if proc.stderr else ""
+        err = child_stderr(proc)
         raise RuntimeError(f"pipeline child failed rc={rc}: {err[-2000:]}")
     st = read_status(cfg["status_path"])
     if st is None or not st.get("done"):

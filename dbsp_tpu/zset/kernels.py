@@ -87,18 +87,31 @@ def native_kernel(kernel: str) -> bool:
     return native_merge.available() and native_merge.kernel_enabled(kernel)
 
 
-# The DBSP_TPU_PALLAS spellings that force the Pallas kernels ON even off
-# an accelerator backend — the ONE definition shared by the dispatch
-# pre-checks here/in cursor.py and pallas_kernels.enabled(), so the
-# grammar cannot drift between the cheap check and the real one.
+# The DBSP_TPU_PALLAS spellings that select the Pallas kernels on the CPU
+# backend, where they run under the Pallas interpreter — the ONE definition
+# shared by the dispatch pre-checks here/in cursor.py and
+# pallas_kernels.enabled(), so the grammar cannot drift between the cheap
+# check and the real one.
 PALLAS_FORCE_ON = ("1", "on", "interpret")
+
+# The Pallas programs the TPU's compiler accepts, by dispatch name: off the
+# CPU backend the dispatch selects exactly these and every other kernel
+# takes its plain-XLA formulation. None today — compiled for a described
+# v5e with interpret=False, probe_ladder / join_ladder / gather_ladder are
+# refused for their (1, 1)-of-(K, 1) and (1, cap)-of-(K, cap) block shapes
+# (last two block dims must divide by 8 and 128 or equal the array's), and
+# rank_merge / segment_reduce for their int64 operands ("64-bit types are
+# not supported"). tests/test_tpu_compile.py holds every name to the
+# compiler's verdict: listed here <=> it compiles.
+PALLAS_TPU_COMPILED: frozenset = frozenset()
 
 
 def pallas_requested() -> bool:
     """Cheap pre-check for the Pallas dispatch branch WITHOUT importing
-    the pallas module (not free on CPU cold start): an accelerator
-    backend, or an explicit DBSP_TPU_PALLAS force-on. The full gate
-    (including the force-off spellings and dtype support) lives in
+    the pallas module (not free on CPU cold start): an accelerator backend
+    with at least one program its compiler accepts, or an explicit
+    DBSP_TPU_PALLAS force-on on the CPU. The full gate (per-kernel
+    selection, the force-off spellings and dtype support) lives in
     ``pallas_kernels.use_pallas`` — this only decides whether that module
     is worth importing."""
     import os
@@ -106,7 +119,7 @@ def pallas_requested() -> bool:
     import jax
 
     if jax.default_backend() != "cpu":
-        return True
+        return bool(PALLAS_TPU_COMPILED)
     return os.environ.get("DBSP_TPU_PALLAS", "").strip().lower() in \
         PALLAS_FORCE_ON
 
@@ -144,18 +157,109 @@ def sentinel_fill(shape, dtype) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+# Rows per ``lax.sort`` call on accelerators. XLA:TPU's compile time for a
+# multi-operand int64 sort climbs steeply with the row count (a 5-column
+# NEXmark row: 1.4 s at 2,048 rows, 7.3 s at 4,096, 26 s at 8,192, minutes at
+# 65,536 — compiled for a described v5e), while the rank merge of sorted runs
+# is probes + gathers and compiles in seconds at any size.
+SORT_CHUNK_ROWS = 2048
+
+
 def sort_rows(cols: Sequence[jnp.ndarray], payload: Sequence[jnp.ndarray]
               ) -> Tuple[Tuple[jnp.ndarray, ...], Tuple[jnp.ndarray, ...]]:
     """Stable ascending lexicographic sort by ``cols``; ``payload`` rides along.
 
     Zero-column rows (unit-keyed Z-sets, e.g. a global COUNT(*)) are a valid
     degenerate case: every row is equal, nothing to sort.
+
+    On accelerators a sort of more than :data:`SORT_CHUNK_ROWS` rows runs as
+    a merge sort (:func:`_sort_rows_chunked`) — same result bit for bit.
     """
     if not cols:
         return (), tuple(payload)
     ops = (*cols, *payload)
-    out = lax.sort(ops, num_keys=len(cols), is_stable=True)
+    if cols[0].ndim == 1 and cols[0].shape[0] > SORT_CHUNK_ROWS and \
+            jax.default_backend() != "cpu":
+        out = _sort_rows_chunked(ops, len(cols), SORT_CHUNK_ROWS)
+    else:
+        out = lax.sort(ops, num_keys=len(cols), is_stable=True)
     return tuple(out[: len(cols)]), tuple(out[len(cols):])
+
+
+def _pad_last(dtype):
+    """A value that a stable ascending ``lax.sort`` leaves at the very end:
+    the dtype's greatest under the sort's total order (NaN for floats)."""
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.array(jnp.nan, dtype)
+    return sentinel_for(dtype)
+
+
+def _sort_rows_chunked(ops: Sequence[jnp.ndarray], num_keys: int,
+                       chunk: int) -> Tuple[jnp.ndarray, ...]:
+    """Stable lexicographic sort of 1-D ``ops`` by their first ``num_keys``
+    operands: sorts of at most ``chunk`` rows (one ``lax.map`` body), then
+    a bottom-up merge sort over the sorted runs in which every level is the
+    SAME program — a ``fori_loop`` over levels whose body merges all
+    neighbouring run pairs at once by binary-search ranks, one scatter of
+    row numbers and a gather per operand. The compiler sees one small sort
+    and one merge body whatever the row count. Bit-identical to
+    ``lax.sort(..., is_stable=True)``."""
+    n = ops[0].shape[0]
+    levels = max(0, -(-n // chunk) - 1).bit_length()  # runs = 2**levels
+    runs = 1 << levels
+    size = -(-n // runs)
+    total = runs * size
+    if total > n:
+        # pad rows compare >= every real row and sit after them in the
+        # input, so stability keeps them last: the first n rows are the
+        # stable sort of the real rows
+        ops = [jnp.concatenate([o, jnp.full((total - n,),
+                                            _pad_last(o.dtype))])
+               for o in ops]
+    ops = lax.map(
+        lambda row: tuple(lax.sort(row, num_keys=num_keys, is_stable=True)),
+        tuple(o.reshape(runs, size) for o in ops))
+    ops = tuple(o.reshape(total) for o in ops)
+    g = jnp.arange(total, dtype=jnp.int32)
+
+    def merge_level(level, ops):
+        s = jnp.int32(size) << level           # rows per sorted run
+        run = g // s
+        first = (run & 1) == 0                 # row of a pair's first run
+        other = (run ^ 1) * s                  # where the pair's other run starts
+        keys = ops[:num_keys]
+
+        # cross-rank of every row in its pair's other run — the stable
+        # position map of merge_sorted_cols: a first-run row goes after
+        # the other run's rows strictly below it, a second-run row after
+        # those at or below it
+        def halve(_, lohi):
+            lo, hi = lohi
+            active = lo < hi
+            mid = (lo + hi) >> 1
+            at = other + jnp.minimum(mid, s - 1)
+            go_right = jnp.where(
+                first, _lex_le_rows(keys, at, keys, strict=True),
+                _lex_le_rows(keys, at, keys, strict=False))
+            return (jnp.where(active & go_right, mid + 1, lo),
+                    jnp.where(active & ~go_right, mid, hi))
+
+        # inside shard_map the rows vary per worker: the loop carry must
+        # enter with the varying type it leaves with
+        vma = tuple(jax.typeof(ops[0]).vma)
+        bounds = (jnp.zeros((total,), jnp.int32),
+                  jnp.broadcast_to(s, (total,)))
+        if vma:
+            bounds = tuple(lax.pcast(b, vma, to="varying") for b in bounds)
+        # s + 1 candidate ranks [0, s] => bit_length(s) halvings
+        rank, _ = lax.fori_loop(0, 32 - lax.clz(s), halve, bounds)
+        pos = (run >> 1) * (2 * s) + (g - run * s) + rank
+        src = jnp.zeros((total,), jnp.int32).at[pos].set(
+            g, unique_indices=True)
+        return tuple(o[src] for o in ops)
+
+    ops = lax.fori_loop(0, levels, merge_level, ops)
+    return tuple(o[:n] for o in ops)
 
 
 def _col_eq(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -36,37 +36,13 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _ffi_module():
-    """The XLA FFI surface for this jax version.
-
-    ``jax.ffi`` (>= 0.5) and ``jax.extend.ffi`` (0.4.35-0.4.38) expose the
-    SAME API (``ffi_call`` returning a callable, ``register_ffi_target``,
-    ``pycapsule``, ``include_dir``); only the module moved. Anything older
-    has a different registration ABI and stays gated off."""
-    if hasattr(jax, "ffi"):
-        return jax.ffi
-    try:
-        from jax.extend import ffi as xffi
-    except ImportError:
-        return None
-    # the modern API landed in jax.extend.ffi before moving to jax.ffi;
-    # require the exact entry points this module drives
-    if all(hasattr(xffi, n) for n in (
-            "ffi_call", "register_ffi_target", "pycapsule", "include_dir")):
-        return xffi
-    return None
-
-
-_FFI = _ffi_module()
+_FFI = jax.ffi
 
 
 def _vma_of(x):
-    """Varying-manual-axes tag of a traced value (None before jax grew vma
-    tracking — there is nothing to re-tag on those versions)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return None
-    return getattr(typeof(x), "vma", None)
+    """Varying-manual-axes tag of a traced value (empty outside
+    shard_map)."""
+    return jax.typeof(x).vma
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))
@@ -114,12 +90,6 @@ KERNELS = ("merge", "consolidate", "probe", "probe_ladder", "expand",
 def _build() -> str:
     global _build_error
     if _build_error is not None:
-        raise RuntimeError(_build_error)
-    if _FFI is None:
-        # pre-0.4.35 jax has a different registration ABI; gate the whole
-        # native route off rather than drive an untested bridge (kernels
-        # fall back to the XLA sort path)
-        _build_error = "XLA FFI API unavailable in this jax version"
         raise RuntimeError(_build_error)
     if not os.path.exists(_SO) or (
             os.path.getmtime(_SO) < os.path.getmtime(_SRC)):

@@ -50,7 +50,7 @@ def _kill_and_restore(mode: str, tmp_path) -> None:
         faults.wait_for_tick(st_k, KILL_AT, proc=p_kill, timeout_s=420)
         faults.kill9(p_kill)  # SIGKILL: no flush, no atexit
         rc = p_ref.wait(timeout=420)
-        assert rc == 0, p_ref.stderr.read()[-2000:]
+        assert rc == 0, faults.child_stderr(p_ref)[-2000:]
     finally:
         for p in (p_ref, p_kill):
             if p.poll() is None:

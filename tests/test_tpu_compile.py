@@ -1,0 +1,182 @@
+"""Ask the TPU's compiler, without a TPU: the main path's kernels compiled
+for one DESCRIBED v5e chip (``jax.experimental.topologies``; nothing runs).
+
+Guards what interpret mode and the CPU backend cannot: that the plain-XLA
+kernels of the served q4 path lower at a 40,000-row delta (capacity bucket
+65,536), that the large sort is the chunked merge sort there, and that the
+dispatch selects on a TPU exactly the Pallas programs its compiler accepts
+(``kernels.PALLAS_TPU_COMPILED`` <=> compiles).
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process at a time may load the TPU's library, and every xdist
+worker imports this file); every compile runs in the test's own process.
+The code under test picks its branch from ``jax.default_backend()``, which
+still says ``cpu`` here — the ``tpu_dispatch`` fixture steers it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dbsp_tpu.zset import cursor, kernels, pallas_kernels
+from dbsp_tpu.zset.batch import Batch
+
+I64, I32 = jnp.int64, jnp.int32
+CAP = 65_536  # bucket_cap of a 40,000-event tick's bid delta
+# bids row: key (auction) + vals (bidder, price, channel, date_time)
+BID = (I64, I64, I64, I32, I64)
+PALLAS_PROGRAMS = ("probe_ladder", "join_ladder", "gather_ladder",
+                   "segment_reduce", "rank_merge")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip (it warns and recompiles)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def tpu_dispatch(monkeypatch):
+    """Steer the backend-keyed dispatch to its accelerator branches."""
+    monkeypatch.delenv("DBSP_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture
+def compile_for(one_chip, no_persistent_cache, tpu_dispatch):
+    def shape(n, dtype=I64):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    def batch(n, key_dts, val_dts):
+        return Batch(tuple(shape(n, d) for d in key_dts),
+                     tuple(shape(n, d) for d in val_dts), shape(n),
+                     runs=(n,))
+
+    def compile_(fn, *args):
+        return jax.jit(fn).lower(*args).compile()
+
+    compile_.shape, compile_.batch = shape, batch
+    return compile_
+
+
+def _case(name, c):
+    """(fn, args) of one main-path kernel at the 40,000-event tick's sizes."""
+    rows = tuple(c.shape(CAP, d) for d in BID)
+    ladder = (CAP, 4 * CAP, 16 * CAP)  # l0 / l1 / tail of a 1M-row trace
+    if name == "consolidate":
+        return kernels.consolidate_cols, (rows, c.shape(CAP))
+    if name in ("merge_sorted", "rank_merge"):
+        return kernels.merge_sorted_cols, (rows, c.shape(CAP),
+                                           rows, c.shape(CAP))
+    if name == "lex_probe":
+        return (lambda t, q: kernels.lex_probe(t, q, "left")), (
+            (c.shape(16 * CAP), c.shape(16 * CAP)),
+            (c.shape(CAP), c.shape(CAP)))
+    if name == "probe_ladder":
+        return (lambda ts, q: cursor.lex_probe_ladder(ts, q, "left")), (
+            [(c.shape(n),) for n in ladder], (c.shape(CAP),))
+    if name == "join_ladder":  # q4-join: bids delta x auctions trace
+        def fn(k, bv, av):
+            return (k[0], av[0]), (bv[1], bv[3], av[1], av[2])
+        return (lambda d, lv: cursor.join_ladder(d, lv, 1, fn, 2 * CAP)), (
+            c.batch(CAP, (I64,), (I64, I64, I32, I64)),
+            [c.batch(n, (I64,), (I64, I64, I64)) for n in ladder])
+    if name == "gather_ladder":  # q4-max: group gather over its trace
+        return (lambda qk, ql, lv: cursor.gather_ladder(
+            qk, ql, lv, 2 * CAP)), (
+            (c.shape(CAP), c.shape(CAP)), c.shape(CAP, jnp.bool_),
+            [c.batch(n, (I64, I64), (I64,)) for n in ladder])
+    if name == "segment_reduce":
+        from dbsp_tpu.operators.aggregate import segment_reduce
+        return (lambda v, w, seg: segment_reduce(
+            (("max", 0),), (v,), w, seg, CAP)), (
+            c.shape(2 * CAP), c.shape(2 * CAP), c.shape(2 * CAP, I32))
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name", ["consolidate", "merge_sorted", "lex_probe",
+                                  "join_ladder", "gather_ladder"])
+def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
+    fn, args = _case(name, compile_for)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    compiled = compile_for(fn, *args)
+    assert "tpu_custom_call" not in compiled.as_text()  # no Mosaic kernel
+    took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
+            if n > before.get(k, 0)}
+    assert took and {b for _, b in took} == {"xla"}, took
+
+
+def test_large_sort_is_chunked_on_tpu(compile_for):
+    """Off the CPU the consolidate's sort never hands XLA more than
+    SORT_CHUNK_ROWS rows at once (a 65,536-row, 5-column int64 sort takes
+    minutes to compile for this chip; the chunk takes seconds)."""
+    cols = tuple(compile_for.shape(CAP, d) for d in BID)
+    text = jax.jit(lambda c, w: kernels.sort_rows(c, (w,))).lower(
+        cols, compile_for.shape(CAP)).as_text()
+    assert text.count("stablehlo.sort") == 1
+
+    def operands(n):  # the sort's operand types, as its signature prints them
+        return ", ".join(f"tensor<{n}x{'i32' if d == I32 else 'i64'}>"
+                         for d in (*BID, I64))
+
+    assert f"({operands(kernels.SORT_CHUNK_ROWS)}) ->" in text
+    assert f"({operands(CAP)}) ->" not in text
+
+
+@pytest.mark.parametrize("name", PALLAS_PROGRAMS)
+def test_pallas_program_selected_on_tpu_iff_it_compiles(name, compile_for,
+                                                        monkeypatch):
+    """The static tier decision against the compiler's verdict: a program
+    is listed in PALLAS_TPU_COMPILED exactly when Mosaic accepts it, and
+    the dispatch selects it on a TPU exactly when it is listed."""
+    ints = (jnp.zeros((8,), I64),)
+    listed = name in kernels.PALLAS_TPU_COMPILED
+    assert pallas_kernels.use_pallas(name, ints) == listed
+    assert not pallas_kernels.interpret_mode()  # never interpreted off-CPU
+    # compile the program itself, whatever the dispatch would pick
+    monkeypatch.setattr(kernels, "PALLAS_TPU_COMPILED",
+                        frozenset(PALLAS_PROGRAMS))
+    assert pallas_kernels.use_pallas(name, ints)
+    fn, args = _case(name, compile_for)
+    try:
+        text = compile_for(fn, *args).as_text()
+    except Exception as e:  # noqa: BLE001 — the compiler's refusal
+        accepted, why = False, f"{type(e).__name__}: {e}"
+    else:
+        accepted, why = "tpu_custom_call" in text, "no Mosaic kernel in HLO"
+    assert accepted == listed, (
+        f"{name}: listed={listed} but the v5e compiler says "
+        f"accepted={accepted} ({why[:300]}) — update "
+        "kernels.PALLAS_TPU_COMPILED")
+
+
+def test_interpreter_is_refused_off_the_cpu(tpu_dispatch, monkeypatch):
+    monkeypatch.setenv("DBSP_TPU_PALLAS", "interpret")
+    with pytest.raises(RuntimeError, match="CPU backend only"):
+        pallas_kernels.enabled()
+    monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
+    assert not pallas_kernels.enabled()
+    assert kernels.pallas_requested() == bool(kernels.PALLAS_TPU_COMPILED)
