@@ -1,0 +1,9 @@
+"""The end-to-end ``delta_age_p95_s`` arithmetic, reported per layer in the
+cells where it cannot be held to a bound (q3: one slow tick in a run of 3 s
+ticks moves the p95 by 40 %; PERF.md, section 2).
+Layer: tick (io/controller.py, compiled/driver.py)."""
+
+
+def read(ctx):
+    ages = ctx["measures"].delta_ages(ctx["run"])
+    return None if not ages else ctx["measures"].percentile(ages, 95)
