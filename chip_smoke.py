@@ -10,6 +10,13 @@ pushed events (plain Python below — it shares no code with the engine).
 
     python chip_smoke.py              # one chip, 40,000-event ticks
     python chip_smoke.py --chips 4    # the key-hash-sharded path, 4 workers
+    python chip_smoke.py --profile-dir DIR --profile-ticks 2   # keeps a trace
+
+``--profile-dir DIR --profile-ticks N`` runs the last N ticks (warm ones)
+under a ``jax.profiler`` session and keeps its trace in DIR, with the span
+ring's Chrome trace beside it (``DIR/spans.json``):
+``tools/trace_scopes.py DIR --spans DIR/spans.json`` reads both. Scopes show
+only in programs compiled by this tree: use a fresh compile cache directory.
 
 One process; it starts no child. Without a TPU it exits non-zero and never
 prints ``"ok": true`` — there is no CPU fallback (tests call
@@ -29,6 +36,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 import urllib.request
@@ -109,12 +117,14 @@ class _CompileMeter:
 
 
 def run_served(ticks: int, events_per_tick: int, seed: int,
-               workers: int = 1, emit=_emit) -> dict:
+               workers: int = 1, emit=_emit, profile_dir: str | None = None,
+               profile_ticks: int = 0) -> dict:
     """Serve q4 for ``ticks`` ticks of ``events_per_tick`` events and check
     the served view against :func:`q4_recompute`. Runs on whatever backend
     JAX has (the device check is ``main``'s). Emits fact lines through
     ``emit`` and returns the summary (``ok`` is the verdict); raises on any
-    phase's failure."""
+    phase's failure. With ``profile_dir`` the last ``profile_ticks`` ticks
+    run under a profiler session whose trace is kept there."""
     import jax
     import jax.numpy as jnp
 
@@ -175,6 +185,12 @@ def run_served(ticks: int, events_per_tick: int, seed: int,
             tick_s, tick_step_programs = [], []
             presized = False
             for t in range(ticks):
+                if profile_dir and t == ticks - profile_ticks:
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0  # no Python frames: small
+                    opts.host_tracer_level = 1    # annotations only
+                    jax.profiler.start_trace(profile_dir,
+                                             profiler_options=opts)
                 cols = gen.generate(t * events_per_tick,
                                     (t + 1) * events_per_tick)
                 p, a, b = cols["persons"], cols["auctions"], cols["bids"]
@@ -217,6 +233,11 @@ def run_served(ticks: int, events_per_tick: int, seed: int,
             view = _http(base + "/view/q4")
             got = {(r[0], r[1]): r[2] for r in view["rows"]}
         finally:
+            if profile_dir and 0 < profile_ticks <= ticks:
+                jax.profiler.stop_trace()
+                with open(os.path.join(profile_dir, "spans.json"),
+                          "w") as f:
+                    f.write(srv.spans.to_json())
             srv.stop()
             ctl.stop()
     meter.close()
@@ -276,6 +297,9 @@ def main(argv=None) -> int:
                     help="4 = only the key-hash-sharded path on four chips")
     ap.add_argument("--ticks", type=int, default=DEFAULT_TICKS)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--profile-dir", default=None,
+                    help="keep a profiler trace of the last ticks here")
+    ap.add_argument("--profile-ticks", type=int, default=2)
     args = ap.parse_args(argv)
 
     import jax
@@ -291,7 +315,8 @@ def main(argv=None) -> int:
               f"{len(devices)} device(s)", file=sys.stderr)
         return 2
     summary = run_served(args.ticks, EVENTS_PER_TICK, args.seed,
-                         workers=args.chips)
+                         workers=args.chips, profile_dir=args.profile_dir,
+                         profile_ticks=args.profile_ticks)
     if not summary["ok"]:
         print("chip_smoke: served view != recomputation", file=sys.stderr)
         return 1

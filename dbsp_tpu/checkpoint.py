@@ -172,6 +172,7 @@ STATE_SCHEMA: Dict[str, Dict[str, str]] = {
         "ch": "config",           # its own persisted parts listed above
         "validate_every": "config",
         "_inputs": "config",
+        "input_labels": "config",  # span arg per input, from the catalog
         "_outputs": "config",
         "_snap": "derived",       # rebuilt from restored state on resume
         "_out_buffer": "derived",  # rebuilt by replaying _retained
@@ -210,6 +211,7 @@ STATE_SCHEMA: Dict[str, Dict[str, str]] = {
                                     # manifest ("read_epoch")
         "e2e": "runtime",   # delta-trace contexts die with the process:
                             # a restored pipeline mints fresh trace ids
+        "spans": "runtime",  # this process's span ring — trace surface
     },
     "_InputEndpoint": {
         "total_records": "persisted",   # consumed high-water mark: the

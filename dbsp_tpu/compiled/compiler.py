@@ -200,7 +200,9 @@ def _drain_pair(receiver: Batch, source: Batch, cap: int):
     """One maintenance drain as a single jitted dispatch (eager Batch ops
     cost ~10 dispatches each; this runs every few validation intervals on
     every leveled trace, so dispatch overhead was measurable)."""
-    return receiver.merge_with(source).with_cap(cap), source.masked(False)
+    with jax.named_scope("maintain.drain"):
+        return (receiver.merge_with(source).with_cap(cap),
+                source.masked(False))
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(0, 1))
@@ -218,20 +220,22 @@ def _drain_slice(receiver: Batch, source: Batch, n, cap: int):
     the last axis of either layout. On a sharded level ``n`` applies
     per-worker slice (lives are max-worker counts, the same convention
     capacity bucketing uses)."""
-    idx = jnp.arange(source.cap, dtype=jnp.int32)
-    take = source.masked(idx < n)
-    rolled = Batch(
-        tuple(jnp.roll(k, -n, axis=-1) for k in source.keys),
-        tuple(jnp.roll(v, -n, axis=-1) for v in source.vals),
-        jnp.roll(source.weights, -n, axis=-1))
-    # positions that wrapped around hold the taken prefix — dead them;
-    # rolled live rows occupy [0, live - n), already packed at the front.
-    # The remainder IS still one consolidated run (sorted suffix, packed,
-    # sentinel tail) — tag it so the level's pytree aux stays IDENTICAL
-    # across drains; an aux flip here would retrace the whole step program
-    # on the next tick (run metadata is static data).
-    rest = rolled.masked(idx < source.cap - n).tagged((source.cap,))
-    return receiver.merge_with(take).with_cap(cap), rest
+    with jax.named_scope("maintain.drain"):
+        idx = jnp.arange(source.cap, dtype=jnp.int32)
+        take = source.masked(idx < n)
+        rolled = Batch(
+            tuple(jnp.roll(k, -n, axis=-1) for k in source.keys),
+            tuple(jnp.roll(v, -n, axis=-1) for v in source.vals),
+            jnp.roll(source.weights, -n, axis=-1))
+        # positions that wrapped around hold the taken prefix — dead
+        # them; rolled live rows occupy [0, live - n), already packed at
+        # the front. The remainder IS still one consolidated run (sorted
+        # suffix, packed, sentinel tail) — tag it so the level's pytree aux
+        # stays IDENTICAL across drains; an aux flip here would retrace the
+        # whole step program on the next tick (run metadata is static
+        # data).
+        rest = rolled.masked(idx < source.cap - n).tagged((source.cap,))
+        return receiver.merge_with(take).with_cap(cap), rest
 
 
 class CompiledHandle:
@@ -853,7 +857,11 @@ class CompiledHandle:
         for cn in self.cnodes:
             ins = [values[i] for i in cn.node.inputs]
             st = states.get(str(cn.node.index))
-            st2, out = cn.eval(ctx, st, ins)
+            # the scope names every operation of this node in the lowered
+            # program and the device trace: n<index>.<CNode class>
+            with jax.named_scope(
+                    f"n{cn.node.index}.{type(cn).__name__}"):
+                st2, out = cn.eval(ctx, st, ins)
             if st2 is not None:
                 new_states[str(cn.node.index)] = st2
             values[cn.node.index] = out
@@ -1121,12 +1129,16 @@ class CompiledHandle:
         jax.block_until_ready(self.states)
 
     # -- validation / growth -------------------------------------------------
-    def validate(self) -> None:
+    def validate(self, spans=None) -> None:
         """ONE device->host fetch: check every capacity requirement recorded
-        since the last validation. Raises :class:`CompiledOverflow`."""
+        since the last validation. Raises :class:`CompiledOverflow`.
+        ``spans`` (obs.SpanRecorder) gets the fetch — the wait for the step
+        program plus the transfer — as ``tick.device_wait``."""
         if self._req is None or not self._checks:
             return
-        req = np.asarray(jax.device_get(self._req))
+        with (spans.span("tick.device_wait", "tick") if spans is not None
+              else contextlib.nullcontext()):
+            req = np.asarray(jax.device_get(self._req))
         items = []
         for (cn, key), r in zip(self._checks, req):
             cn.note_requirement(key, int(r))

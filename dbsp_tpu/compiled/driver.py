@@ -32,7 +32,6 @@ tests) sees compiled and host pipelines identically.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import time
@@ -40,6 +39,7 @@ from typing import Dict, List, Optional, Tuple
 
 from dbsp_tpu.compiled.compiler import (CompiledHandle, CompiledOverflow,
                                         compile_circuit)
+from dbsp_tpu.obs.tracing import default_recorder
 
 logger = logging.getLogger(__name__)
 
@@ -85,7 +85,6 @@ class CompiledCircuitDriver:
     """Controller-facing driver over a compiled circuit (see module doc)."""
 
     mode = "compiled"
-    spans = None  # optional obs.SpanRecorder (set by CompiledInstrumentation)
 
     def __init__(self, handle, compiled: Optional[CompiledHandle] = None,
                  validate_every: Optional[int] = None):
@@ -96,6 +95,9 @@ class CompiledCircuitDriver:
         self.circuit = handle.circuit
         enable_compile_cache()
         self.ch = compiled or compile_circuit(handle)
+        # the phase spans of a tick land here (obs.SpanRecorder; a
+        # PipelineObs hands its own through CompiledInstrumentation)
+        self.spans = default_recorder()
         self._tick = 0
         self.validate_every = max(1, validate_every if validate_every
                                   is not None else int(os.environ.get(
@@ -103,11 +105,17 @@ class CompiledCircuitDriver:
         # (op, drain_fn): ZSetInput feeds its tick batch; UpsertInput feeds
         # the raw command batch its compiled node diffs against state
         self._inputs = []
+        # op -> the ``table`` arg of its ``tick.build_inputs`` span; the
+        # controller puts the catalog's names in
+        self.input_labels: Dict = {}
         for cn in self.ch.cnodes:
             if isinstance(cn.op, ZSetInput):
                 self._inputs.append((cn.op, cn.op.eval))
             elif isinstance(cn.op, UpsertInput):
                 self._inputs.append((cn.op, cn.op.take_commands))
+            else:
+                continue
+            self.input_labels[cn.op] = f"n{cn.node.index}"
         self._outputs = [(cn.node.index, cn.op) for cn in self.ch.cnodes
                          if isinstance(cn.op, OutputOperator)]
         # interval state: snapshot at interval start, retained (tick, feeds)
@@ -141,36 +149,38 @@ class CompiledCircuitDriver:
         """One serving tick: drain input buffers -> compiled step ->
         (at the validation cadence) validate, grow + exact replay of the
         retained interval on overflow, maintain, and deliver the buffered
-        outputs to the host output operators."""
+        outputs to the host output operators. Each phase is a ``tick.*``
+        span, a child of the controller's ``tick`` when one drives it."""
         from dbsp_tpu.circuit.runtime import Runtime
 
         # the drain runs under the circuit's runtime like the host handle's
         # step (CircuitHandle.step): on a worker mesh ZSetInput.eval reads
         # it to key-hash-shard the tick's batch, and the serving thread has
         # no current runtime of its own
+        spans = self.spans
         prev = Runtime._swap(self.host_handle.runtime)
         try:
-            feeds: Dict = {op: drain() for op, drain in self._inputs}
+            feeds: Dict = {}
+            for op, drain in self._inputs:
+                with spans.span("tick.build_inputs", "tick",
+                                args={"table": self.input_labels[op]}):
+                    feeds[op] = drain()
         finally:
             Runtime._swap(prev)
-        spans = self.spans
-        if spans is not None:
-            spans.begin(f"tick[{self._tick}]", cat="step")
         if not self._retained:
             # interval-start checkpoint; timed into host_overhead_ns like
             # run_ticks does, so serving pipelines feed the same phase
             # observability (obs histogram + flight recorder) as bench runs
-            h0 = time.perf_counter_ns()
-            self._snap = self.ch.snapshot()
-            self.ch.host_overhead_ns["snapshot"].append(
-                time.perf_counter_ns() - h0)
+            with spans.span("tick.snapshot", "tick") as sp:
+                self._snap = self.ch.snapshot()
+            self.ch.host_overhead_ns["snapshot"].append(sp.elapsed_ns)
             # the previous interval's snapshot is gone: zero-reference
             # cold blobs can be swept without endangering any replay
             self.ch._sweep_cold()
             self._interval_open_ts = time.time()
         self._retained.append((self._tick, feeds))
-        with (spans.span("compiled_step", cat="compiled") if spans
-              is not None else contextlib.nullcontext()):
+        with spans.span("tick.dispatch", "tick",
+                        args={"retraced": self.ch._step_jit is None}):
             self.ch.step(tick=self._tick, feeds=feeds)
         # feeds are host-built program INPUTS (never donated), so the
         # retained references replay the identical batches after a grow
@@ -178,50 +188,56 @@ class CompiledCircuitDriver:
         self._tick += 1
         if len(self._retained) >= self.validate_every:
             self._flush()
-        if spans is not None:
-            spans.end(f"tick[{self._tick - 1}]")
 
     def _flush(self) -> None:
         """Validate the open interval; on overflow grow + replay the
         retained feeds from the interval-start snapshot (exact); then run
         a bounded maintenance slice and deliver outputs in tick order."""
         spans = self.spans
-        h0 = time.perf_counter_ns()
-        while True:
-            try:
-                self.ch.validate()
-                break
-            except CompiledOverflow as e:
-                self.ch.overflow_replays += 1
-                if spans is not None:
-                    spans.instant("overflow_replay", cat="compiled")
-                self.ch.grow(e)
-                self.ch.restore(self._snap)
-                self._out_buffer.clear()
-                for tick, feeds in self._retained:
-                    self.ch.step(tick=tick, feeds=feeds)
-                    self._out_buffer.append(dict(self.ch.last_outputs))
-        self.ch.host_overhead_ns["validate"].append(
-            time.perf_counter_ns() - h0)
-        h0 = time.perf_counter_ns()
-        self.ch.maintain()  # spine drains; dispatch-free when nothing due
-        self.ch.host_overhead_ns["maintain"].append(
-            time.perf_counter_ns() - h0)
-        for outputs in self._out_buffer:
-            for idx, out_op in self._outputs:
-                batch = outputs.get(idx)
-                if batch is not None:
-                    # deferred-to-sink consolidation (placement pass):
-                    # canonicalize at delivery so every host consumer
-                    # (HTTP readers, transports, to_dict tests) sees the
-                    # same batches as the eager-consolidate engine — the
-                    # ONE policy shared with CompiledHandle.output()
-                    canon = self.ch.canonicalize_sink(batch)
-                    if canon is not batch and \
-                            self.ch.last_outputs.get(idx) is batch:
-                        # share the canonical batch with output() readers
-                        self.ch.last_outputs[idx] = canon
-                    out_op.eval(canon)
+        with spans.span("tick.validate", "tick") as sp:
+            while True:
+                try:
+                    self.ch.validate(spans)
+                    break
+                except CompiledOverflow as e:
+                    self.ch.overflow_replays += 1
+                    with spans.span("tick.grow", "tick"):
+                        self.ch.grow(e)
+                    with spans.span("tick.replay", "tick",
+                                    args={"ticks": len(self._retained)}):
+                        self.ch.restore(self._snap)
+                        self._out_buffer.clear()
+                        for tick, feeds in self._retained:
+                            self.ch.step(tick=tick, feeds=feeds)
+                            self._out_buffer.append(
+                                dict(self.ch.last_outputs))
+        self.ch.host_overhead_ns["validate"].append(sp.elapsed_ns)
+        stats = self.ch.maintain_stats
+        drains0 = stats["drains"] + stats["partial_drains"]
+        rows0 = stats["rows_moved"]
+        with spans.span("tick.maintain", "tick") as sp:
+            self.ch.maintain()  # spine drains; dispatch-free when nothing due
+            sp.note(drains=stats["drains"] + stats["partial_drains"]
+                    - drains0, rows_moved=stats["rows_moved"] - rows0)
+        self.ch.host_overhead_ns["maintain"].append(sp.elapsed_ns)
+        with spans.span("tick.deliver", "tick"):
+            for outputs in self._out_buffer:
+                for idx, out_op in self._outputs:
+                    batch = outputs.get(idx)
+                    if batch is not None:
+                        # deferred-to-sink consolidation (placement pass):
+                        # canonicalize at delivery so every host consumer
+                        # (HTTP readers, transports, to_dict tests) sees
+                        # the same batches as the eager-consolidate engine
+                        # — the ONE policy shared with
+                        # CompiledHandle.output()
+                        canon = self.ch.canonicalize_sink(batch)
+                        if canon is not batch and \
+                                self.ch.last_outputs.get(idx) is batch:
+                            # share the canonical batch with output()
+                            # readers
+                            self.ch.last_outputs[idx] = canon
+                        out_op.eval(canon)
         self._out_buffer.clear()
         self._retained.clear()
         self._snap = None

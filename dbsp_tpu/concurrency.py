@@ -170,6 +170,9 @@ CONCURRENCY_SCHEMA: Dict[str, Dict[str, str]] = {
                     "own lock)",
         "read_plane": "immutable",
         "e2e": "immutable",
+        "spans": "gil-atomic: wired once by PipelineObs.attach_controller "
+                 "before start(); read-only afterwards (begin/end go "
+                 "through the recorder's own lock)",
     },
     "_InputEndpoint": {
         "name": "immutable",
@@ -208,6 +211,7 @@ CONCURRENCY_SCHEMA: Dict[str, Dict[str, str]] = {
         "controller": "immutable",
         "profiler": "immutable",
         "obs": "immutable",
+        "spans": "immutable",
         "analysis_findings": "immutable",
         "httpd": "immutable",
         "port": "immutable",
@@ -364,11 +368,10 @@ CONCURRENCY_SCHEMA: Dict[str, Dict[str, str]] = {
     "SpanRecorder": {
         "process": "immutable",
         "pid": "immutable",
+        "max_steps": "immutable",
         "_lock": "immutable",
         "_steps": "lock(_lock)",
         "_open": "lock(_lock)",
-        "_depth": "lock(_lock)",
-        "_threads": "lock(_lock)",
         "dropped_steps": "writelock(_lock)",
         "_dropped_counter": "gil-atomic: wired once by bind() during obs "
                             "attach, before any traffic; read-only "

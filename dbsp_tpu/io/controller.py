@@ -225,9 +225,20 @@ class Controller:
         # fleet-wide delta tracing (obs/tracing.py): every ingested batch
         # gets a trace context that flows push -> tick -> publish ->
         # changefeed -> replica -> read; DBSP_TPU_TRACE_E2E=0 disables.
-        from dbsp_tpu.obs.tracing import E2ETracer
+        from dbsp_tpu.obs.tracing import E2ETracer, default_recorder
 
         self.e2e = E2ETracer()
+        # the ``tick`` span and its controller-side phases land here
+        # (PipelineObs.attach_controller hands its own ring)
+        self.spans = default_recorder()
+        labels = getattr(handle, "input_labels", None)
+        if labels is not None:
+            # the compiled driver names its per-input spans by node; the
+            # catalog knows the tables
+            for name, col in self.catalog.inputs.items():
+                op = getattr(col.handle, "_op", None)
+                if op in labels:
+                    labels[op] = name
         _tsan_hook(self)
 
     # -- endpoint wiring ----------------------------------------------------
@@ -362,14 +373,15 @@ class Controller:
                                bytes=info["bytes"])
         return info
 
-    def _maybe_checkpoint_locked(self) -> None:  # holds: _step_lock
-        """Periodic-cadence hook on the circuit thread: a checkpoint
-        failure is recorded (flight + stats) but never takes the pipeline
-        down — serving continues at reduced durability."""
-        if not self.checkpoint_every or not self.checkpoint_dir:
-            return
-        if self.steps - self._last_ckpt_step < self.checkpoint_every:
-            return
+    def _checkpoint_due(self) -> bool:
+        return bool(self.checkpoint_every and self.checkpoint_dir) and \
+            self.steps - self._last_ckpt_step >= self.checkpoint_every
+
+    def _periodic_checkpoint_locked(self) -> None:  # holds: _step_lock
+        """Periodic-cadence hook on the circuit thread, run when
+        :meth:`_checkpoint_due`: a checkpoint failure is recorded (flight +
+        stats) but never takes the pipeline down — serving continues at
+        reduced durability."""
         try:
             self._checkpoint_locked()
         except Exception as e:  # noqa: BLE001 — durability is best-effort
@@ -590,49 +602,68 @@ class Controller:
 
     def step(self) -> None:
         """One controller-driven tick: drain buffers -> step -> emit outputs."""
+        self.spans.begin("step.lock_wait", "step")
         with self._step_lock:
+            self.spans.end("step.lock_wait")
             self._step_locked()
 
     def _step_locked(self) -> None:  # holds: _step_lock
-        t0 = time.perf_counter_ns()
-        # queue_wait ends for every batch stamped so far: contexts noted
-        # BEFORE this point have their rows in the buffers drained below
-        # (push sites append rows before stamping the context)
-        self.e2e.tick_begin()
-        with self._pushed_lock:
-            rows_in = self._pushed
-            self._pushed = 0  # this step consumes all pushed rows
-        for ep in self.inputs.values():
-            rows = ep.drain()
-            if rows:
-                ep.collection.push_rows(rows)
-                rows_in += len(rows)
-        self.handle.step()
-        self.steps += 1
-        rows_out = self._emit_outputs()
-        trace_ids = self.e2e.tick_end()
-        if not getattr(self.handle, "interval_open", False):
-            # validation publish: swap in immutable read-plane snapshots
-            # (host engine: every step; compiled: when the deferred-
-            # validation interval closed this tick). BEFORE the periodic
-            # checkpoint so a checkpoint captures this tick's publication.
-            self.read_plane.publish(tracer=self.e2e)
-        self._maybe_checkpoint_locked()
-        self._run_monitors()
-        # the tick record is stamped LAST so checkpoint writes and in-tick
-        # monitor work (everything inside the step lock) count toward the
-        # tick's wall latency — that is what a serving client waits on
-        tl = self.timeline
-        if tl is not None:
-            tl.note_tick(self.steps, time.perf_counter_ns() - t0,
-                         rows_in=rows_in, rows_out=rows_out,
-                         queue_depth=sum(ep.buffered()
-                                         for ep in self.inputs.values()),
-                         trace_ids=trace_ids)
+        """The ``tick`` span: opened before anything is drained, closed
+        when everything a client of ``/step`` waits on is done. Its start
+        is also the timeline's tick start — one clock reading for both."""
+        spans = self.spans
+        with spans.span("tick", "step", args={"tick": self.steps}) as tick:
+            # queue_wait ends for every batch stamped so far: contexts
+            # noted BEFORE this point have their rows in the buffers
+            # drained below (push sites append rows before stamping the
+            # context)
+            self.e2e.tick_begin()
+            with spans.span("tick.drain_endpoints", "tick"):
+                with self._pushed_lock:
+                    rows_in = self._pushed
+                    self._pushed = 0  # this step consumes all pushed rows
+                for ep in self.inputs.values():
+                    rows = ep.drain()
+                    if rows:
+                        ep.collection.push_rows(rows)
+                        rows_in += len(rows)
+            self.handle.step()
+            self.steps += 1
+            with spans.span("tick.emit_outputs", "tick"):
+                rows_out = self._emit_outputs()
+            trace_ids = self.e2e.tick_end()
             if not getattr(self.handle, "interval_open", False):
-                # this step's results validated and published (host engine:
-                # every step; compiled: when no deferred interval remains)
-                tl.note_visible(list(self.catalog.outputs))
+                # validation publish: swap in immutable read-plane
+                # snapshots (host engine: every step; compiled: when the
+                # deferred-validation interval closed this tick). BEFORE
+                # the periodic checkpoint so a checkpoint captures this
+                # tick's publication.
+                with spans.span("tick.publish", "tick"):
+                    self.read_plane.publish(tracer=self.e2e)
+            if self._checkpoint_due():
+                with spans.span("tick.checkpoint", "tick"):
+                    self._periodic_checkpoint_locked()
+            if self._monitors:
+                with spans.span("tick.monitors", "tick"):
+                    self._run_monitors()
+            tick.note(rows_in=rows_in, rows_out=rows_out,
+                      batches=trace_ids)
+            # the tick record is stamped LAST so checkpoint writes and
+            # in-tick monitor work (everything inside the step lock) count
+            # toward the tick's wall latency — that is what a serving
+            # client waits on
+            tl = self.timeline
+            if tl is not None:
+                tl.note_tick(self.steps, time.perf_counter_ns() - tick.t0,
+                             rows_in=rows_in, rows_out=rows_out,
+                             queue_depth=sum(ep.buffered()
+                                             for ep in self.inputs.values()),
+                             trace_ids=trace_ids)
+                if not getattr(self.handle, "interval_open", False):
+                    # this step's results validated and published (host
+                    # engine: every step; compiled: when no deferred
+                    # interval remains)
+                    tl.note_visible(list(self.catalog.outputs))
 
     def _emit_outputs(self) -> int:
         from dbsp_tpu.zset.batch import concat_batches

@@ -19,6 +19,7 @@ from urllib.parse import parse_qs, urlparse
 from dbsp_tpu.io.controller import Controller
 from dbsp_tpu.io.format import INPUT_FORMATS, OUTPUT_FORMATS
 from dbsp_tpu.obs import export as obs_export
+from dbsp_tpu.obs.tracing import default_recorder
 from dbsp_tpu.testing.tsan import maybe_instrument as _tsan_hook
 
 
@@ -30,6 +31,9 @@ class CircuitServer:
         # obs: an obs.PipelineObs bundle — /metrics serves its registry
         # (plus the legacy names) and /trace its Chrome-trace span window
         self.obs = obs
+        # the request spans (``ingest``, ``step_request``, ``read``) land in
+        # the pipeline's ring, or the process's when no obs is attached
+        self.spans = obs.spans if obs is not None else default_recorder()
         # Static-analysis gate (dbsp_tpu/analysis): ERROR findings refuse
         # to serve; WARNs are logged/counted and exposed at /analysis.
         # Callers that already verified (the manager) pass their findings
@@ -80,6 +84,52 @@ class CircuitServer:
             def _json(self, obj, code=200, headers=None):
                 self._reply(code, json.dumps(obj).encode(),
                             headers=headers)
+
+            def _get_view(self, url, name, sp):
+                """``GET /view/<name>`` inside its ``read`` span ``sp``:
+                ``read.query`` until the response object is built,
+                ``read.respond`` until it is written."""
+                c = server.controller
+                spans = server.spans
+                with spans.span("read.query", "read"):
+                    t0 = _time.perf_counter()
+                    plane = c.read_plane
+                    if not plane.enabled:
+                        return self._json(
+                            {"error": "read plane disabled "
+                                      "(DBSP_TPU_READPLANE=0)"}, 503)
+                    qs = parse_qs(url.query)
+                    try:
+                        key = tuple(int(x) for x in
+                                    qs["key"][0].split(",")) \
+                            if "key" in qs else None
+                        lo = int(qs["lo"][0]) if "lo" in qs else None
+                        hi = int(qs["hi"][0]) if "hi" in qs else None
+                        limit = int(qs["limit"][0]) if "limit" in qs \
+                            else None
+                        obj = plane.query(name, key=key, lo=lo, hi=hi,
+                                          limit=limit)
+                    except KeyError:
+                        return self._json(
+                            {"error": f"unknown view {name!r}; have "
+                                      f"{sorted(plane.views())}"}, 404)
+                    except ValueError as e:
+                        return self._json({"error": str(e)}, 400)
+                    plane.note_read(
+                        "view_point" if key is not None else
+                        "view_range" if (lo is not None or hi is not None)
+                        else "view_scan", t0)
+                    # e2e attribution: age_s + per-stage breakdown of the
+                    # served epoch's delta path, and the trace ids echoed
+                    # as a response header for cross-process correlation
+                    c.e2e.annotate_read(obj, t0)
+                    ids = (obj.get("trace") or {}).get("ids") or ()
+                sp.note(rows=len(obj.get("rows") or ()),
+                        epoch=obj.get("epoch"))
+                with spans.span("read.respond", "read"):
+                    self._json(obj, headers={"X-Dbsp-Trace":
+                                             ",".join(ids)} if ids
+                               else None)
 
             def do_GET(self):
                 url = urlparse(self.path)
@@ -146,11 +196,7 @@ class CircuitServer:
                     self._json([f.to_dict()
                                 for f in server.analysis_findings])
                 elif route == "/trace":
-                    if server.obs is None:
-                        self._json({"error": "tracing not enabled"}, 400)
-                    else:
-                        self._reply(200,
-                                    server.obs.spans.to_json().encode())
+                    self._reply(200, server.spans.to_json().encode())
                 elif route == "/dump_profile":
                     if server.profiler is None:
                         self._json({"error": "profiler not enabled"}, 400)
@@ -216,42 +262,10 @@ class CircuitServer:
                     # lock and quiesce() are NEVER taken on this path
                     # (C003). Staleness <= one validation interval. 503
                     # when the plane is off (DBSP_TPU_READPLANE=0).
-                    t0 = _time.perf_counter()
-                    plane = c.read_plane
-                    if not plane.enabled:
-                        return self._json(
-                            {"error": "read plane disabled "
-                                      "(DBSP_TPU_READPLANE=0)"}, 503)
                     name = route.rsplit("/", 1)[1]
-                    qs = parse_qs(url.query)
-                    try:
-                        key = tuple(int(x) for x in
-                                    qs["key"][0].split(",")) \
-                            if "key" in qs else None
-                        lo = int(qs["lo"][0]) if "lo" in qs else None
-                        hi = int(qs["hi"][0]) if "hi" in qs else None
-                        limit = int(qs["limit"][0]) if "limit" in qs \
-                            else None
-                        obj = plane.query(name, key=key, lo=lo, hi=hi,
-                                          limit=limit)
-                    except KeyError:
-                        return self._json(
-                            {"error": f"unknown view {name!r}; have "
-                                      f"{sorted(plane.views())}"}, 404)
-                    except ValueError as e:
-                        return self._json({"error": str(e)}, 400)
-                    plane.note_read(
-                        "view_point" if key is not None else
-                        "view_range" if (lo is not None or hi is not None)
-                        else "view_scan", t0)
-                    # e2e attribution: age_s + per-stage breakdown of the
-                    # served epoch's delta path, and the trace ids echoed
-                    # as a response header for cross-process correlation
-                    c.e2e.annotate_read(obj, t0)
-                    ids = (obj.get("trace") or {}).get("ids") or ()
-                    self._json(obj, headers={"X-Dbsp-Trace":
-                                             ",".join(ids)} if ids
-                               else None)
+                    with server.spans.span("read", "read",
+                                           args={"view": name}) as sp:
+                        self._get_view(url, name, sp)
                 elif route == "/changefeed":
                     # changefeed read with a resume-from-epoch cursor:
                     # ?view=<name>&after=<epoch>[&timeout=<s>][&limit=N].
@@ -337,6 +351,46 @@ class CircuitServer:
                 else:
                     self._json({"error": f"no route {route}"}, 404)
 
+            def _post_input(self, url, name, sp):
+                """``POST /input_endpoint/<name>`` inside its ``ingest``
+                span ``sp``."""
+                c = server.controller
+                spans = server.spans
+                with spans.span("ingest.read_body", "ingest"):
+                    n = int(self.headers.get("Content-Length", 0))
+                    body = self.rfile.read(n)
+                fmt = parse_qs(url.query).get("format", ["json"])[0]
+                try:
+                    col = c.catalog.input(name)
+                except KeyError as e:
+                    return self._json({"error": str(e)}, 404)
+                with spans.span("ingest.parse", "ingest"):
+                    parser = INPUT_FORMATS[fmt](col.dtypes)
+                    try:
+                        parser.feed(body)
+                        parser.eoi()
+                        rows = parser.take()
+                    except (ValueError, KeyError) as e:
+                        return self._json(
+                            {"error": f"parse error: {e}"}, 400)
+                with spans.span("ingest.push_rows", "ingest"):
+                    col.push_rows(rows)
+                    # HTTP pushes must wake the circuit loop like transport
+                    # rows do — found by the console JS-path test: pushed
+                    # rows sat unstepped until an explicit /step.
+                    # An X-Dbsp-Trace request header is adopted as the
+                    # batch's e2e trace id (cross-process propagation);
+                    # otherwise one is minted — either way it is echoed.
+                    trace_id = c.note_pushed(
+                        len(rows),
+                        trace_id=self.headers.get("X-Dbsp-Trace") or None)
+                sp.note(records=len(rows), bytes=n, trace=trace_id)
+                resp = {"records": len(rows)}
+                if trace_id is not None:
+                    resp["trace"] = trace_id
+                self._json(resp, headers={"X-Dbsp-Trace": trace_id}
+                           if trace_id else None)
+
             def do_POST(self):
                 url = urlparse(self.path)
                 route = url.path.rstrip("/")
@@ -351,8 +405,9 @@ class CircuitServer:
                     threading.Thread(target=c.stop, daemon=True).start()
                     self._json({"state": "shutdown"})
                 elif route == "/step":
-                    c.step()
-                    self._json({"steps": c.steps})
+                    with server.spans.span("step_request", "step"):
+                        c.step()
+                        self._json({"steps": c.steps})
                 elif route == "/checkpoint":
                     # write one durable checkpoint generation now
                     # (quiesced under the step lock); 400 when no
@@ -365,35 +420,9 @@ class CircuitServer:
                     self._json(info)
                 elif route.startswith("/input_endpoint/"):
                     name = route.rsplit("/", 1)[1]
-                    n = int(self.headers.get("Content-Length", 0))
-                    body = self.rfile.read(n)
-                    fmt = parse_qs(url.query).get("format", ["json"])[0]
-                    try:
-                        col = c.catalog.input(name)
-                    except KeyError as e:
-                        return self._json({"error": str(e)}, 404)
-                    parser = INPUT_FORMATS[fmt](col.dtypes)
-                    try:
-                        parser.feed(body)
-                        parser.eoi()
-                        rows = parser.take()
-                    except (ValueError, KeyError) as e:
-                        return self._json({"error": f"parse error: {e}"}, 400)
-                    col.push_rows(rows)
-                    # HTTP pushes must wake the circuit loop like transport
-                    # rows do — found by the console JS-path test: pushed
-                    # rows sat unstepped until an explicit /step.
-                    # An X-Dbsp-Trace request header is adopted as the
-                    # batch's e2e trace id (cross-process propagation);
-                    # otherwise one is minted — either way it is echoed.
-                    trace_id = c.note_pushed(
-                        len(rows),
-                        trace_id=self.headers.get("X-Dbsp-Trace") or None)
-                    resp = {"records": len(rows)}
-                    if trace_id is not None:
-                        resp["trace"] = trace_id
-                    self._json(resp, headers={"X-Dbsp-Trace": trace_id}
-                               if trace_id else None)
+                    with server.spans.span("ingest", "ingest",
+                                           args={"table": name}) as sp:
+                        self._post_input(url, name, sp)
                 else:
                     self._json({"error": f"no route {route}"}, 404)
 
@@ -483,7 +512,7 @@ class CircuitServer:
             out["flight"] = self.obs.flight.to_dict(limit=64)
             # span-ring drop accounting: a truncated /trace window must
             # announce itself in the bug-report bundle
-            dropped = self.obs.spans.dropped_steps
+            dropped = self.spans.dropped_steps
             out["trace"] = {"dropped_steps": dropped,
                             "truncated": dropped > 0}
         return out
@@ -499,7 +528,7 @@ class CircuitServer:
         with self.controller.quiesce():
             report = self.profiler.profile_report(
                 ticks=ticks,
-                spans=self.obs.spans if self.obs is not None else None,
+                spans=self.spans,
                 registry=self.obs.registry if self.obs is not None else None)
         self._last_profile = report  # /debug embeds the last served report
         return report

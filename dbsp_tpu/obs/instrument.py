@@ -620,6 +620,10 @@ class PipelineObs:
         # arrival/visibility records straight onto this pipeline's timeline
         if hasattr(controller, "timeline"):
             controller.timeline = self.timeline
+        # the ``tick`` span and its phases: this pipeline's ring, the one
+        # its /trace serves
+        if hasattr(controller, "spans"):
+            controller.spans = self.spans
         # read serving plane (dbsp_tpu/serving.py): read QPS/latency
         # metrics + a flight ring for staleness-breach attribution
         plane = getattr(controller, "read_plane", None)

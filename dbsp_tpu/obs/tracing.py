@@ -4,8 +4,19 @@ the end-to-end stage attribution that rides the serving plane.
 Load any export in Perfetto (https://ui.perfetto.dev) or chrome://tracing.
 Spans nest step -> operator eval -> exchange on the host path (driven by
 :class:`~dbsp_tpu.obs.instrument.CircuitInstrumentation` from the
-scheduler-event stream) and tick -> compiled-step/validate/maintain on the
-compiled path (driven by the compiled driver directly).
+scheduler-event stream). The served path names every phase of a request
+with fixed strings (the tick index, table, record and byte counts are
+``args``): ``ingest`` > ``ingest.read_body`` / ``ingest.parse`` /
+``ingest.push_rows`` (io/server.py), ``step_request`` > ``step.lock_wait``,
+``tick`` > ``tick.drain_endpoints`` / ``tick.build_inputs`` /
+``tick.snapshot`` / ``tick.dispatch`` / ``tick.validate`` (>
+``tick.device_wait``, ``tick.grow``, ``tick.replay``) / ``tick.maintain`` /
+``tick.deliver`` / ``tick.emit_outputs`` / ``tick.publish`` /
+``tick.checkpoint`` / ``tick.monitors`` (io/controller.py,
+compiled/driver.py), ``read`` > ``read.query`` / ``read.respond``, and a
+closed ``compile`` child for every program asked of the compiler inside
+any of them. :func:`default_recorder` is the ring they land in unless a
+``PipelineObs`` hands its own.
 
 Format: the JSON-object flavor of the Trace Event Format — ``B``/``E``
 duration events with microsecond timestamps, so nesting is explicit and a
@@ -13,8 +24,9 @@ consumer (or test) can check balance. Events carry the real ``os.getpid()``
 and ``threading.get_native_id()`` so the serving plane's thread fan-out
 (HTTP handlers, circuit loop, replica feed loops) lands in distinct lanes,
 with ``M`` metadata events naming each process and thread. The window is
-bounded: only the most recent ``max_steps`` completed top-level spans are
-retained (a serving pipeline runs forever; the trace buffer must not);
+bounded: per kind of top-level span (its ``cat``) only the most recent
+``max_steps`` completed ones are retained (a serving pipeline runs
+forever; the trace buffer must not), so reads never evict ticks;
 evictions are counted in ``dropped_steps`` and exported as
 ``dbsp_tpu_obs_trace_dropped_total{pipeline}`` once :meth:`SpanRecorder.bind`
 has run.
@@ -59,12 +71,15 @@ import os
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Deque, Dict, List, Optional, Sequence
+from typing import Deque, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from dbsp_tpu.testing.tsan import maybe_instrument as _tsan_hook
 
 __all__ = [
-    "SpanRecorder", "E2ETracer", "E2E_STAGES", "trace_e2e_enabled",
+    "SpanRecorder", "default_recorder", "E2ETracer", "E2E_STAGES",
+    "trace_e2e_enabled",
     "merge_chrome_traces",
 ]
 
@@ -85,61 +100,169 @@ def trace_e2e_enabled(env: Optional[dict] = None) -> bool:
         "0", "false", "no", "off")
 
 
+#: top-level spans kept per kind by :func:`default_recorder`: a served
+#: pipeline read every 100 ms over a ~50 s window leaves ~500 ``read``
+#: entries, and the window's reads are all wanted back afterwards
+DEFAULT_STEPS_PER_KIND = 1024
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: per-thread: ``rec`` = the recorder that last opened a span here (the
+#: compile listener's target), ``cache_hit`` = the compile in flight on this
+#: thread was a persistent-cache load
+_tls = threading.local()
+_default: Optional["SpanRecorder"] = None
+_module_lock = threading.RLock()  # default_recorder builds under it
+_listening = False
+
+
+def _on_compile_event(event: str, **_) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _tls.cache_hit = True
+
+
+def _on_compile_duration(event: str, secs: float, **kw) -> None:
+    """One backend compile request (a persistent-cache load included) ended
+    on this thread: a closed ``compile`` child of whatever span is open
+    here, else a top-level span of the default recorder."""
+    if event != _COMPILE_EVENT:
+        return
+    hit = getattr(_tls, "cache_hit", False)
+    _tls.cache_hit = False
+    rec = getattr(_tls, "rec", None) or _default
+    if rec is None:
+        return
+    t1 = time.perf_counter_ns()
+    rec.span_at("compile", t1 - int(secs * 1e9), t1, cat="compile",
+                args={"seconds": secs, "cache_hit": hit,
+                      "fun": kw.get("fun_name")}, nest=True)
+
+
+def _listen_for_compiles() -> None:
+    """Register the ``jax.monitoring`` listeners once per process (they
+    route by thread, so one pair serves every recorder)."""
+    global _listening
+    with _module_lock:
+        if _listening:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_listener(_on_compile_event)
+        monitoring.register_event_duration_secs_listener(_on_compile_duration)
+        _listening = True
+
+
+def default_recorder() -> "SpanRecorder":
+    """The process's span ring: the served path (server, controller,
+    compiled driver) records into it unless handed another. It outlives
+    the objects it observed, so a harness reads it after they are gone."""
+    global _default
+    if _default is None:
+        with _module_lock:
+            if _default is None:
+                _default = SpanRecorder(max_steps=DEFAULT_STEPS_PER_KIND)
+    return _default
+
+
+class _OpenStack:
+    """One thread's in-flight top-level span: its events so far and, per
+    open span, the profiler annotation to leave at its end."""
+
+    __slots__ = ("thread", "events", "marks")
+
+    def __init__(self, thread: str):
+        self.thread = thread
+        self.events: List[dict] = []
+        self.marks: List[TraceAnnotation] = []
+
+
 class SpanRecorder:
-    """Accumulates B/E span events; ring-buffered per top-level span.
+    """Accumulates B/E span events; one bounded ring per kind of top-level
+    span (its ``cat``), so a flood of one kind (``read``) never evicts
+    another (``step``).
 
     Events are stamped with the recorder's process id and the *real* native
     thread id of the caller, with per-thread open-span stacks so concurrent
     serving-plane threads (circuit loop, HTTP handlers, replica feed loop)
-    nest correctly in their own lanes instead of interleaving into one.
+    nest correctly in their own lanes instead of interleaving into one. A
+    thread's stack exists only while it has a span open. Timestamps are
+    ``time.perf_counter_ns`` (``CLOCK_MONOTONIC`` on Linux, the clock of
+    ``time.monotonic``). Every ``begin``/``end`` also enters/leaves a
+    ``jax.profiler.TraceAnnotation`` named ``dbsp.<span name>``: under a
+    profiler session the host phases sit on the device trace's own clock;
+    without one the annotation is inert.
     """
 
     def __init__(self, max_steps: int = 64, process: str = "dbsp_tpu"):
         self.pid = os.getpid()
         self.process = process
-        self._steps: Deque[List[dict]] = deque(maxlen=max_steps)
-        self._open: Dict[int, List[dict]] = {}   # tid -> in-flight events
-        self._depth: Dict[int, int] = {}         # tid -> open-span depth
-        self._threads: Dict[int, str] = {}       # tid -> thread name
+        self.max_steps = max_steps
+        # kind -> ring of (thread name, events of one top-level span)
+        self._steps: Dict[str, Deque[Tuple[str, List[dict]]]] = {}
+        self._open: Dict[int, _OpenStack] = {}   # tid -> in-flight stack
         self._lock = threading.Lock()
         self.dropped_steps = 0
         self._dropped_counter = None  # wired once by bind()
         self._pipeline = ""
+        _listen_for_compiles()
         _tsan_hook(self)
 
     # -- recording ----------------------------------------------------------
-    def _push_step_locked(self, events: List[dict]) -> None:  # holds: _lock
-        if len(self._steps) == self._steps.maxlen:
+    def _push_step_locked(self, thread: str,
+                          events: List[dict]) -> None:  # holds: _lock
+        kind = events[0].get("cat", "")
+        ring = self._steps.get(kind)
+        if ring is None:
+            ring = self._steps[kind] = deque(maxlen=self.max_steps)
+        if len(ring) == ring.maxlen:
             self.dropped_steps += 1
-        self._steps.append(events)
+        ring.append((thread, events))
 
     def begin(self, name: str, cat: str = "operator",
-              ts_ns: Optional[int] = None, args: Optional[dict] = None) -> None:
-        ts = (ts_ns if ts_ns else time.perf_counter_ns()) / 1e3
+              ts_ns: Optional[int] = None,
+              args: Optional[dict] = None) -> int:
+        """Open a span on this thread; returns its start (ns)."""
+        mark = TraceAnnotation("dbsp." + name)
+        mark.__enter__()
+        ts_ns = ts_ns if ts_ns else time.perf_counter_ns()
         tid = threading.get_native_id()
         ev = {"name": name, "cat": cat, "ph": "B",
-              "ts": ts, "pid": self.pid, "tid": tid}
+              "ts": ts_ns / 1e3, "pid": self.pid, "tid": tid}
+        if args:
+            ev["args"] = args
+        _tls.rec = self
+        with self._lock:
+            stack = self._open.get(tid)
+            if stack is None:
+                stack = self._open[tid] = _OpenStack(
+                    threading.current_thread().name)
+            stack.events.append(ev)
+            stack.marks.append(mark)
+        return ts_ns
+
+    def end(self, name: str, ts_ns: Optional[int] = None,
+            args: Optional[dict] = None) -> int:
+        """Close this thread's innermost span; ``args`` (what was only
+        known at the end) ride the ``E`` event. Returns its end (ns)."""
+        ts_ns = ts_ns if ts_ns else time.perf_counter_ns()
+        tid = threading.get_native_id()
+        ev = {"name": name, "ph": "E", "ts": ts_ns / 1e3,
+              "pid": self.pid, "tid": tid}
         if args:
             ev["args"] = args
         with self._lock:
-            if tid not in self._threads:
-                self._threads[tid] = threading.current_thread().name
-            self._open.setdefault(tid, []).append(ev)
-            self._depth[tid] = self._depth.get(tid, 0) + 1
-
-    def end(self, name: str, ts_ns: Optional[int] = None) -> None:
-        ts = (ts_ns if ts_ns else time.perf_counter_ns()) / 1e3
-        tid = threading.get_native_id()
-        with self._lock:
-            depth = self._depth.get(tid, 0)
-            if depth == 0:
-                return  # unbalanced end (attached mid-step): drop
-            self._open[tid].append({"name": name, "ph": "E", "ts": ts,
-                                    "pid": self.pid, "tid": tid})
-            depth -= 1
-            self._depth[tid] = depth
-            if depth == 0:
-                self._push_step_locked(self._open.pop(tid))
+            stack = self._open.get(tid)
+            if stack is None:
+                return ts_ns  # unbalanced end (attached mid-step): drop
+            stack.events.append(ev)
+            mark = stack.marks.pop()
+            if not stack.marks:
+                del self._open[tid]
+                self._push_step_locked(stack.thread, stack.events)
+                _tls.rec = None
+        mark.__exit__(None, None, None)
+        return ts_ns
 
     def instant(self, name: str, cat: str = "event",
                 ts_ns: Optional[int] = None,
@@ -152,18 +275,22 @@ class SpanRecorder:
         if args:
             ev["args"] = args
         with self._lock:
-            if tid not in self._threads:
-                self._threads[tid] = threading.current_thread().name
-            if self._depth.get(tid, 0):
-                self._open[tid].append(ev)
+            stack = self._open.get(tid)
+            if stack is not None:
+                stack.events.append(ev)
             else:
-                self._push_step_locked([ev])
+                self._push_step_locked(
+                    threading.current_thread().name, [ev])
 
     def span_at(self, name: str, t0_ns: int, t1_ns: int,
-                cat: str = "e2e", args: Optional[dict] = None) -> None:
-        """Append one already-completed span as a self-contained, balanced
-        ``[B, E]`` ring entry — the e2e stage spans use this, so a trace
-        snapshot taken mid-tick can never observe them half-open."""
+                cat: str = "e2e", args: Optional[dict] = None,
+                nest: bool = False) -> None:
+        """Append one already-completed span as a balanced ``[B, E]`` pair.
+        By default it is a ring entry of its own — the e2e stage spans use
+        this, so a trace snapshot taken mid-tick can never observe them
+        half-open (and they may start before the tick that reports them).
+        ``nest=True`` makes it a child of the span open on this thread, if
+        any, its start clipped to the events already there."""
         tid = threading.get_native_id()
         bev = {"name": name, "cat": cat, "ph": "B", "ts": t0_ns / 1e3,
                "pid": self.pid, "tid": tid}
@@ -172,27 +299,46 @@ class SpanRecorder:
         eev = {"name": name, "ph": "E", "ts": max(t0_ns, t1_ns) / 1e3,
                "pid": self.pid, "tid": tid}
         with self._lock:
-            if tid not in self._threads:
-                self._threads[tid] = threading.current_thread().name
-            self._push_step_locked([bev, eev])
+            stack = self._open.get(tid) if nest else None
+            if stack is not None:
+                bev["ts"] = max(bev["ts"], stack.events[-1]["ts"])
+                eev["ts"] = max(eev["ts"], bev["ts"])
+                stack.events += (bev, eev)
+            else:
+                self._push_step_locked(
+                    threading.current_thread().name, [bev, eev])
 
     class _Span:
-        __slots__ = ("rec", "name", "cat")
+        """``with rec.span(...) as sp``: ``sp.note(k=v)`` adds args known
+        only at the end; ``sp.t0`` / ``sp.t1`` are the two clock readings
+        the ring holds, for callers that keep a duration beside it."""
 
-        def __init__(self, rec, name, cat):
-            self.rec, self.name, self.cat = rec, name, cat
+        __slots__ = ("rec", "name", "cat", "args", "t0", "t1")
+
+        def __init__(self, rec, name, cat, args):
+            self.rec, self.name, self.cat, self.args = rec, name, cat, args
+            self.t0 = self.t1 = 0
 
         def __enter__(self):
-            self.rec.begin(self.name, self.cat)
+            self.t0 = self.rec.begin(self.name, self.cat, args=self.args)
+            self.args = None
             return self
 
+        def note(self, **args) -> None:
+            self.args = {**(self.args or {}), **args}
+
         def __exit__(self, *exc):
-            self.rec.end(self.name)
+            self.t1 = self.rec.end(self.name, args=self.args)
             return False
 
-    def span(self, name: str, cat: str = "operator") -> "_Span":
+        @property
+        def elapsed_ns(self) -> int:
+            return self.t1 - self.t0
+
+    def span(self, name: str, cat: str = "operator",
+             args: Optional[dict] = None) -> "_Span":
         """Context-manager convenience for host-driven span pairs."""
-        return SpanRecorder._Span(self, name, cat)
+        return SpanRecorder._Span(self, name, cat, args)
 
     # -- export -------------------------------------------------------------
     def bind(self, registry=None, pipeline: str = "") -> None:
@@ -215,15 +361,27 @@ class SpanRecorder:
         self._dropped_counter.labels(pipeline=self._pipeline).set_total(
             float(self.dropped_steps))
 
+    def _retained_locked(self) -> List[Tuple[str, List[dict]]]:  # holds: _lock
+        """Every ring's entries, in order of their start."""
+        return sorted((step for ring in self._steps.values()
+                       for step in ring), key=lambda s: s[1][0]["ts"])
+
     def events(self) -> List[dict]:
         with self._lock:
-            return [ev for step in self._steps for ev in step]
+            return [ev for _, evs in self._retained_locked() for ev in evs]
+
+    def open_threads(self) -> int:
+        """Threads with a span in flight (the per-thread map's size)."""
+        with self._lock:
+            return len(self._open)
 
     def to_chrome_trace(self) -> dict:
         with self._lock:
-            evs = [ev for step in self._steps for ev in step]
-            threads = dict(self._threads)
+            steps = self._retained_locked()
+            threads = {tid: st.thread for tid, st in self._open.items()}
             dropped = self.dropped_steps
+        evs = [ev for _, es in steps for ev in es]
+        threads.update((es[0]["tid"], thread) for thread, es in steps)
         meta = [{"name": "process_name", "ph": "M", "pid": self.pid,
                  "tid": 0, "args": {"name": self.process}}]
         for tid in sorted(threads):
@@ -241,7 +399,6 @@ class SpanRecorder:
         with self._lock:
             self._steps.clear()
             self._open = {}
-            self._depth = {}
 
 
 def merge_chrome_traces(traces: Sequence[dict]) -> dict:

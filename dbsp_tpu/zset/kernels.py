@@ -13,12 +13,29 @@ keys (max value) so that a single ascending sort moves them to the end.
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, wraps
 from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+
+
+def _scoped(fn):
+    """Run a public kernel under ``jax.named_scope("k.<kernel>")``: every
+    operation it lowers to carries the scope in its metadata, so a device
+    trace and the lowered text name it in situ, inside whichever program
+    and circuit-node scope called it. Nothing runs at run time."""
+    scope = "k." + fn.__name__
+
+    @wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
+
+    return scoped
+
 
 # ---------------------------------------------------------------------------
 # Consolidation-path accounting
@@ -165,6 +182,7 @@ def sentinel_fill(shape, dtype) -> jnp.ndarray:
 SORT_CHUNK_ROWS = 2048
 
 
+@_scoped
 def sort_rows(cols: Sequence[jnp.ndarray], payload: Sequence[jnp.ndarray]
               ) -> Tuple[Tuple[jnp.ndarray, ...], Tuple[jnp.ndarray, ...]]:
     """Stable ascending lexicographic sort by ``cols``; ``payload`` rides along.
@@ -293,6 +311,7 @@ def rows_equal_prev(cols: Sequence[jnp.ndarray], n: int | None = None
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
             keep: jnp.ndarray) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
     """Move rows with ``keep`` to the front (order preserved); rest is dead.
@@ -330,6 +349,7 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def consolidate_cols(cols: Sequence[jnp.ndarray], weights: jnp.ndarray
                      ) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
     """Canonicalize a weighted row set (reference: ``trace/consolidation``).
@@ -389,6 +409,7 @@ def merge_strategy() -> str:
     return "native" if native_kernel("merge") else "sort"
 
 
+@_scoped
 def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
                       cols_b: Sequence[jnp.ndarray], w_b: jnp.ndarray
                       ) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
@@ -464,6 +485,7 @@ def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("side",))
+@_scoped
 def lex_searchsorted(table_cols: Tuple[jnp.ndarray, ...],
                      query_cols: Tuple[jnp.ndarray, ...],
                      side: str = "left") -> jnp.ndarray:
@@ -541,6 +563,7 @@ def _lex_le_rows(table_cols, idx, query_cols, strict: bool):
     return lt if strict else lt | all_eq
 
 
+@_scoped
 def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
               query_cols: Tuple[jnp.ndarray, ...],
               side: str = "left") -> jnp.ndarray:
@@ -585,6 +608,7 @@ def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
 # ---------------------------------------------------------------------------
 
 
+@_scoped
 def expand_ranges(lo: jnp.ndarray, hi: jnp.ndarray, out_cap: int
                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Flatten variable-length ranges into static-capacity index arrays.

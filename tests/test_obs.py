@@ -495,9 +495,9 @@ def test_manager_metrics_scrape_compiled_mode(manager):
     doc = pipe.trace()
     evs = doc["traceEvents"]
     _assert_balanced(evs)
-    assert any(e["ph"] == "B" and e["name"].startswith("tick[")
-               for e in evs)
-    assert any(e["ph"] == "B" and e["name"] == "compiled_step"
+    assert any(e["ph"] == "B" and e["name"] == "tick"
+               and isinstance(e["args"]["tick"], int) for e in evs)
+    assert any(e["ph"] == "B" and e["name"] == "tick.dispatch"
                for e in evs)
 
 
