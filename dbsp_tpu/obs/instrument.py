@@ -82,7 +82,11 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
         "dbsp_tpu_zset_kernel_dispatch_total",
         "Z-set kernel dispatch decisions by entry point and backend "
         "(native = C++ FFI custom call, xla = pure-XLA lowering, "
-        "pallas = hand-written Pallas program); the fused ladder-consumer "
+        "pallas = hand-written Pallas program; an accelerator's XLA "
+        "formulation is named where it is not the CPU's: xla_bitonic = the "
+        "bitonic merge network behind kernel=merge and kernel=sort_merge, "
+        "a sort of more than SORT_CHUNK_ROWS rows; xla_shift = the shift "
+        "compaction behind kernel=compact); the fused ladder-consumer "
         "megakernels report as kernel=join_ladder / gather_ladder / "
         "old_weights and the reduction offensive as kernel=segment_reduce "
         "/ agg_ladder / join_sorted, whose xla rows are the stitched-chain "

@@ -457,7 +457,7 @@ def _reduce_groups_impl(parts, agg: Aggregator, q_cap: int,
 
     One gathered level needs no netting (its rows are unique); multiple
     levels combine with one sort-consolidation on CPU or a fold of
-    rank-merges on TPU (kernels.merge_strategy). ``net=True`` forces the
+    sorted merges on TPU (kernels.merge_strategy). ``net=True`` forces the
     consolidation for a SINGLE part that was itself combined from several
     levels (compiled ``gather_levels``) and so may carry cross-level
     insert/retract rows for one (qrow, vals)."""

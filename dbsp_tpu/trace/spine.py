@@ -346,7 +346,7 @@ class Spine:
             elif len(self.batches) == 1:
                 self._consolidated = self.batches[0]
             else:
-                # fold small->large so each rank-merge probes the smaller side
+                # fold small->large so the accumulator stays as small as it can
                 acc = None
                 for b in sorted(self.batches, key=lambda b: b.cap):
                     acc = b if acc is None else acc.merge_with(b)

@@ -358,7 +358,7 @@ def consolidate_regime(batch: Batch) -> Batch:
                 return Batch(cols[:nk], cols[nk:], w, runs=(batch.cap,))
         kernels.count_kernel_dispatch("rank_fold", "xla")
         # fold sorted merges over the run slices, smallest runs first so
-        # each merge probes the smaller side into the accumulator
+        # the accumulator stays as small as it can
         bounds = []
         off = 0
         for r in runs:

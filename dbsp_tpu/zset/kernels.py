@@ -74,7 +74,10 @@ def count_consolidate_path(path: str) -> None:
 
 # Which implementation each kernel entry point dispatched to, keyed by
 # (kernel, backend) with backend one of "native" (C++ FFI custom call),
-# "xla" (pure-XLA lowering) or "pallas" (hand-written Pallas program).
+# "xla" (pure-XLA lowering), "pallas" (hand-written Pallas program) or, where
+# an accelerator's XLA formulation is not the CPU's, its name: "xla_bitonic"
+# (the merge network behind "merge" and "sort_merge", a sort of more than
+# SORT_CHUNK_ROWS rows) and "xla_shift" (the shift compaction of "compact").
 # Same counting convention as CONSOLIDATE_COUNTS (eager calls per eval,
 # traced calls per trace); exported by obs as
 # ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` and embedded in
@@ -177,8 +180,8 @@ def sentinel_fill(shape, dtype) -> jnp.ndarray:
 # Rows per ``lax.sort`` call on accelerators. XLA:TPU's compile time for a
 # multi-operand int64 sort climbs steeply with the row count (a 5-column
 # NEXmark row: 1.4 s at 2,048 rows, 7.3 s at 4,096, 26 s at 8,192, minutes at
-# 65,536 — compiled for a described v5e), while the rank merge of sorted runs
-# is probes + gathers and compiles in seconds at any size.
+# 65,536 — compiled for a described v5e), while the merge network over the
+# sorted chunks is one small loop body per level at any size.
 SORT_CHUNK_ROWS = 2048
 
 
@@ -198,6 +201,7 @@ def sort_rows(cols: Sequence[jnp.ndarray], payload: Sequence[jnp.ndarray]
     ops = (*cols, *payload)
     if cols[0].ndim == 1 and cols[0].shape[0] > SORT_CHUNK_ROWS and \
             jax.default_backend() != "cpu":
+        count_kernel_dispatch("sort_merge", "xla_bitonic")
         out = _sort_rows_chunked(ops, len(cols), SORT_CHUNK_ROWS)
     else:
         out = lax.sort(ops, num_keys=len(cols), is_stable=True)
@@ -212,21 +216,24 @@ def _pad_last(dtype):
     return sentinel_for(dtype)
 
 
+# The accelerator formulations below are loops, and a loop dispatched
+# eagerly is traced anew and asked of the compiler on every call (the
+# host path consolidates each input batch eagerly): each is one jitted
+# program, compiled once per shape.
+
+
+@partial(jax.jit, static_argnames=("num_keys", "chunk"))
 def _sort_rows_chunked(ops: Sequence[jnp.ndarray], num_keys: int,
                        chunk: int) -> Tuple[jnp.ndarray, ...]:
     """Stable lexicographic sort of 1-D ``ops`` by their first ``num_keys``
-    operands: sorts of at most ``chunk`` rows (one ``lax.map`` body), then
-    a bottom-up merge sort over the sorted runs in which every level is the
-    SAME program — a ``fori_loop`` over levels whose body merges all
-    neighbouring run pairs at once by binary-search ranks, one scatter of
-    row numbers and a gather per operand. The compiler sees one small sort
-    and one merge body whatever the row count. Bit-identical to
-    ``lax.sort(..., is_stable=True)``."""
+    operands: sorts of ``chunk`` rows (one ``lax.map`` body), then a
+    bottom-up merge sort whose every level merges all neighbouring run
+    pairs at once with :func:`_bitonic_merge` — elementwise passes over
+    contiguous rows, no gather. The compiler sees one small sort whatever
+    the row count. Bit-identical to ``lax.sort(..., is_stable=True)``."""
     n = ops[0].shape[0]
-    levels = max(0, -(-n // chunk) - 1).bit_length()  # runs = 2**levels
-    runs = 1 << levels
-    size = -(-n // runs)
-    total = runs * size
+    total = 1 << (n - 1).bit_length()
+    size = min(total, 1 << (chunk.bit_length() - 1))
     if total > n:
         # pad rows compare >= every real row and sit after them in the
         # input, so stability keeps them last: the first n rows are the
@@ -236,48 +243,100 @@ def _sort_rows_chunked(ops: Sequence[jnp.ndarray], num_keys: int,
                for o in ops]
     ops = lax.map(
         lambda row: tuple(lax.sort(row, num_keys=num_keys, is_stable=True)),
-        tuple(o.reshape(runs, size) for o in ops))
+        tuple(o.reshape(total // size, size) for o in ops))
     ops = tuple(o.reshape(total) for o in ops)
-    g = jnp.arange(total, dtype=jnp.int32)
-
-    def merge_level(level, ops):
-        s = jnp.int32(size) << level           # rows per sorted run
-        run = g // s
-        first = (run & 1) == 0                 # row of a pair's first run
-        other = (run ^ 1) * s                  # where the pair's other run starts
-        keys = ops[:num_keys]
-
-        # cross-rank of every row in its pair's other run — the stable
-        # position map of merge_sorted_cols: a first-run row goes after
-        # the other run's rows strictly below it, a second-run row after
-        # those at or below it
-        def halve(_, lohi):
-            lo, hi = lohi
-            active = lo < hi
-            mid = (lo + hi) >> 1
-            at = other + jnp.minimum(mid, s - 1)
-            go_right = jnp.where(
-                first, _lex_le_rows(keys, at, keys, strict=True),
-                _lex_le_rows(keys, at, keys, strict=False))
-            return (jnp.where(active & go_right, mid + 1, lo),
-                    jnp.where(active & ~go_right, mid, hi))
-
-        # inside shard_map the rows vary per worker: the loop carry must
-        # enter with the varying type it leaves with
-        vma = tuple(jax.typeof(ops[0]).vma)
-        bounds = (jnp.zeros((total,), jnp.int32),
-                  jnp.broadcast_to(s, (total,)))
-        if vma:
-            bounds = tuple(lax.pcast(b, vma, to="varying") for b in bounds)
-        # s + 1 candidate ranks [0, s] => bit_length(s) halvings
-        rank, _ = lax.fori_loop(0, 32 - lax.clz(s), halve, bounds)
-        pos = (run >> 1) * (2 * s) + (g - run * s) + rank
-        src = jnp.zeros((total,), jnp.int32).at[pos].set(
-            g, unique_indices=True)
-        return tuple(o[src] for o in ops)
-
-    ops = lax.fori_loop(0, levels, merge_level, ops)
+    while size < total:
+        # a pair's second run reversed after its first is one bitonic
+        # sequence; the row number breaks ties as a stable merge does
+        ops = _bitonic_merge(
+            tuple(_rev_second_half(o, size)
+                  for o in (*ops, jnp.arange(total, dtype=jnp.int32))),
+            num_keys, 2 * size)[:-1]
+        size *= 2
     return tuple(o[:n] for o in ops)
+
+
+def _rev_second_half(x: jnp.ndarray, size: int) -> jnp.ndarray:
+    """Reverse the second ``size`` rows of every aligned ``2 * size``."""
+    pairs = x.reshape(-1, 2, size)
+    return jnp.stack([pairs[:, 0], pairs[:, 1, ::-1]], axis=1).reshape(-1)
+
+
+def _lex_lt_eq(xs: Sequence[jnp.ndarray], ys: Sequence[jnp.ndarray], shape
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Row-wise ``(x < y, x == y)``, lexicographic over column lists of
+    matching dtypes that broadcast to ``shape``, under the total order
+    lax.sort uses (NaN ranks greatest, NaN == NaN)."""
+    lt = jnp.zeros(shape, jnp.bool_)
+    all_eq = jnp.ones(shape, jnp.bool_)
+    for x, y in zip(xs, ys):
+        col_lt = x < y
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            col_lt = col_lt | (jnp.isnan(y) & ~jnp.isnan(x))
+        lt = lt | (all_eq & col_lt)
+        all_eq = all_eq & _col_eq(x, y)
+    return lt, all_eq
+
+
+def _rolled(x: jnp.ndarray, k) -> jnp.ndarray:
+    """``x[(i + k) % n]`` at i for a traced ``0 <= k <= n``: one contiguous
+    slice of the doubled column, so a loop over shift distances is one
+    program body."""
+    return lax.dynamic_slice(jnp.concatenate([x, x]), (k,), x.shape)
+
+
+def _bitonic_merge(ops: Sequence[jnp.ndarray], num_keys: int, span: int
+                   ) -> Tuple[jnp.ndarray, ...]:
+    """Sort every aligned ``span`` rows (a power of two) of 1-D ``ops``,
+    each holding a BITONIC sequence — ascending then descending — under the
+    order (first ``num_keys`` operands, then ``ops[-1]``, which must make
+    it strict: a row number).
+
+    The merge of sorted runs on accelerators: log2(span) compare-exchange
+    stages, each an elementwise pass in which row i meets row i + j
+    through shifted copies of the columns. Nothing is gathered, scattered
+    or searched, whatever the data: the chip streams such passes where it
+    gathers single int64 elements at 16.5 ns each (PERF.md 6, PR 29)."""
+    n = ops[0].shape[0]
+    keyed = (*range(num_keys), len(ops) - 1)
+    i = jnp.arange(n, dtype=jnp.int32)
+    # inside shard_map the rows vary per worker: the row numbers must enter
+    # the loop with the varying type they leave with
+    vma = tuple(jax.typeof(ops[0]).vma - jax.typeof(ops[-1]).vma)
+    if vma:
+        ops = (*ops[:-1], lax.pcast(ops[-1], vma, to="varying"))
+
+    def stage(s, ops):
+        j = jnp.int32(span // 2) >> s
+        low = (i & j) == 0  # the lower row of a pair keeps the smaller
+        up = [_rolled(o, j) for o in ops]
+        less, _ = _lex_lt_eq([ops[k] for k in keyed], [up[k] for k in keyed],
+                             (n,))
+        return tuple(
+            jnp.where(low, jnp.where(less, o, u),
+                      _rolled(jnp.where(less, u, o), n - j))
+            for o, u in zip(ops, up))
+
+    return lax.fori_loop(0, span.bit_length() - 1, stage, tuple(ops))
+
+
+@partial(jax.jit, static_argnames=("num_keys",))
+def _merge_runs(ops_a: Sequence[jnp.ndarray], ops_b: Sequence[jnp.ndarray],
+                num_keys: int) -> Tuple[jnp.ndarray, ...]:
+    """Stable merge of two runs sorted by their first ``num_keys`` operands
+    (of equal rows ``a``'s come first): ``a``, pad rows that sort last and
+    ``b`` reversed are one bitonic sequence for :func:`_bitonic_merge`."""
+    na, nb = ops_a[0].shape[0], ops_b[0].shape[0]
+    total = 1 << (na + nb - 1).bit_length()
+    rows = [jnp.concatenate([a, jnp.full((total - na - nb,),
+                                         _pad_last(a.dtype)),
+                             b.astype(a.dtype)[::-1]])
+            for a, b in zip(ops_a, ops_b)]
+    # row numbers in (a, b, pad) order: ties go to a, pads go last
+    t = jnp.arange(total, dtype=jnp.int32)
+    t = jnp.concatenate([t[:na], t[na + nb:], t[na:na + nb][::-1]])
+    out = _bitonic_merge((*rows, t), num_keys, total)
+    return tuple(o[:na + nb] for o in out[:-1])
 
 
 def _col_eq(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -317,13 +376,13 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
     """Move rows with ``keep`` to the front (order preserved); rest is dead.
 
     Equivalent of the reference's in-place ``retain`` on batch vectors.
-    GATHER formulation: output slot j reads the (j+1)-th kept row, found by
-    one searchsorted over the inclusive keep-prefix-sums — a scatter
-    formulation measured ~40ns/element on XLA:CPU (scatters lower to a
-    sequential update loop; a 16k-row x 7-col filter cost ~5ms/tick), while
-    searchsorted + gathers vectorize. On CPU with the native library the
-    whole pass is ONE sequential C++ copy (ZsetCompactImpl). Bit-identical
-    output on every path.
+    On CPU with the native library the whole pass is ONE sequential C++
+    copy (ZsetCompactImpl); without it a GATHER formulation: output slot j
+    reads the (j+1)-th kept row, found by one searchsorted over the
+    inclusive keep-prefix-sums (a scatter measured ~40ns/element on
+    XLA:CPU, where scatters lower to a sequential update loop; searchsorted
+    + gathers vectorize). On accelerators neither: shifts
+    (:func:`_compact_shift`). Bit-identical output on every path.
     """
     if cols and weights.ndim == 1 and native_kernel("compact"):
         from dbsp_tpu.zset import native_merge
@@ -331,6 +390,10 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
         if native_merge.supports(c.dtype for c in cols):
             count_kernel_dispatch("compact", "native")
             return native_merge.compact_native(cols, weights, keep)
+    if weights.ndim == 1 and jax.default_backend() != "cpu":
+        count_kernel_dispatch("compact", "xla_shift")
+        *out_cols, w = _compact_shift((*cols, weights), keep)
+        return tuple(out_cols), w
     count_kernel_dispatch("compact", "xla")
     cap = weights.shape[0]
     csum = jnp.cumsum(keep.astype(jnp.int32))
@@ -342,6 +405,80 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
         jnp.where(valid, c[src], sentinel_for(c.dtype)) for c in cols)
     w = jnp.where(valid, weights[src], 0)
     return tuple(out_cols), w
+
+
+@jax.jit
+def _compact_shift(ops: Sequence[jnp.ndarray], keep: jnp.ndarray
+                   ) -> Tuple[jnp.ndarray, ...]:
+    """:func:`compact` without a gather: a kept row moves left by the
+    number of dropped rows before it, one binary digit of that distance per
+    elementwise pass, lowest digit first. After the digits below 2**k a
+    kept row i sits at ``final_i + (dist_i >> k << k)``, which grows
+    strictly with i, so kept rows never collide and never pass each other.
+    As many passes as the longest distance has digits: none when the kept
+    rows are a prefix already (an insert-only merge). The last operand is
+    the weight column; dropped slots come out dead."""
+    n = keep.shape[0]
+    i = jnp.arange(n, dtype=jnp.int32)
+
+    # dropped rows at or before i, by doubling (a cumsum of an odd length
+    # can take the TPU's compiler half a minute: 1,114,112 rows, 30 s)
+    def count(s, c):
+        j = jnp.int32(1) << s
+        return c + jnp.where(i >= j, _rolled(c, n - j), 0)
+
+    dist = lax.fori_loop(0, (n - 1).bit_length(), count,
+                         (~keep).astype(jnp.int32))
+    dist = jnp.where(keep, dist, 0)
+
+    def stage(carry):
+        j, keep, dist, ops = carry
+        moves = keep & ((dist & j) != 0)
+        arrives = _rolled(moves, j) & (i < n - j)
+        return (j * 2, arrives | (keep & ~moves),
+                jnp.where(arrives, _rolled(dist, j), dist),
+                tuple(jnp.where(arrives, _rolled(o, j), o) for o in ops))
+
+    top = jnp.max(dist)
+    _, keep, _, (*cols, w) = lax.while_loop(
+        lambda carry: carry[0] <= top, stage,
+        (jnp.int32(1), keep, dist, tuple(ops)))
+    return (*(jnp.where(keep, c, sentinel_for(c.dtype)) for c in cols),
+            jnp.where(keep, w, 0))
+
+
+def _net_sorted(cols: Sequence[jnp.ndarray], w: jnp.ndarray
+                ) -> jnp.ndarray:
+    """Weights of SORTED rows netted per group of equal rows: the group's
+    sum on ONE of its rows, 0 on the others (every column is a key, so the
+    group's rows are interchangeable)."""
+    n = w.shape[0]
+    dup = rows_equal_prev(cols, n=n)
+    if jax.default_backend() != "cpu":
+        return _group_sums(dup, w)
+    seg = jnp.cumsum(~dup) - 1  # segment id per row
+    sums = jax.ops.segment_sum(w, seg, num_segments=n)
+    return jnp.where(dup, 0, sums[seg]).astype(w.dtype)
+
+
+@jax.jit
+def _group_sums(dup: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """:func:`_net_sorted` on accelerators: a segmented running sum by
+    doubling — elementwise passes instead of a scatter-add and a gather,
+    as many as the longest group of equal rows needs; a group's LAST row
+    holds its sum. Rows i < j have met their group's start by the pass of
+    distance j, so what :func:`_rolled` wraps around is never added."""
+    n = w.shape[0]
+
+    def stage(carry):
+        j, met, w = carry
+        return (j * 2, met | _rolled(met, n - j),
+                jnp.where(met, w, w + _rolled(w, n - j)))
+
+    _, _, w = lax.while_loop(lambda carry: ~jnp.all(carry[1]), stage,
+                             (jnp.int32(1), ~dup, w))
+    last = jnp.concatenate([~dup[1:], jnp.ones((1,), jnp.bool_)])
+    return jnp.where(last, w, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -372,14 +509,9 @@ def consolidate_cols(cols: Sequence[jnp.ndarray], weights: jnp.ndarray
     else:
         count_consolidate_path("sort")
     count_kernel_dispatch("consolidate", "xla")
-    cap = weights.shape[0]
     cols, (weights,) = sort_rows(cols, (weights,))
-    dup = rows_equal_prev(cols, n=cap)
-    seg = jnp.cumsum(~dup) - 1  # segment id per row, first-of-group gets new id
-    sums = jax.ops.segment_sum(weights, seg, num_segments=cap)
-    w_new = jnp.where(dup, 0, sums[seg]).astype(weights.dtype)
-    keep = w_new != 0
-    return compact(cols, w_new, keep)
+    w_new = _net_sorted(cols, weights)
+    return compact(cols, w_new, w_new != 0)
 
 
 # ---------------------------------------------------------------------------
@@ -390,12 +522,14 @@ def consolidate_cols(cols: Sequence[jnp.ndarray], weights: jnp.ndarray
 def merge_strategy() -> str:
     """Backend-dependent choice for combining sorted row sets.
 
-    ``rank`` (cross-rank binary-search merge) does O(log n) *dependent*
-    gather passes — cheap on TPU where a bitonic ``lax.sort`` costs
-    O(n log^2 n) full passes of HBM traffic, but measurably SLOWER than the
-    XLA:CPU native sort (one fused C++ quicksort). So on accelerators:
-    rank-merge. On CPU: a ``jax.pure_callback`` into the native two-pointer
-    merge (native/zset_merge.cpp) — already-sorted runs need no sort, and
+    On accelerators ``bitonic``: the two runs, one reversed, are a bitonic
+    sequence that log2(n) elementwise compare-exchange passes sort
+    (:func:`_bitonic_merge`) — O(n log n) streamed bytes and no gather. The
+    cross-rank merge it replaced did O(log n) *dependent* gather passes,
+    and a v5e gathers single int64 elements at 16.5 ns each: 3.3 s to merge
+    131,072 rows into 1,048,576 against 17 ms now (PERF.md 6, PR 29). On
+    CPU: a ``jax.pure_callback`` into the native two-pointer merge
+    (native/zset_merge.cpp) — already-sorted runs need no sort, and
     XLA:CPU's comparator-based multi-operand sort measured ~50x slower than
     the C++ walk at spine-tail shapes (1.2s vs ~25ms for 1.5M rows x 7
     cols). ``sort`` remains the fallback when the native library can't
@@ -405,7 +539,7 @@ def merge_strategy() -> str:
     import jax
 
     if jax.default_backend() != "cpu":
-        return "rank"
+        return "bitonic"
     return "native" if native_kernel("merge") else "sort"
 
 
@@ -414,20 +548,15 @@ def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
                       cols_b: Sequence[jnp.ndarray], w_b: jnp.ndarray
                       ) -> Tuple[Tuple[jnp.ndarray, ...], jnp.ndarray]:
     """Merge two SORTED row sets into one consolidated set, capacity |a|+|b|.
-    Strategy is backend-dependent (see :func:`merge_strategy`); the rank
-    path below is the TPU fast path.
+    Strategy is backend-dependent (see :func:`merge_strategy`).
 
     The replacement for the reference's pairwise batch ``Merger``
-    (``trace/ord/merge_batcher``): since both inputs are sorted, output
-    positions follow from cross-ranks — row i of ``a`` lands at
-    ``i + |{b < a_i}|``, row j of ``b`` at ``j + |{a <= b_j}|`` — so the
-    whole merge is two binary-search probes (O(n log m)) plus scatters, not
-    an O((n+m) log(n+m)) re-sort. The position map stays bijective even
-    with duplicate rows (each side's equal block lands contiguously, a's
-    block first, because the ``+i``/``+j`` terms advance within a block).
-    Equal rows land adjacent; their weights are summed and zero-net rows
-    dropped, so the result is consolidated. Dead sentinel rows merge into
-    the dead tail and vanish in the compaction.
+    (``trace/ord/merge_batcher``): both inputs are sorted, so nothing is
+    re-sorted — the CPU walks them with two pointers, an accelerator runs
+    the merge network over them (:func:`_merge_runs`; of equal rows ``a``'s
+    come first). Equal rows land adjacent; their weights are summed and
+    zero-net rows dropped, so the result is consolidated. Dead sentinel
+    rows merge into the dead tail and vanish in the compaction.
     """
     if not cols_a:  # zero-column (unit-row) sets: nothing to order
         return consolidate_cols((), jnp.concatenate([w_a, w_b]))
@@ -446,11 +575,6 @@ def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
         cols = tuple(jnp.concatenate([a, b.astype(a.dtype)])
                      for a, b in zip(cols_a, cols_b))
         return consolidate_cols(cols, jnp.concatenate([w_a, w_b]))
-    na, nb = w_a.shape[0], w_b.shape[0]
-    # rank path (accelerators): the probe + position-scatter inner loop,
-    # either the Pallas program (zset/pallas_kernels.py) or the XLA
-    # formulation — bit-identical buffers either way; the netting +
-    # compaction tail below is shared.
     from dbsp_tpu.zset import pallas_kernels
 
     if pallas_kernels.use_pallas("rank_merge", (*cols_a, *cols_b)) and \
@@ -458,24 +582,11 @@ def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
         count_kernel_dispatch("merge", "pallas")
         out_cols, w = pallas_kernels.rank_merge_scatter(
             cols_a, w_a, cols_b, w_b)
-        out_cols = list(out_cols)
     else:
-        count_kernel_dispatch("merge", "xla")
-        ra = lex_probe(cols_b, cols_a, side="left")   # b-rows strictly < a_i
-        rb = lex_probe(cols_a, cols_b, side="right")  # a-rows <= b_j
-        pos_a = jnp.arange(na, dtype=jnp.int32) + ra
-        pos_b = jnp.arange(nb, dtype=jnp.int32) + rb
-        out_cols = []
-        for ca, cb in zip(cols_a, cols_b):
-            buf = sentinel_fill((na + nb,), ca.dtype)
-            out_cols.append(
-                buf.at[pos_a].set(ca).at[pos_b].set(cb.astype(ca.dtype)))
-        w = jnp.zeros((na + nb,), w_a.dtype).at[pos_a].set(w_a) \
-            .at[pos_b].set(w_b)
-    dup = rows_equal_prev(out_cols, n=na + nb)
-    seg = jnp.cumsum(~dup) - 1
-    sums = jax.ops.segment_sum(w, seg, num_segments=na + nb)
-    w = jnp.where(dup, 0, sums[seg]).astype(w_a.dtype)
+        count_kernel_dispatch("merge", "xla_bitonic")
+        *out_cols, w = _merge_runs((*cols_a, w_a), (*cols_b, w_b),
+                                   len(cols_a))
+    w = _net_sorted(out_cols, w)
     return compact(out_cols, w, w != 0)
 
 
@@ -549,17 +660,11 @@ def _lex_le_rows(table_cols, idx, query_cols, strict: bool):
     table dtype silently truncates a wider query (the same hazard class
     :func:`searchsorted1` fixes; a no-op when dtypes already match, which
     the schema-pinned engine paths guarantee)."""
-    lt = jnp.zeros(idx.shape, jnp.bool_)
-    all_eq = jnp.ones(idx.shape, jnp.bool_)
-    for t, q in zip(table_cols, query_cols):
-        dt = jnp.promote_types(t.dtype, q.dtype)
-        tv = t[idx].astype(dt)
-        qv = q.astype(dt)
-        col_lt = tv < qv
-        if jnp.issubdtype(dt, jnp.floating):
-            col_lt = col_lt | (jnp.isnan(qv) & ~jnp.isnan(tv))
-        lt = lt | (all_eq & col_lt)
-        all_eq = all_eq & _col_eq(tv, qv)
+    dts = [jnp.promote_types(t.dtype, q.dtype)
+           for t, q in zip(table_cols, query_cols)]
+    lt, all_eq = _lex_lt_eq(
+        [t[idx].astype(dt) for t, dt in zip(table_cols, dts)],
+        [q.astype(dt) for q, dt in zip(query_cols, dts)], idx.shape)
     return lt if strict else lt | all_eq
 
 

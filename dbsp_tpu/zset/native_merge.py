@@ -16,7 +16,7 @@ converting >=8MB operands on the callback thread).
 Only integer/bool columns take this path (every column is widened to int64
 for the call; sign-extension preserves lexicographic order).  Float columns
 fall back to the XLA sort.  The TPU backend never loads this library — its
-rank-merge strategy is pure XLA and runs on-device (kernels.merge_strategy).
+merge network is pure XLA and runs on-device (kernels.merge_strategy).
 
 Reference analog: the pairwise batch merger the spine drives,
 crates/dbsp/src/trace/ord/merge_batcher.rs (the same two-pointer walk,

@@ -214,7 +214,7 @@ def test_merge_sorted_cols_rank_path_via_pallas(pallas_interpret,
     rng = np.random.default_rng(20)
     a = _consolidated(rng, 40, 64, key_range=10)
     b = _consolidated(rng, 70, 128, key_range=10)
-    monkeypatch.setattr(kernels, "merge_strategy", lambda: "rank")
+    monkeypatch.setattr(kernels, "merge_strategy", lambda: "bitonic")
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
     got = kernels.merge_sorted_cols(a.cols, a.weights, b.cols, b.weights)
     assert kernels.KERNEL_DISPATCH_COUNTS.get(("merge", "pallas"), 0) > \

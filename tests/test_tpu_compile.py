@@ -3,9 +3,11 @@ for one DESCRIBED v5e chip (``jax.experimental.topologies``; nothing runs).
 
 Guards what interpret mode and the CPU backend cannot: that the plain-XLA
 kernels of the served q4 path lower at a 40,000-row delta (capacity bucket
-65,536), that the large sort is the chunked merge sort there, and that the
-dispatch selects on a TPU exactly the Pallas programs its compiler accepts
-(``kernels.PALLAS_TPU_COMPILED`` <=> compiles).
+65,536) and at a maintenance drain's shapes (65,536 rows into 1,048,576),
+that the large sort is the chunked merge sort there, that no merge of sorted
+runs gathers or scatters, and that the dispatch selects on a TPU exactly the
+Pallas programs its compiler accepts (``kernels.PALLAS_TPU_COMPILED`` <=>
+compiles).
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU's library, and every xdist
@@ -86,10 +88,18 @@ def _case(name, c):
     """(fn, args) of one main-path kernel at the 40,000-event tick's sizes."""
     rows = tuple(c.shape(CAP, d) for d in BID)
     ladder = (CAP, 4 * CAP, 16 * CAP)  # l0 / l1 / tail of a 1M-row trace
+    level = tuple(c.shape(16 * CAP, d) for d in BID)  # a drain's target
     if name == "consolidate":
         return kernels.consolidate_cols, (rows, c.shape(CAP))
+    if name == "consolidate_drain":  # the same rows, unsorted
+        n = 17 * CAP
+        return kernels.consolidate_cols, (
+            tuple(c.shape(n, d) for d in BID), c.shape(n))
     if name in ("merge_sorted", "rank_merge"):
         return kernels.merge_sorted_cols, (rows, c.shape(CAP),
+                                           rows, c.shape(CAP))
+    if name == "merge_sorted_drain":  # 65,536 rows into 1,048,576
+        return kernels.merge_sorted_cols, (level, c.shape(16 * CAP),
                                            rows, c.shape(CAP))
     if name == "lex_probe":
         return (lambda t, q: kernels.lex_probe(t, q, "left")), (
@@ -117,8 +127,9 @@ def _case(name, c):
     raise AssertionError(name)
 
 
-@pytest.mark.parametrize("name", ["consolidate", "merge_sorted", "lex_probe",
-                                  "join_ladder", "gather_ladder"])
+@pytest.mark.parametrize("name", [
+    "consolidate", "consolidate_drain", "merge_sorted", "merge_sorted_drain",
+    "lex_probe", "join_ladder", "gather_ladder"])
 def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     fn, args = _case(name, compile_for)
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
@@ -126,24 +137,49 @@ def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     assert "tpu_custom_call" not in compiled.as_text()  # no Mosaic kernel
     took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
             if n > before.get(k, 0)}
-    assert took and {b for _, b in took} == {"xla"}, took
+    assert took and {b for _, b in took} <= {
+        "xla", "xla_bitonic", "xla_shift"}, took
+
+
+def _sort_of(n):
+    """The signature a sort of ``n`` bids rows prints after its comparator."""
+    operands = ", ".join(f"tensor<{n}x{'i32' if d == I32 else 'i64'}>"
+                         for d in (*BID, I64))
+    return f"}}) : ({operands}) ->"
+
+
+@pytest.mark.parametrize("name", ["merge_sorted", "merge_sorted_drain",
+                                  "consolidate", "consolidate_drain"])
+def test_merges_of_sorted_runs_gather_nothing_on_tpu(name, compile_for):
+    """Off the CPU a merge of sorted runs, the merge levels of a large
+    sort, and the netting and compaction behind both are elementwise
+    passes over contiguous rows: no gather and no scatter, in a loop or
+    out of one (the chip gathers single int64 elements at 16.5 ns each —
+    PERF.md 6, PR 29), and no sort but the consolidate's one SORT_CHUNK_ROWS
+    chunk sort."""
+    fn, args = _case(name, compile_for)
+    text = jax.jit(fn).lower(*args).as_text()
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
+    if name.startswith("merge"):
+        assert "stablehlo.sort" not in text
+    else:
+        assert text.count("stablehlo.sort") == 1
+        assert _sort_of(kernels.SORT_CHUNK_ROWS) in text
 
 
 def test_large_sort_is_chunked_on_tpu(compile_for):
     """Off the CPU the consolidate's sort never hands XLA more than
     SORT_CHUNK_ROWS rows at once (a 65,536-row, 5-column int64 sort takes
-    minutes to compile for this chip; the chunk takes seconds)."""
+    minutes to compile for this chip; the chunk takes seconds): one chunk
+    sort in a ``lax.map``, and merge levels that sort and gather nothing."""
     cols = tuple(compile_for.shape(CAP, d) for d in BID)
     text = jax.jit(lambda c, w: kernels.sort_rows(c, (w,))).lower(
         cols, compile_for.shape(CAP)).as_text()
     assert text.count("stablehlo.sort") == 1
-
-    def operands(n):  # the sort's operand types, as its signature prints them
-        return ", ".join(f"tensor<{n}x{'i32' if d == I32 else 'i64'}>"
-                         for d in (*BID, I64))
-
-    assert f"({operands(kernels.SORT_CHUNK_ROWS)}) ->" in text
-    assert f"({operands(CAP)}) ->" not in text
+    assert _sort_of(kernels.SORT_CHUNK_ROWS) in text
+    assert "stablehlo.gather" not in text
+    assert "stablehlo.scatter" not in text
 
 
 @pytest.mark.parametrize("name", PALLAS_PROGRAMS)

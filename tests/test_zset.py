@@ -254,3 +254,251 @@ def test_chunked_sort_under_shard_map():
         want = lax.sort((a[k], w[k]), num_keys=1, is_stable=True)
         for x, y in zip(want, got):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y[k]))
+
+
+# ---------------------------------------------------------------------------
+# The accelerator formulations (bitonic merge network, shift compaction,
+# doubling segment sums), steered onto the CPU: bit-identical to the
+# stable lax.sort + netting and to the CPU backend's own (native) kernels
+# ---------------------------------------------------------------------------
+
+I64_MAX = np.iinfo(np.int64).max
+I32_MAX = np.iinfo(np.int32).max
+
+
+def _sorted_run(rows, cap, dtypes):
+    """A sorted row set of capacity ``cap``: ``rows`` = [(tuple, weight)]
+    in any order, sorted here by Python's tuple order with NaN greatest;
+    dead sentinel tail. Equal rows are kept apart (not netted)."""
+    def key(rw):
+        return tuple((1, 0.0) if isinstance(v, float) and np.isnan(v)
+                     else (0, v) for v in rw[0])
+
+    rows = sorted(rows, key=key)
+    pad = cap - len(rows)
+    assert pad >= 0
+    cols = tuple(
+        jnp.concatenate([jnp.asarray([r[0][i] for r in rows], dt).reshape(-1),
+                         kernels.sentinel_fill((pad,), dt)])
+        for i, dt in enumerate(dtypes))
+    w = jnp.asarray([r[1] for r in rows] + [0] * pad, jnp.int64)
+    return cols, w
+
+
+def _sort_and_net(cols, w):
+    """The reference: one stable lax.sort of all rows, equal neighbours
+    summed on the host, survivors packed to the front."""
+    from jax import lax
+
+    n = w.shape[0]
+    *cols, w = lax.sort((*cols, w), num_keys=len(cols), is_stable=True) \
+        if cols else (w,)
+    cols = [np.asarray(c) for c in cols]
+    w = np.asarray(w)
+    out = []  # (row index kept, net weight)
+    for i in range(n):
+        same = out and all(
+            c[i] == c[out[-1][0]] or (c.dtype.kind == "f" and
+                                      np.isnan(c[i]) and
+                                      np.isnan(c[out[-1][0]]))
+            for c in cols)
+        if same:
+            out[-1][1] += int(w[i])
+        else:
+            out.append([i, int(w[i])])
+    live = [(i, x) for i, x in out if x != 0]
+    idx = [i for i, _ in live]
+    pad = n - len(live)
+    return (tuple(np.concatenate([c[idx], np.full(pad, np.asarray(
+        kernels.sentinel_for(c.dtype)), c.dtype)]) for c in cols),
+        np.asarray([x for _, x in live] + [0] * pad, np.int64))
+
+
+def _assert_same(got, want):
+    got = (*got[0], got[1])
+    want = (*want[0], want[1])
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _merge_case(name):
+    """(cols_a, w_a, cols_b, w_b) of one merge shape worth its own row."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    two = (jnp.int64, jnp.int32)
+
+    def rand(live, hi=40):
+        seen = {}
+        for _ in range(live):
+            seen[(int(rng.integers(0, hi)), int(rng.integers(0, 3)))] = \
+                int(rng.choice([-2, -1, 1, 2]))
+        return list(seen.items())
+
+    if name == "odd_sizes":  # neither side nor the sum a power of two
+        return (*_sorted_run(rand(400), 513, two),
+                *_sorted_run(rand(90), 101, two))
+    if name == "one_row_into_many":
+        return (*_sorted_run(rand(1), 1, two),
+                *_sorted_run(rand(250), 300, two))
+    if name == "all_dead_side":
+        return (*_sorted_run([], 8, two), *_sorted_run(rand(60), 64, two))
+    if name == "zero_length_side":
+        return (*_sorted_run(rand(60), 64, two), *_sorted_run([], 0, two))
+    if name == "full_capacity":  # no dead tail on either side
+        a = [((k, 0), 1) for k in range(0, 64)]
+        b = [((k, 0), -1) for k in range(32, 96)]  # half of it cancels
+        return (*_sorted_run(a, 64, two), *_sorted_run(b, 64, two))
+    if name == "everything_cancels":
+        a = rand(200)
+        return (*_sorted_run(a, 256, two),
+                *_sorted_run([(r, -w) for r, w in a], 256, two))
+    if name == "long_equal_runs":
+        # sorted but NOT consolidated: 300 equal rows on one side, 77 on
+        # the other — one group across the a/b seam, many network stages
+        # and nine doublings of the segment sum
+        a = [((7, 1), 1)] * 300 + [((9, 0), 2)]
+        b = [((7, 1), -1)] * 77 + [((7, 2), 5), ((9, 0), -2)]
+        return (*_sorted_run(a, 320, two), *_sorted_run(b, 96, two))
+    if name == "live_sentinel_row":
+        # a LIVE row whose every key is the dead-row sentinel sorts among
+        # the dead rows and the pad rows of the network, and must survive
+        a = [((I64_MAX, I32_MAX), 3), ((1, 1), 1)]
+        b = [((I64_MAX, I32_MAX), 4), ((I64_MAX, 0), 1)]
+        return (*_sorted_run(a, 5, two), *_sorted_run(b, 9, two))
+    if name == "nan_inf_floats":
+        f = (jnp.int64, jnp.float32)
+        nan, inf = float("nan"), float("inf")
+        a = [((1, nan), 1), ((1, inf), 2), ((1, -inf), 1), ((2, 0.5), 1),
+             ((2, nan), -1)]
+        b = [((1, nan), -1), ((1, inf), 2), ((2, nan), -1), ((3, -inf), 1),
+             ((0, nan), 7)]
+        return (*_sorted_run(a, 8, f), *_sorted_run(b, 16, f))
+    if name == "int32_into_int64":  # b's columns are cast to a's
+        a = _sorted_run(rand(30), 32, (jnp.int64, jnp.int64))
+        b = _sorted_run(rand(30), 32, (jnp.int32, jnp.int32))
+        return (*a, *b)
+    if name == "five_columns":  # the bids row
+        five = (jnp.int64, jnp.int64, jnp.int64, jnp.int32, jnp.int64)
+        rows = lambda n: list({  # noqa: E731
+            tuple(int(rng.integers(0, 4)) for _ in five): 1
+            for _ in range(n)}.items())
+        return (*_sorted_run(rows(700), 1024, five),
+                *_sorted_run(rows(100), 128, five))
+    raise AssertionError(name)
+
+
+MERGE_CASES = ["odd_sizes", "one_row_into_many", "all_dead_side",
+               "zero_length_side", "full_capacity", "everything_cancels",
+               "long_equal_runs", "live_sentinel_row", "nan_inf_floats",
+               "int32_into_int64", "five_columns"]
+
+
+@pytest.fixture
+def accelerator_dispatch(monkeypatch):
+    """Steer the backend-keyed dispatch to its accelerator branches."""
+    import jax
+
+    monkeypatch.delenv("DBSP_TPU_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.mark.parametrize("name", MERGE_CASES)
+def test_accelerator_merge_bit_identical(name, monkeypatch):
+    import jax
+
+    cols_a, w_a, cols_b, w_b = _merge_case(name)
+    cols = tuple(jnp.concatenate([a, b.astype(a.dtype)])
+                 for a, b in zip(cols_a, cols_b))
+    want = _sort_and_net(cols, jnp.concatenate([w_a, w_b]))
+    if name != "long_equal_runs":  # the native walk nets across sides only
+        _assert_same(kernels.merge_sorted_cols(cols_a, w_a, cols_b, w_b), want)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    got = jax.jit(kernels.merge_sorted_cols)(cols_a, w_a, cols_b, w_b)
+    took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
+            if n > before.get(k, 0)}
+    assert took == {("merge", "xla_bitonic"), ("compact", "xla_shift")}
+    _assert_same(got, want)
+
+
+@pytest.mark.parametrize("n", [1, 2, 777, 2048, 2049, 5000])
+def test_accelerator_consolidate_bit_identical(n, accelerator_dispatch):
+    """Below, at and above SORT_CHUNK_ROWS: groups of up to dozens of
+    equal rows, rows that net to zero, dead rows scattered through the
+    input, an int32 beside an int64 column."""
+    rng = np.random.default_rng(n)
+    cols = (jnp.asarray(rng.integers(0, 40, n)),
+            jnp.asarray(rng.integers(0, 3, n).astype(np.int32)))
+    w = jnp.asarray(rng.integers(-1, 2, n))
+    dead = jnp.asarray(rng.random(n) < 0.2)
+    cols = tuple(jnp.where(dead, kernels.sentinel_for(c.dtype), c)
+                 for c in cols)
+    w = jnp.where(dead, 0, w)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    got = kernels.consolidate_cols(cols, w)
+    if n > kernels.SORT_CHUNK_ROWS:
+        assert kernels.KERNEL_DISPATCH_COUNTS[("sort_merge", "xla_bitonic")] \
+            > before.get(("sort_merge", "xla_bitonic"), 0)
+    _assert_same(got, _sort_and_net(cols, w))
+
+
+def test_accelerator_zero_column_rows(accelerator_dispatch):
+    """Unit rows (no key column): every row is equal, the weights net."""
+    w_a = jnp.asarray([3, -1, 0, 0], jnp.int64)
+    w_b = jnp.asarray([4, 0], jnp.int64)
+    cols, w = kernels.merge_sorted_cols((), w_a, (), w_b)
+    assert cols == () and w.tolist() == [6, 0, 0, 0, 0, 0]
+    cols, w = kernels.merge_sorted_cols((), w_a, (), -w_a)
+    assert w.tolist() == [0] * 8
+
+
+@pytest.mark.parametrize("pattern", ["all", "none", "prefix", "suffix",
+                                     "alternate", "random", "one_at_end"])
+def test_accelerator_compact_bit_identical(pattern, monkeypatch):
+    import jax
+
+    n = 333
+    rng = np.random.default_rng(4)
+    keep = {"all": np.ones(n, bool), "none": np.zeros(n, bool),
+            "prefix": np.arange(n) < 100, "suffix": np.arange(n) >= 200,
+            "alternate": np.arange(n) % 2 == 1,
+            "random": rng.random(n) < 0.4,
+            "one_at_end": np.arange(n) == n - 1}[pattern]
+    cols = (jnp.asarray(rng.integers(0, 1 << 40, n)),
+            jnp.asarray(rng.integers(0, 9, n).astype(np.int32)),
+            jnp.asarray(rng.standard_normal(n).astype(np.float32)))
+    w = jnp.asarray(rng.integers(1, 4, n))
+    keep = jnp.asarray(keep)
+    monkeypatch.setenv("DBSP_TPU_NATIVE", "0")  # floats: the XLA reference
+    want = kernels.compact(cols, w, keep)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _assert_same(jax.jit(kernels.compact)(cols, w, keep), want)
+
+
+def test_accelerator_merge_under_shard_map(accelerator_dispatch):
+    """Per-worker slices merge independently inside shard_map, as
+    lifted_merge runs them."""
+    import jax
+    from jax import shard_map
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("workers",))
+    parts = [_merge_case("odd_sizes"), _merge_case("odd_sizes")]
+    # the second worker's b side is negated a: its slice cancels in part
+    ca, wa, cb, wb = parts[1]
+    parts[1] = (ca, wa, tuple(c[:101] for c in ca), -wa[:101])
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *parts)
+
+    def body(ca, wa, cb, wb):
+        cols, w = kernels.merge_sorted_cols(
+            tuple(c[0] for c in ca), wa[0], tuple(c[0] for c in cb), wb[0])
+        return tuple(c[None] for c in cols), w[None]
+
+    spec = P("workers")
+    got = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,) * 4,
+                            out_specs=(spec, spec)))(*stacked)
+    for k, (ca, wa, cb, wb) in enumerate(parts):
+        cols = tuple(jnp.concatenate([a, b]) for a, b in zip(ca, cb))
+        _assert_same((tuple(c[k] for c in got[0]), got[1][k]),
+                     _sort_and_net(cols, jnp.concatenate([wa, wb])))

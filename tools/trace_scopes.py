@@ -18,7 +18,8 @@ program name is all they have.
 Every device nanosecond is counted once, by the outermost operation event
 that covers it (an operation with a body — a ``while``, a fusion — covers
 its body's events). An outermost operation with no scope in its path is
-``unscoped``. The host side of the same trace holds the served path's phase
+``unscoped``; ``by_op_s`` names the outermost operations themselves, each
+with its node and kernel scope. The host side of the same trace holds the served path's phase
 spans as ``dbsp.<span>`` annotations (``obs/tracing.py``): for each the
 table gives its own host seconds (what no child annotation covers) and how
 much of that the device was busy or idle.
@@ -226,7 +227,8 @@ def reduce(path: str, n_stats: int = 0) -> dict:
                             census.append({"name": e.name[:300],
                                            "scope": scope})
                         ops.append((int(e.start_ns),
-                                    int(e.start_ns + e.duration_ns), scope))
+                                    int(e.start_ns + e.duration_ns), scope,
+                                    e.name))
                 elif line.name == "XLA Modules":
                     modules = [(int(e.start_ns),
                                 int(e.start_ns + e.duration_ns),
@@ -245,7 +247,8 @@ def reduce(path: str, n_stats: int = 0) -> dict:
     by_program: dict = {}
     by_node: dict = {}
     by_kernel: dict = {}
-    for start, end, scope in top:
+    by_op: dict = {}
+    for start, end, scope, op in top:
         i = bisect.bisect_right(mod_starts, start) - 1
         program = modules[i][2] if i >= 0 and start < modules[i][1] \
             else "no_module"
@@ -257,6 +260,12 @@ def reduce(path: str, n_stats: int = 0) -> dict:
         by_node[key] = by_node.get(key, 0) + ns
         key = kernel.group(1) if kernel else "unscoped"
         by_kernel[key] = by_kernel.get(key, 0) + ns
+        # the operation itself, so that ``unscoped`` has names: the leading
+        # "%name = type[shape] opcode" of its text, and its node and kernel
+        scoped = " ".join(m.group(1) for m in (node, kernel) if m)
+        key = f"{program}/{op.split('(')[0].strip()[:100]} " \
+              f"[{scoped or 'unscoped'}]"
+        by_op[key] = by_op.get(key, 0) + ns
         if kernel:
             key = f"{kernel.group(1)} in {program}/" \
                   f"{node.group(1) if node else 'unscoped'}"
@@ -264,7 +273,7 @@ def reduce(path: str, n_stats: int = 0) -> dict:
     runs: dict = {}
     for _, _, name in modules:
         runs[name] = runs.get(name, 0) + 1
-    busy = [(s, e) for s, e, _ in top]
+    busy = [(s, e) for s, e, *_ in top]
     busy_starts = [s for s, _ in busy]
     phases: dict = {}
     for evs in host:
@@ -293,7 +302,7 @@ def reduce(path: str, n_stats: int = 0) -> dict:
         "scope_stats": stat_names,
         "program_runs": runs,
         "by_program_s": table(by_program), "by_node_s": table(by_node),
-        "by_kernel_s": table(by_kernel),
+        "by_kernel_s": table(by_kernel), "by_op_s": table(by_op),
         "host_phases": dict(sorted(phases.items(),
                                    key=lambda kv: -kv[1]["host_s"])),
         "host_annotations": host,
@@ -363,7 +372,7 @@ def main(argv=None) -> int:
     if args.spans:
         with open(args.spans) as f:
             out["clocks"] = tie_clocks(annotations, json.load(f))
-    for key in ("by_node_s", "by_kernel_s", "by_program_s"):
+    for key in ("by_node_s", "by_kernel_s", "by_program_s", "by_op_s"):
         out[key] = dict(list(out[key].items())[:args.top])
     print(json.dumps(out, indent=1))
     return 0
