@@ -250,16 +250,35 @@ def run_served(ticks: int, events_per_tick: int, seed: int,
     total_s = time.perf_counter() - t_start
     sharding = None
     if workers > 1:
+        from dbsp_tpu.parallel import exchange
+
         # what one chip cannot show: the state really spans the workers
         leaves = jax.tree_util.tree_leaves(driver.ch.states)
         spans = sorted({len(x.sharding.device_set) for x in leaves
                         if hasattr(x, "sharding")})
-        in_use = [(d.memory_stats() or {}).get("bytes_in_use")
-                  for d in devices[:workers]]
+        # spanning the mesh is not enough: a leaf a program returned
+        # replicated holds every worker's slice on every chip
+        replicated = sum(1 for x in leaves if hasattr(x, "sharding")
+                         and x.sharding.is_fully_replicated)
+
+        def per_chip(stat):
+            return [(d.memory_stats() or {}).get(stat)
+                    for d in devices[:workers]]
+
+        in_use = per_chip("bytes_in_use")
+        # per exchange site [worst worker's live rows, bucket capacity] at
+        # the last validation, and the bucket overflows replayed, by kind
         sharding = {"state_leaves": len(leaves),
-                    "devices_per_leaf": spans, "bytes_in_use": in_use}
+                    "devices_per_leaf": spans,
+                    "replicated_leaves": replicated, "bytes_in_use": in_use,
+                    "peak_bytes_in_use": per_chip("peak_bytes_in_use"),
+                    "exchange_sites": {
+                        f"{kind}:n{node}": list(v) for (kind, node), v
+                        in sorted(exchange.EXCHANGE_SITE_ROWS.items())},
+                    "exchange_overflows":
+                        dict(exchange.EXCHANGE_OVERFLOW_COUNTS)}
         # (the CPU backend, where tests rehearse this, reports no stats)
-        ok = ok and spans == [workers] and (
+        ok = ok and spans == [workers] and not replicated and (
             devices[0].platform == "cpu" or all(in_use))
     stats = devices[0].memory_stats() or {}
     summary = {

@@ -511,6 +511,14 @@ class CInput(CNode):
         ctx.require(self, "input", out.live_count())
         return None, out.with_cap(self.caps["input"])
 
+    def note_requirement(self, key: str, required: int) -> None:
+        # only a sharded source registers the requirement: the worst
+        # worker's share of the tick against its static capacity
+        from dbsp_tpu.parallel.exchange import note_exchange_site
+
+        note_exchange_site("input", self.node.index, required,
+                           self.caps["input"])
+
 
 class CPure(CNode):
     """Map/filter/flat_map — the host op's kernel is already a pure
@@ -1306,7 +1314,11 @@ class CExchange(CNode):
 
     def note_requirement(self, key: str, required: int) -> None:
         if key == "exchange":
+            from dbsp_tpu.parallel.exchange import note_exchange_site
+
             self.last_required = required
+            note_exchange_site("exchange", self.node.index, required,
+                               self.caps["exchange"])
 
     def eval(self, ctx, state, inputs):
         from dbsp_tpu.parallel.exchange import exchange_local

@@ -195,14 +195,32 @@ def _copy_tree(tree):
     return jax.tree_util.tree_map(jnp.copy, tree)
 
 
+def _on_workers(levels):
+    """Pin what a drain returns to its levels' own placement: on a worker
+    mesh, one slice a worker. An emptied level is all constants, and left
+    to itself the TPU's compiler returns it REPLICATED on every chip
+    (asked for a described v5e 2x2; the CPU's keeps it sharded): the next
+    step then meets a state leaf under a new input sharding, which is a
+    whole new SPMD step program — on four chips one after each level
+    pair's first drain, 33 s from the cache and 97–109 s compiled, inside
+    the serving window (CHANGES.md, PR 30)."""
+    if not levels[0].sharded:
+        return levels
+    from dbsp_tpu.parallel.lift import current_mesh
+    from dbsp_tpu.parallel.mesh import worker_sharding
+
+    return jax.lax.with_sharding_constraint(
+        levels, worker_sharding(current_mesh()))
+
+
 @partial(jax.jit, static_argnums=(2,), donate_argnums=(0, 1))
 def _drain_pair(receiver: Batch, source: Batch, cap: int):
     """One maintenance drain as a single jitted dispatch (eager Batch ops
     cost ~10 dispatches each; this runs every few validation intervals on
     every leveled trace, so dispatch overhead was measurable)."""
     with jax.named_scope("maintain.drain"):
-        return (receiver.merge_with(source).with_cap(cap),
-                source.masked(False))
+        return _on_workers((receiver.merge_with(source).with_cap(cap),
+                            source.masked(False)))
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(0, 1))
@@ -235,7 +253,7 @@ def _drain_slice(receiver: Batch, source: Batch, n, cap: int):
         # whole step program on the next tick (run metadata is static
         # data).
         rest = rolled.masked(idx < source.cap - n).tagged((source.cap,))
-        return receiver.merge_with(take).with_cap(cap), rest
+        return _on_workers((receiver.merge_with(take).with_cap(cap), rest))
 
 
 class CompiledHandle:
@@ -587,6 +605,16 @@ class CompiledHandle:
         dict rides the donated argument; cold levels ride separately so
         XLA device_puts them per call (transient buffers) and the program
         never returns them as persistent outputs."""
+        if self.mesh is not None:
+            # ONE input placement for the SPMD step program, whichever
+            # host-side program last wrote a leaf (a drain, a re-pad after
+            # a grow, a restore): to the jit a leaf under another sharding
+            # is another program. A no-op for leaves already so placed; no
+            # cold operand exists under a mesh (see set_residency).
+            from dbsp_tpu.parallel.mesh import worker_sharding
+
+            return jax.device_put(self.states,
+                                  worker_sharding(self.mesh)), {}
         if not self._tiers:
             return self.states, {}
         hot = dict(self.states)

@@ -110,7 +110,9 @@ def export_exchange_overflows(registry: MetricsRegistry) -> None:
     ``dbsp_tpu_exchange_overflow_total{kind}``: each count is one validated
     interval whose per-worker exchange (or sharded-input) bucket overflowed
     under skew and was re-run at grown capacity by the overflow-replay
-    machinery — the replay saves the rows; the counter makes it visible."""
+    machinery — the replay saves the rows; the counter makes it visible.
+    Beside it, per exchange site, the worst worker's live rows and the
+    bucket capacity at the last validation (``EXCHANGE_SITE_ROWS``)."""
     if getattr(registry, "_exchange_overflows_exported", False):
         return
     registry._exchange_overflows_exported = True
@@ -120,11 +122,26 @@ def export_exchange_overflows(registry: MetricsRegistry) -> None:
         "check and repaired by overflow replay (kind = exchange | input)",
         labels=("kind",))
 
+    live = registry.gauge(
+        "dbsp_tpu_exchange_site_live_rows",
+        "Worst-worker live rows at an exchange site (a compiled exchange "
+        "or a sharded input) at the last validation",
+        labels=("kind", "node"))
+    capacity = registry.gauge(
+        "dbsp_tpu_exchange_site_capacity_rows",
+        "Static per-worker bucket capacity of an exchange site; capacity "
+        "less live rows is padding every worker sorts and merges",
+        labels=("kind", "node"))
+
     def _collect() -> None:
-        from dbsp_tpu.parallel.exchange import EXCHANGE_OVERFLOW_COUNTS
+        from dbsp_tpu.parallel.exchange import (EXCHANGE_OVERFLOW_COUNTS,
+                                                EXCHANGE_SITE_ROWS)
 
         for kind, n in list(EXCHANGE_OVERFLOW_COUNTS.items()):
             counter.labels(kind=kind).set_total(n)
+        for (kind, node), (rows, cap) in list(EXCHANGE_SITE_ROWS.items()):
+            live.labels(kind=kind, node=str(node)).set(rows)
+            capacity.labels(kind=kind, node=str(node)).set(cap)
 
     registry.register_collector(_collect)
 

@@ -8,15 +8,16 @@ scheduler-event stream). The served path names every phase of a request
 with fixed strings (the tick index, table, record and byte counts are
 ``args``): ``ingest`` > ``ingest.read_body`` / ``ingest.parse`` /
 ``ingest.push_rows`` (io/server.py), ``step_request`` > ``step.lock_wait``,
-``tick`` > ``tick.drain_endpoints`` / ``tick.build_inputs`` /
-``tick.snapshot`` / ``tick.dispatch`` / ``tick.validate`` (>
-``tick.device_wait``, ``tick.grow``, ``tick.replay``) / ``tick.maintain`` /
-``tick.deliver`` / ``tick.emit_outputs`` / ``tick.publish`` /
+``tick`` > ``tick.drain_endpoints`` / ``tick.build_inputs`` (on a worker
+mesh > ``tick.shard_inputs``) / ``tick.snapshot`` / ``tick.dispatch`` /
+``tick.validate`` (> ``tick.device_wait``, ``tick.grow``, ``tick.replay``)
+/ ``tick.maintain`` / ``tick.deliver`` (on a worker mesh >
+``tick.unshard_outputs``) / ``tick.emit_outputs`` / ``tick.publish`` /
 ``tick.checkpoint`` / ``tick.monitors`` (io/controller.py,
-compiled/driver.py), ``read`` > ``read.query`` / ``read.respond``, and a
-closed ``compile`` child for every program asked of the compiler inside
-any of them. :func:`default_recorder` is the ring they land in unless a
-``PipelineObs`` hands its own.
+compiled/driver.py, operators/io_handles.py), ``read`` > ``read.query`` /
+``read.respond``, and a closed ``compile`` child for every program asked
+of the compiler inside any of them. :func:`default_recorder` is the ring
+they land in unless a ``PipelineObs`` hands its own.
 
 Format: the JSON-object flavor of the Trace Event Format — ``B``/``E``
 duration events with microsecond timestamps, so nesting is explicit and a
@@ -66,6 +67,7 @@ rings (as ``e2e`` category spans carrying the trace ids), in the timeline
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
@@ -78,8 +80,8 @@ from jax.profiler import TraceAnnotation
 from dbsp_tpu.testing.tsan import maybe_instrument as _tsan_hook
 
 __all__ = [
-    "SpanRecorder", "default_recorder", "E2ETracer", "E2E_STAGES",
-    "trace_e2e_enabled",
+    "SpanRecorder", "default_recorder", "child_span", "E2ETracer",
+    "E2E_STAGES", "trace_e2e_enabled",
     "merge_chrome_traces",
 ]
 
@@ -151,6 +153,18 @@ def _listen_for_compiles() -> None:
         monitoring.register_event_listener(_on_compile_event)
         monitoring.register_event_duration_secs_listener(_on_compile_duration)
         _listening = True
+
+
+def child_span(name: str, cat: str = "tick", args: Optional[dict] = None):
+    """A span under whatever span this thread has open, in that span's
+    recorder — for code below the served path's phases that is handed no
+    recorder (an input handle sharding its batch inside
+    ``tick.build_inputs``). Where the thread has no span open (the host
+    engine, a test calling the operator directly) it records nothing."""
+    rec = getattr(_tls, "rec", None)
+    if rec is None:
+        return contextlib.nullcontext()
+    return rec.span(name, cat, args)
 
 
 def default_recorder() -> "SpanRecorder":

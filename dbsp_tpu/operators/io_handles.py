@@ -74,9 +74,12 @@ class ZSetInput(SourceOperator):
         if workers > 1:
             # distribute by key hash over the mesh (the reference spreads
             # input across workers at the handle, input.rs:66-67/309-311)
+            from dbsp_tpu.obs.tracing import child_span
             from dbsp_tpu.parallel.exchange import shard_batch
 
-            acc = shard_batch(acc, rt.mesh).shrink_to_fit()
+            with child_span("tick.shard_inputs",
+                            args={"rows": acc.cap, "workers": workers}):
+                acc = shard_batch(acc, rt.mesh).shrink_to_fit()
         return acc
 
     def state_dict(self):
@@ -136,9 +139,13 @@ class OutputOperator(SinkOperator):
         if isinstance(v, Batch) and v.sharded:
             # collapse to one host-side batch so every consumer (tests,
             # transports, HTTP readers) sees worker-count-independent output
+            from dbsp_tpu.obs.tracing import child_span
             from dbsp_tpu.parallel.exchange import unshard_batch
 
-            v = unshard_batch(v)
+            with child_span("tick.unshard_outputs",
+                            args={"rows": v.cap,
+                                  "workers": v.weights.shape[0]}):
+                v = unshard_batch(v)
         self.current = v
         self.step_id += 1
         for q in self._consumers.values():

@@ -53,6 +53,22 @@ def count_exchange_overflow(kind: str, n: int = 1) -> None:
     EXCHANGE_OVERFLOW_COUNTS[kind] = EXCHANGE_OVERFLOW_COUNTS.get(kind, 0) + n
 
 
+# Process-wide, per exchange site — keyed (kind, node index), kind as in
+# EXCHANGE_OVERFLOW_COUNTS: (the worst worker's live rows, the static
+# per-worker bucket capacity) at the last validation. Filled from the
+# requirement levels validation fetches anyway (no device sync of its own);
+# rows / capacity is the bucket's occupancy, the rest of the bucket is
+# padding every worker sorts and merges for nothing. Exported as
+# ``dbsp_tpu_exchange_site_live_rows{kind,node}`` and
+# ``dbsp_tpu_exchange_site_capacity_rows{kind,node}``; empty with one worker.
+EXCHANGE_SITE_ROWS: dict = {}
+
+
+def note_exchange_site(kind: str, node: int, rows: int,
+                       capacity: int) -> None:
+    EXCHANGE_SITE_ROWS[(kind, node)] = (rows, capacity)
+
+
 def _hash_key(col: jnp.ndarray) -> jnp.ndarray:
     """splitmix64-style mix of the first key column (any int dtype)."""
     z = col.astype(jnp.uint64) * jnp.uint64(0x9E3779B97F4A7C15)
@@ -65,11 +81,14 @@ def worker_of(col: jnp.ndarray, nworkers: int) -> jnp.ndarray:
     return (_hash_key(col) % jnp.uint64(nworkers)).astype(jnp.int32)
 
 
-def _bucketize(batch: Batch, nworkers: int) -> Batch:
+@kernels._scoped
+def exchange_bucketize(batch: Batch, nworkers: int) -> Batch:
     """Scatter local rows into [W, cap] bins by key hash (dead rows dropped).
 
     Rows keep their relative order within a bin; bins are zero-padded with
     sentinel keys so each bin is a valid (unconsolidated) batch slice.
+    Scope ``k.exchange_bucketize`` (as the public kernels of
+    ``zset/kernels.py`` carry ``k.<kernel>``).
     """
     cap = batch.cap
     dest = jnp.where(batch.weights != 0,
@@ -103,15 +122,17 @@ def exchange_local(batch: Batch, nworkers: int) -> Batch:
     and consolidated. Output capacity is ``nworkers * cap``; callers
     re-bucket outside the jit boundary when they care (spine insert does).
     """
-    binned = _bucketize(batch, nworkers)
+    binned = exchange_bucketize(batch, nworkers)
 
     def a2a(x):
         return lax.all_to_all(x, WORKER_AXIS, split_axis=0, concat_axis=0,
                               tiled=True).reshape(nworkers * batch.cap)
 
     nk = len(batch.keys)
-    cols = tuple(a2a(c) for c in binned.cols)
-    w = a2a(binned.weights)
+    # the collectives are no loop bodies, so the scope stays on them
+    with jax.named_scope("x.all_to_all"):
+        cols = tuple(a2a(c) for c in binned.cols)
+        w = a2a(binned.weights)
     # a consolidated input arrives as nworkers sorted runs (each peer's bin
     # keeps its relative order, live-packed with a sentinel tail) — the
     # regime dispatch folds sorted merges instead of re-sorting
@@ -128,8 +149,9 @@ def gather_local(batch: Batch) -> Batch:
         return lax.all_gather(x, WORKER_AXIS, tiled=True)
 
     nk = len(batch.keys)
-    cols = tuple(ag(c) for c in batch.cols)
-    w = ag(batch.weights)
+    with jax.named_scope("x.all_gather"):
+        cols = tuple(ag(c) for c in batch.cols)
+        w = ag(batch.weights)
     # the gather stacks every worker's consolidated slice: W sorted runs
     # (W read off the gathered shape — no worker count to pass or get wrong)
     runs = None
@@ -162,7 +184,7 @@ def spmd(mesh: Mesh, fn):
 
 @partial(jax.jit, static_argnames=("nworkers",))
 def _shard_kernel(batch: Batch, nworkers: int) -> Batch:
-    return _bucketize(batch, nworkers)
+    return exchange_bucketize(batch, nworkers)
 
 
 @lru_cache(maxsize=None)

@@ -41,7 +41,20 @@ def test_run_served_four_workers_shards_the_served_path():
     summary = chip_smoke.run_served(ticks=2, events_per_tick=600, seed=3,
                                     workers=4, emit=lambda _: None)
     assert summary["ok"] and summary["view_equals_recompute"]
-    assert summary["sharding"]["devices_per_leaf"] == [4]
+    sharding = summary["sharding"]
+    assert sharding["devices_per_leaf"] == [4]
+    assert sharding["replicated_leaves"] == 0
+    # the exchange's counters and the per-chip bytes ride the summary line
+    # (the CPU backend reports no memory statistics: four empty readings)
+    assert len(sharding["bytes_in_use"]) == 4
+    assert len(sharding["peak_bytes_in_use"]) == 4
+    sites = sharding["exchange_sites"]
+    assert {k.split(":")[0] for k in sites} == {"input", "exchange"}
+    assert sum(k.startswith("input:") for k in sites) == 3
+    for rows, cap in sites.values():
+        assert 0 <= rows <= cap and cap & (cap - 1) == 0
+    assert set(sharding["exchange_overflows"]) <= {"input", "exchange"}
+    json.dumps(summary)
 
 
 def test_q4_recompute_by_hand():

@@ -216,3 +216,103 @@ def test_interpreter_is_refused_off_the_cpu(tpu_dispatch, monkeypatch):
     monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
     assert not pallas_kernels.enabled()
     assert kernels.pallas_requested() == bool(kernels.PALLAS_TPU_COMPILED)
+
+
+# -- the exchange, for the four chips of a v5e host ---------------------------
+
+WORKERS = 4
+EXCHANGES = {
+    # name: (per-worker capacity, key dtypes, value dtypes, collective)
+    # q4's one compiled exchange (re-key the per-auction maxima by
+    # category) at the bucket a 40,000-event tick presizes it to
+    "exchange_q4_maxima": (4_096, (I64,), (I64,), "all-to-all"),
+    # a bids delta's per-worker share, as a query that exchanges bids would
+    # route it
+    "exchange_bids_share": (CAP // WORKERS, BID[:1], BID[1:], "all-to-all"),
+    # the output's gather (CUnshard); at a view of a few dozen rows the
+    # compiler folds the gather into fusions and no all-gather is left
+    "gather_view": (4_096, (I64, I64), (), "all-gather"),
+}
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topo.devices[:WORKERS]), ("workers",))
+
+
+@pytest.mark.parametrize("name", EXCHANGES)
+def test_exchange_compiles_for_four_v5e_chips(name, four_chips,
+                                              no_persistent_cache,
+                                              tpu_dispatch):
+    """Bucketize + ``all_to_all`` + per-worker consolidation (and the
+    output's ``all_gather``) as ONE SPMD program over a described 2x2 mesh,
+    with the accelerator formulations behind the consolidation."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dbsp_tpu.parallel import exchange
+
+    cap, key_dts, val_dts, collective = EXCHANGES[name]
+    sharded = NamedSharding(four_chips, P("workers"))
+
+    def shape(dtype=I64):
+        return jax.ShapeDtypeStruct((WORKERS, cap), dtype, sharding=sharded)
+
+    batch = Batch(tuple(shape(d) for d in key_dts),
+                  tuple(shape(d) for d in val_dts), shape(), runs=(cap,))
+    body = exchange.gather_local if collective == "all-gather" else (
+        lambda b: exchange.exchange_local(b, WORKERS))
+    compiled = jax.jit(exchange.spmd(four_chips, body)).lower(batch).compile()
+    text = compiled.as_text()
+    ncols = len(key_dts) + len(val_dts) + 1
+    assert text.count(f" {collective}(") + text.count(
+        f" {collective}-start(") >= 1, f"no {collective} in the program"
+    # every column and the weights cross the mesh: nothing stays behind
+    out = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out) == ncols
+    assert all(len(s.device_set) == WORKERS for s in out)
+
+
+@pytest.mark.parametrize("name", ["drain_pair", "drain_slice", "copy_tree"])
+def test_maintenance_keeps_levels_on_their_workers(name, four_chips,
+                                                   no_persistent_cache,
+                                                   tpu_dispatch):
+    """What maintenance hands back to the step program stays one slice a
+    worker. Left to itself the v5e's compiler returned a drain's emptied
+    level (all constants) replicated on the four chips, and every level
+    pair's first drain cost the served path a new SPMD step program."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dbsp_tpu.circuit.runtime import Runtime
+    from dbsp_tpu.compiled import compiler
+
+    sharded = NamedSharding(four_chips, P("workers"))
+
+    def level(cap):
+        def shape(dtype=I64):
+            return jax.ShapeDtypeStruct((WORKERS, cap), dtype,
+                                        sharding=sharded)
+
+        return Batch(tuple(shape(d) for d in BID[:1]),
+                     tuple(shape(d) for d in BID[1:]), shape(), runs=(cap,))
+
+    l0, l1 = level(CAP // WORKERS), level(CAP)
+    prev = Runtime._swap(Runtime(WORKERS, mesh=four_chips))
+    try:
+        if name == "drain_pair":
+            lowered = compiler._drain_pair.lower(l1, l0, CAP)
+        elif name == "drain_slice":
+            lowered = compiler._drain_slice.lower(
+                l1, l0, jax.ShapeDtypeStruct((), I32), CAP)
+        else:
+            lowered = compiler._copy_tree.lower((l1, l0))
+        compiled = lowered.compile()
+    finally:
+        Runtime._swap(prev)
+    out = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert len(out) == 2 * (len(BID) + 1)
+    for s in out:
+        assert not s.is_fully_replicated, s
+        assert s.is_equivalent_to(sharded, 2), s

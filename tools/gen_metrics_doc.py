@@ -49,6 +49,11 @@ Scrape surfaces: every pipeline's `GET /metrics`
 `dbsp_steps`-era names) and the manager's fleet-wide `GET /metrics`
 (adds a `pipeline` label). See README §Observability for the subsystem
 tour.
+
+Numbers: `MULTICHIP_r01–r06.json` are CPU timesharing (virtual devices on
+two cores); what four chips give is the benchmark cell
+`nexmark-q4-4w.saturated`'s to say (PERF.md), and its `exchange_*` metrics
+read the `dbsp_tpu_exchange_*` families below from the same counters.
 """
 
 

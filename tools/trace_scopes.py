@@ -6,10 +6,12 @@
 
 The programs carry their own names: ``CompiledHandle._run_nodes`` wraps each
 node's eval in ``jax.named_scope("n<index>.<CNode class>")``, the public
-kernels of ``zset/kernels.py`` in ``k.<kernel>``, the maintenance drains in
-``maintain.drain``; XLA keeps the scope path in each operation's metadata
-and the TPU's trace keeps it in the metadata of the ``XLA Ops`` line's
-events — on a v5e in the metadata's ``tf_op`` stat (my chip run, PR 28);
+kernels of ``zset/kernels.py`` in ``k.<kernel>``, the exchange's collectives
+in ``x.all_to_all`` / ``x.all_gather`` (``parallel/exchange.py``; counted
+with the kernels), the maintenance drains in ``maintain.drain``; XLA keeps
+the scope path in each operation's metadata and the TPU's trace keeps it
+in the metadata of the ``XLA Ops`` line's events — on a v5e in the
+metadata's ``tf_op`` stat (my chip run, PR 28);
 where is looked for, not assumed: ``scope_stats`` in the output counts the
 places, ``--stats N`` prints the first N scoped operations. Eagerly
 dispatched programs (``jit_scan``, ``jit_gather``) carry no scope: their
@@ -45,7 +47,7 @@ import sys
 
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 NODE = re.compile(r"(?:^|/)(n\d+\.C\w+|maintain\.drain)(?:/|$)")
-KERNEL = re.compile(r"(?:^|/)(k\.\w+)(?:/|$)")
+KERNEL = re.compile(r"(?:^|/)([kx]\.\w+)(?:/|$)")
 ANNOTATION_PREFIX = "dbsp."
 
 
