@@ -240,6 +240,7 @@ def run_served(ticks: int, events_per_tick: int, seed: int,
                     f.write(srv.spans.to_json())
             srv.stop()
             ctl.stop()
+            parsed = ctl.stats()["parsed_records"]
     meter.close()
     want = q4_recompute(pushed["auctions"], pushed["bids"])
     ok = bool(want) and got == want and view["step"] == ticks
@@ -297,6 +298,8 @@ def run_served(ticks: int, events_per_tick: int, seed: int,
         "step_programs_traced": compiles.compiles.get("step_fn", 0),
         "overflow_replays": driver.ch.overflow_replays,
         "presize_used": presized,
+        # pushed rows by the parser path that took them (io/format.py)
+        "parsed_records": parsed,
         "kernel_dispatch": {f"{k}/{b}": n for (k, b), n in sorted(
             kernels.KERNEL_DISPATCH_COUNTS.items())},
         "consolidate_paths": dict(kernels.CONSOLIDATE_COUNTS),

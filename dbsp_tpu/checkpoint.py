@@ -182,6 +182,10 @@ STATE_SCHEMA: Dict[str, Dict[str, str]] = {
     "Controller": {
         "steps": "persisted",
         "total_pushed": "persisted",
+        # which parser path took this process's pushes: observability of
+        # the running parser, restarts from zero like any process counter
+        "parsed_columnar": "runtime",
+        "parsed_fallback": "runtime",
         "handle": "config",
         "catalog": "config",
         "config": "config",

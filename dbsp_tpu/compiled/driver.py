@@ -10,8 +10,9 @@ through :class:`~dbsp_tpu.compiled.compiler.CompiledHandle` — one XLA
 program per tick instead of per-operator dispatches.
 
 Feed/overflow protocol: inputs arrive through the normal host
-``InputHandle`` buffers (the catalog's ``push_rows``); each ``step`` drains
-them via ``ZSetInput.eval`` (same canonicalization as the host path),
+``InputHandle`` buffers (the catalog's ``push_rows``: a POST's rows as one
+column block, a transport's as row tuples); each ``step`` drains them via
+``ZSetInput.eval`` (same canonicalization as the host path),
 runs the tick, and validates capacity requirements at the validation
 cadence. On overflow it grows, restores the interval-start snapshot, and
 replays the retained feeds — deterministic, so the replay is exact.

@@ -154,6 +154,8 @@ CONCURRENCY_SCHEMA: Dict[str, Dict[str, str]] = {
         "_lifecycle_lock": "immutable",
         "_pushed": "lock(_pushed_lock)",
         "total_pushed": "writelock(_pushed_lock)",
+        "parsed_columnar": "writelock(_pushed_lock)",
+        "parsed_fallback": "writelock(_pushed_lock)",
         "_thread": "writelock(_lifecycle_lock)",
         "_monitors": "gil-atomic: append-only list appended at deploy "
                      "time; the circuit loop's iteration tolerates a "

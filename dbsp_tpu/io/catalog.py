@@ -9,10 +9,11 @@ handles and encoders read batches out.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 from dbsp_tpu.operators.io_handles import InputHandle, OutputHandle
 from dbsp_tpu.io.format import WeightedRow
+from dbsp_tpu.zset.batch import ColumnBlock
 
 
 @dataclasses.dataclass
@@ -21,7 +22,9 @@ class InputCollection:
     handle: InputHandle
     dtypes: Tuple  # (key..., val...) column dtypes, parser order
 
-    def push_rows(self, rows: List[WeightedRow]) -> int:
+    def push_rows(self, rows: Union[List[WeightedRow], ColumnBlock]) -> int:
+        """The single entry of parsed rows into the handle: a POST's block
+        (``Parser.take_columns``) or a transport's weighted row tuples."""
         self.handle.extend(rows)
         return len(rows)
 

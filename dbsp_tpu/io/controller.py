@@ -159,6 +159,10 @@ class Controller:
         self._stop = threading.Event()
         self._pushed = 0              # host-pushed rows awaiting a step
         self.total_pushed = 0         # lifetime counter (stats)
+        # of the pushed rows that a parser made (HTTP POSTs): how many its
+        # columnar bulk path took, how many its line parser
+        self.parsed_columnar = 0
+        self.parsed_fallback = 0
         self._pushed_lock = threading.Lock()
         self._running = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -308,15 +312,23 @@ class Controller:
             return self.e2e.note_ingest(n, trace_id=trace_id)
         return None
 
-    def note_pushed(self, n: int,
-                    trace_id: Optional[str] = None) -> Optional[str]:
+    def note_pushed(self, n: int, trace_id: Optional[str] = None,
+                    columnar: Optional[int] = None) -> Optional[str]:
         """Record host-pushed rows (HTTP endpoints / client API) so the
         circuit loop's batching sees them alongside transport buffers —
-        without this, pushed rows waited for an explicit /step. Returns
-        the batch's e2e trace id (None when tracing is off)."""
+        without this, pushed rows waited for an explicit /step. Rows out
+        of a parser say how many of them its bulk path took (``columnar``;
+        the line parser took the rest). Returns the batch's e2e trace id
+        (None when tracing is off)."""
         with self._pushed_lock:
             self._pushed += int(n)
             self.total_pushed += int(n)
+        if columnar is not None:
+            # (a block of its own: tests/test_concurrency.py seeds its
+            # defect by unguarding exactly the two writes above)
+            with self._pushed_lock:
+                self.parsed_columnar += int(columnar)
+                self.parsed_fallback += int(n) - int(columnar)
         return self._note_arrival(n, trace_id=trace_id)
 
     # -- durability (dbsp_tpu.checkpoint) -----------------------------------
@@ -721,6 +733,8 @@ class Controller:
             "state": self.state,
             "steps": self.steps,
             "pushed_records": self.total_pushed,
+            "parsed_records": {"columnar": self.parsed_columnar,
+                               "fallback": self.parsed_fallback},
             "checkpoints": self.checkpoints,
             "last_checkpoint_tick": self.last_checkpoint_tick,
             "checkpoint_error": self.checkpoint_error,

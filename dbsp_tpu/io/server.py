@@ -364,15 +364,20 @@ class CircuitServer:
                     col = c.catalog.input(name)
                 except KeyError as e:
                     return self._json({"error": str(e)}, 404)
-                with spans.span("ingest.parse", "ingest"):
+                with spans.span("ingest.parse", "ingest") as parse_sp:
                     parser = INPUT_FORMATS[fmt](col.dtypes)
                     try:
                         parser.feed(body)
                         parser.eoi()
-                        rows = parser.take()
+                        # the whole POST as one block of columns, held to
+                        # their domain here: a bad line or value anywhere
+                        # answers 400 with nothing buffered
+                        rows = parser.take_columns()
                     except (ValueError, KeyError) as e:
                         return self._json(
                             {"error": f"parse error: {e}"}, 400)
+                    parse_sp.note(columnar=parser.columnar,
+                                  fallback=parser.fallback)
                 with spans.span("ingest.push_rows", "ingest"):
                     col.push_rows(rows)
                     # HTTP pushes must wake the circuit loop like transport
@@ -383,7 +388,8 @@ class CircuitServer:
                     # otherwise one is minted — either way it is echoed.
                     trace_id = c.note_pushed(
                         len(rows),
-                        trace_id=self.headers.get("X-Dbsp-Trace") or None)
+                        trace_id=self.headers.get("X-Dbsp-Trace") or None,
+                        columnar=parser.columnar)
                 sp.note(records=len(rows), bytes=n, trace=trace_id)
                 resp = {"records": len(rows)}
                 if trace_id is not None:

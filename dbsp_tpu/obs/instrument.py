@@ -518,6 +518,12 @@ class ControllerInstrumentation:
         reg.counter("dbsp_tpu_io_pushed_records_total",
                     "Rows pushed via the host API / HTTP endpoints"
                     ).set_total(s["pushed_records"])
+        for path, n in s["parsed_records"].items():
+            reg.counter("dbsp_tpu_io_parsed_records_total",
+                        "Rows of HTTP pushes by the parser path that took "
+                        "them: the columnar bulk path or the line parser "
+                        "(fallback)", labels=("path",)).labels(
+                            path=path).set_total(n)
         reg.counter("dbsp_tpu_io_checkpoints_total",
                     "Durable checkpoint generations written by this "
                     "controller").set_total(s.get("checkpoints", 0))

@@ -16,15 +16,17 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from dbsp_tpu.circuit.builder import Circuit, Stream
 from dbsp_tpu.circuit.operator import SinkOperator, SourceOperator
 from dbsp_tpu.operators.registry import stream_method
-from dbsp_tpu.zset.batch import Batch, Row, concat_batches
+from dbsp_tpu.zset.batch import Batch, ColumnBlock, Row, concat_batches
 
 
 class ZSetInput(SourceOperator):
-    """Source draining a host-side buffer of rows/batches once per tick."""
+    """Source draining a host-side buffer of rows, column blocks and
+    batches once per tick."""
 
     name = "input"
 
@@ -39,6 +41,7 @@ class ZSetInput(SourceOperator):
         self.key_dtypes = tuple(key_dtypes)
         self.val_dtypes = tuple(val_dtypes)
         self._rows: List[Tuple[Row, int]] = []
+        self._blocks: List[ColumnBlock] = []  # one per pushed block
         self._batches: List[Tuple[Batch, bool]] = []  # (batch, consolidated)
 
     def eval(self) -> Batch:
@@ -52,8 +55,8 @@ class ZSetInput(SourceOperator):
         # NEXT tick's buffer — a clear-after-read here destroyed them
         # (found by the slow-consumer fault test: a stalling sink widened
         # the eval window and rows pushed mid-step vanished)
-        rows, self._rows = self._rows, []
-        batches, self._batches = self._batches, []
+        rows, blocks, batches, self._rows, self._blocks, self._batches = \
+            self._rows, self._blocks, self._batches, [], [], []
         # canonicalize each part once, then fold with rank-merges — pushed
         # batches that are already consolidated (the common generator path)
         # are never re-sorted
@@ -61,6 +64,13 @@ class ZSetInput(SourceOperator):
         if rows:
             parts.append(Batch.from_tuples(
                 rows, self.key_dtypes, self.val_dtypes))
+        if blocks:
+            # the tick's POSTs as one batch, column by column: the same
+            # shapes and dtypes from_tuples would hand from_columns
+            block = ColumnBlock.concat(blocks)
+            nk = len(self.key_dtypes)
+            parts.append(Batch.from_columns(
+                block.cols[:nk], block.cols[nk:], block.weights))
         if not parts:
             return Batch.empty(self.key_dtypes, self.val_dtypes,
                                lead=(workers,) if workers > 1 else ())
@@ -106,8 +116,21 @@ class InputHandle:
     def push(self, row: Row, weight: int = 1) -> None:
         self._op._rows.append((row, weight))
 
-    def extend(self, rows: Sequence[Tuple[Row, int]]) -> None:
-        self._op._rows.extend(rows)
+    def extend(self, rows) -> None:
+        """Buffer weighted row tuples, or a :class:`ColumnBlock` of the
+        source's columns whole: one append, so a tick drains all of a
+        block or none of it."""
+        if isinstance(rows, ColumnBlock):
+            op = self._op
+            want = tuple(np.dtype(d) for d in
+                         (*op.key_dtypes, *op.val_dtypes))
+            assert tuple(c.dtype for c in rows.cols) == want, (
+                f"block columns {[c.dtype for c in rows.cols]} != "
+                f"schema {list(want)}")
+            if len(rows):
+                op._blocks.append(rows)
+        else:
+            self._op._rows.extend(rows)
 
     def push_batch(self, batch: Batch, consolidated: bool = False) -> None:
         """Zero-copy path: feed an already-built (device) batch. Pass

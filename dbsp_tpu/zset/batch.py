@@ -177,21 +177,8 @@ class Batch:
             for j in range(nv):
                 vcols[j][i] = row[nk + j]
             ws[i] = w
-        # DOMAIN CONTRACT: the max representable value of each column dtype
-        # is the engine's dead-row sentinel; a live row carrying it would be
-        # conflated with padding in probes/window slices. Reject at the host
-        # boundary (zero-cost here; device-batch pushers uphold it by
-        # contract — see push_batch).
-        for col, d in ((c, d) for cols, dts in
-                       ((kcols, key_dtypes), (vcols, val_dtypes))
-                       for c, d in zip(cols, dts)):
-            dt = jnp.dtype(d)
-            if np.issubdtype(dt, np.integer) and n and \
-                    col.max(initial=np.iinfo(dt).min) == np.iinfo(dt).max:
-                raise ValueError(
-                    f"value {np.iinfo(dt).max} ({dt}) is reserved as the "
-                    "dead-row sentinel; remap the input domain (e.g. use a "
-                    "wider dtype)")
+        for col in (*kcols, *vcols):
+            _check_domain(col, col.dtype)
         return Batch.from_columns(kcols, vcols, ws, cap=cap)
 
     # -- canonicalization ---------------------------------------------------
@@ -375,6 +362,118 @@ def consolidate_regime(batch: Batch) -> Batch:
         return Batch(acc[:nk], acc[nk:], acc_w, runs=(batch.cap,))
     cols, w = kernels.consolidate_cols(batch.cols, batch.weights)
     return Batch(cols[:nk], cols[nk:], w, runs=(batch.cap,))
+
+
+def _check_domain(wide: np.ndarray, dt) -> None:
+    """DOMAIN CONTRACT of a host column headed for dtype ``dt``: the max
+    representable value of each integer column dtype is the engine's
+    dead-row sentinel; a live row carrying it would be conflated with
+    padding in probes/window slices. Reject at the host boundary (a
+    vectorised max here; device-batch pushers uphold it by contract — see
+    push_batch). ``wide`` holds the values before any narrowing cast, so a
+    value the dtype cannot hold is refused too, not wrapped."""
+    if not np.issubdtype(dt, np.integer) or not wide.size:
+        return
+    info = np.iinfo(dt)
+    hi, lo = int(wide.max()), int(wide.min())
+    if hi == info.max:
+        raise ValueError(
+            f"value {info.max} ({dt}) is reserved as the dead-row sentinel; "
+            "remap the input domain (e.g. use a wider dtype)")
+    if hi > info.max or lo < info.min:
+        raise ValueError(
+            f"value {hi if hi > info.max else lo} is outside the range of "
+            f"a {dt} column")
+
+
+def transposed(rows: Sequence[Tuple[Row, int]]) -> tuple:
+    """Non-empty weighted rows -> (per-column tuples, weights)."""
+    recs, weights = zip(*rows)
+    return tuple(zip(*recs)), weights
+
+
+def _joined(pieces: Sequence, wide, what: str) -> np.ndarray:
+    """Pieces in row order — numpy arrays or sequences of Python numbers —
+    as one ``wide`` array; a number ``wide`` cannot hold is a
+    ``ValueError``."""
+    try:
+        return np.concatenate([np.asarray(p, dtype=wide) for p in pieces]) \
+            if pieces else np.empty(0, wide)
+    except OverflowError as e:
+        raise ValueError(f"value outside the range of {what}: {e}")
+
+
+def host_column(pieces: Sequence, dtype) -> np.ndarray:
+    """One numpy column of ``dtype`` from its pieces in row order (the JSON
+    parser's bulk path hands arrays, its line parser Python numbers), held
+    to the domain contract (:func:`_check_domain`): raises ``ValueError``
+    for a value the dtype cannot hold or reserves. Floats pass through
+    ``float64`` as ``float(v)`` does."""
+    dt = np.dtype(dtype)
+    wide = (np.float64 if not np.issubdtype(dt, np.integer)
+            else np.uint64 if dt == np.uint64 else np.int64)
+    col = _joined(pieces, wide, f"a {dt} column")
+    _check_domain(col, dt)
+    return col.astype(dt, copy=False)
+
+
+class ColumnBlock:
+    """Weighted rows on the host, column-wise: one numpy array per schema
+    column (keys then values, in the schema's dtypes) and a weight vector.
+    What a parsed POST is pushed as (io/format.py -> InputHandle.extend):
+    the tick's batch is built from the buffered blocks by
+    :meth:`Batch.from_columns`, with no row tuple in between. Supports
+    ``len()`` and slicing like the list of weighted rows it replaces."""
+
+    __slots__ = ("cols", "weights")
+
+    def __init__(self, cols: Sequence[np.ndarray], weights: np.ndarray):
+        self.cols = tuple(cols)
+        self.weights = weights
+        for c in self.cols:
+            assert c.shape == weights.shape, (
+                f"column length {c.shape} != weights length {weights.shape}")
+
+    def __len__(self) -> int:
+        return int(self.weights.shape[0])
+
+    def __getitem__(self, s: slice) -> "ColumnBlock":
+        if not isinstance(s, slice):
+            raise TypeError("a ColumnBlock is sliced, not indexed")
+        return ColumnBlock(tuple(c[s] for c in self.cols), self.weights[s])
+
+    def rows(self) -> list:
+        """The block as ``[((col...), weight), ...]`` of Python scalars."""
+        return list(zip(zip(*(c.tolist() for c in self.cols)),
+                        self.weights.tolist()))
+
+    @staticmethod
+    def from_parts(parts: Sequence[tuple], dtypes: Sequence) -> "ColumnBlock":
+        """``parts``: (per-column pieces, weights) pairs in row order, a
+        piece a numpy array or a sequence of Python numbers -> one block
+        of ``dtypes`` columns; raises ``ValueError`` where
+        :func:`host_column` does."""
+        return ColumnBlock(
+            [host_column([cols[j] for cols, _ in parts], d)
+             for j, d in enumerate(dtypes)],
+            _joined([weights for _, weights in parts],
+                    np.dtype(WEIGHT_DTYPE), "a weight"))
+
+    @staticmethod
+    def from_rows(rows: Sequence[Tuple[Row, int]],
+                  dtypes: Sequence) -> "ColumnBlock":
+        """Weighted row tuples -> a block of ``dtypes`` columns."""
+        return ColumnBlock.from_parts(
+            [transposed(rows)] if rows else [], dtypes)
+
+    @staticmethod
+    def concat(blocks: Sequence["ColumnBlock"]) -> "ColumnBlock":
+        if len(blocks) == 1:
+            return blocks[0]
+        return ColumnBlock(
+            tuple(np.concatenate(cs)
+                  for cs in zip(*(b.cols for b in blocks))),
+            np.concatenate([b.weights for b in blocks]))
 
 
 def _pad_sentinel(col: jnp.ndarray, cap: int) -> jnp.ndarray:

@@ -24,6 +24,9 @@ def test_run_served_small_view_equals_recomputation():
     assert summary["mode"] == "compiled"
     assert summary["view_rows"] > 0, "empty view — the check would be vacuous"
     assert summary["events"] == 1200 and summary["presize_used"]
+    # the bodies the smoke (and the benchmark) pushes are regular NDJSON:
+    # every row goes to columns in bulk, none through the line parser
+    assert summary["parsed_records"] == {"columnar": 1200, "fallback": 0}
     # the counters count: a run that compiled a step program says so
     assert summary["compile_requests"] > 0
     assert summary["step_programs_traced"] >= 1
