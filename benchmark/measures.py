@@ -19,6 +19,20 @@ def percentile(values, q: float):
     return vs[max(0, math.ceil(q / 100.0 * len(vs)) - 1)]
 
 
+def beyond(n: int, q: float) -> int:
+    """How many of ``n`` samples lie beyond their nearest-rank percentile
+    ``q``: what the tail rests on (a percentile wants ten or more)."""
+    return n - max(1, math.ceil(q / 100.0 * n)) if n else 0
+
+
+def tails(values) -> dict | None:
+    """The percentiles a run's fact line states beside each tail metric."""
+    if not values:
+        return None
+    return {"p50": percentile(values, 50), "p90": percentile(values, 90),
+            "p95": percentile(values, 95), "max": max(values)}
+
+
 def window_ticks(run: dict) -> list:
     """Tick indices published inside the window, in order."""
     if run["open"] is None or run["close"] is None:
@@ -71,6 +85,14 @@ def window_reads(run: dict) -> list:
             if run["open"] <= r[0] <= run["close"]]
 
 
+def reads_meeting_push(run: dict) -> int:
+    """Window reads that were waiting (due to received) while a batch was
+    being pushed: the reads whose latency holds a parse."""
+    pushes = list(run["push"].values())
+    return sum(1 for due, _, got, _ in window_reads(run)
+               if any(a < got and b > due for a, b in pushes))
+
+
 def read_latencies_ms(run: dict) -> list:
     """From when each read was due to its response."""
     return [(got - due) * 1e3 for due, _, got, ok in window_reads(run) if ok]
@@ -87,6 +109,15 @@ def window_ops(run: dict) -> tuple:
     unseen = sum(1 for k in window_ticks(run)
                  if str(k + 1) not in run["visible"])
     return len(ops), sum(1 for o in ops if not o[3]) + unseen
+
+
+def compiles_in_window(run: dict, compile_events) -> int | None:
+    """Programs asked of the compiler inside the window: ``compile_events``
+    rows are ``(monotonic time, seconds)``, cache loads included."""
+    if run["open"] is None or run["close"] is None:
+        return None
+    return sum(1 for t, _ in compile_events
+               if run["open"] < t <= run["close"])
 
 
 def mean_module_seconds(trace: dict | None, name: str):

@@ -6,8 +6,5 @@ not a share, so 0 is reported."""
 
 
 def read(ctx):
-    run = ctx["run"]
-    if run["open"] is None or run["close"] is None:
-        return None
-    return float(sum(1 for t, _ in ctx["compile_events"]
-                     if run["open"] < t <= run["close"]))
+    n = ctx["measures"].compiles_in_window(ctx["run"], ctx["compile_events"])
+    return None if n is None else float(n)

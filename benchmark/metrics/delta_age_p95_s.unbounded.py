@@ -1,6 +1,7 @@
 """The end-to-end ``delta_age_p95_s`` arithmetic, reported per layer in the
-cells where it cannot be held to a bound (q3: one slow tick in a run of 3 s
-ticks moves the p95 by 40 %; PERF.md, section 2).
+cells where it cannot be held to a bound: q3 (one slow tick among 0.15 s
+ticks moves the p95 by tens of percent) and q4-4w (no sets of runs on four
+chips yet); PERF.md, section 2.
 Layer: tick (io/controller.py, compiled/driver.py)."""
 
 

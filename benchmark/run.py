@@ -303,6 +303,29 @@ def acked_events(spec: dict, seed: int, ticks: int,
             for rel, names in generator.COLUMNS.items()}
 
 
+def presize(ch, config: dict) -> None:
+    """The run's one presize, after the first validated tick, from what the
+    configuration states: ``assumed.presize_ratio`` (how many times tick
+    0's state the monotone capacities must hold), ``assumed.
+    presize_interval`` (ticks of inflow a trace's level 0 must hold) and,
+    where a deployment states it, ``deployment.per_delta_headroom``:
+    ``{"<CNode class>.<capacity>": n}`` gives that per-delta capacity room
+    for n times tick 0's requirement, where ``presize`` gives twice. (q4's
+    aggregate touches 623 groups a worker in tick 0 and ~2,000 from tick
+    25 on: twice is a capacity of 2,048, which two four-chip runs of six
+    overflowed inside their windows; PERF.md 6, PR 33.)"""
+    headroom = config.get("deployment", {}).get("per_delta_headroom", {})
+    if headroom:
+        from dbsp_tpu.zset.batch import bucket_cap
+
+        for (cn, key), r in zip(ch._checks, ch.last_req):
+            n = headroom.get(f"{type(cn).__name__}.{key}")
+            if n and int(r) > 0:
+                cn.caps[key] = max(cn.caps[key], bucket_cap(n * int(r)))
+    ch.presize(ratio=config["assumed"]["presize_ratio"],
+               interval=config["assumed"].get("presize_interval", 1))
+
+
 def run_cell(spec: dict, args, child: Child) -> dict:
     """Serve the cell's query and drive one run; returns the result line.
     The look for a chip is ``main``'s."""
@@ -376,7 +399,7 @@ def run_cell(spec: dict, args, child: Child) -> dict:
                 if k == 0:
                     # one projected re-trace now instead of a grow/replay
                     # ladder over the run
-                    driver.ch.presize(ratio=config["assumed"]["presize_ratio"])
+                    presize(driver.ch, config)
                 emit({"phase": "tick", "tick": k, "push_s": t["push_s"],
                       "step_s": t["step_s"],
                       "step_programs_traced":
@@ -399,8 +422,13 @@ def run_cell(spec: dict, args, child: Child) -> dict:
 
             if args.trace:
                 tracer.start()
+            traced_before = retrace.compile_counts().get("step_fn", 0)
+            replays_before = driver.ch.overflow_replays
             child.send(cmd="run", seconds=args.seconds)
             run = child.wait_for("run", on_event)
+            traced_in_window = retrace.compile_counts().get(
+                "step_fn", 0) - traced_before
+            replays_in_window = driver.ch.overflow_replays - replays_before
             tracer.stop()   # if the window closed before the trace did
             # the peak on the fullest chip, before any reference runs
             memory_peak = max((d.memory_stats() or {}).get(
@@ -415,8 +443,18 @@ def run_cell(spec: dict, args, child: Child) -> dict:
     trace = tracer.reduced
     setup_s = (run["open"] - T_START) if run["open"] is not None else None
     ticks = (run["last_tick"] or 0) + 1
+    n_window = len(measures.window_ticks(run))
+    reads_ms = measures.read_latencies_ms(run)
+    ages = measures.delta_ages(run)
     emit({"phase": "summary", "mode": driver.mode, "ticks": ticks,
-          "window_ticks": len(measures.window_ticks(run)),
+          "window_ticks": n_window,
+          # the sample behind each tail: a p95 wants ten beyond it
+          "ticks_beyond_p95": measures.beyond(n_window, 95),
+          "window_reads": len(reads_ms),
+          "reads_beyond_p95": measures.beyond(len(reads_ms), 95),
+          "reads_meeting_push": measures.reads_meeting_push(run),
+          "read_ms": measures.tails(reads_ms),
+          "delta_age_s": measures.tails(ages),
           "window_seconds": measures.window_seconds(run)
           if run["close"] is not None else None,
           "tick_seconds": measures.tick_seconds(run),
@@ -426,7 +464,11 @@ def run_cell(spec: dict, args, child: Child) -> dict:
           "programs_compiled": meter.requests - meter.cache_hits,
           "backend_compile_seconds": meter.seconds,
           "step_programs_traced": compiles.compiles.get("step_fn", 0),
+          "step_programs_traced_in_window": traced_in_window,
+          "compiles_in_window": measures.compiles_in_window(
+              run, meter.events),
           "overflow_replays": driver.ch.overflow_replays,
+          "overflow_replays_in_window": replays_in_window,
           "host_overhead_s": {k: sum(v) / 1e9 for k, v in
                               driver.ch.host_overhead_ns.items()},
           "kernel_dispatch": {f"{k}/{b}": n for (k, b), n in sorted(
@@ -499,8 +541,7 @@ def run_cell(spec: dict, args, child: Child) -> dict:
     broken = sum(1 for kind, _, _, ok in run["ops"]
                  if kind in ("push", "step") and not ok)
     compared["push_or_step_failed"] = {"value": broken, "limit": 0}
-    compared["window_ticks"] = {"value": len(measures.window_ticks(run)),
-                                "at_least": 1}
+    compared["window_ticks"] = {"value": n_window, "at_least": 1}
     # a published tick that no /changefeed or /view response ever showed
     # is an answer that never came
     compared["ticks_never_visible"] = {
@@ -516,14 +557,12 @@ def run_cell(spec: dict, args, child: Child) -> dict:
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     else:
-        ages = measures.delta_ages(run)
         values = {
             "events_per_s": measures.events_per_s(
                 run, config["events_per_tick"]),
             "delta_age_p95_s": measures.percentile(ages, 95)
             if ages else None,
-            "read_p95_ms": measures.percentile(
-                measures.read_latencies_ms(run), 95),
+            "read_p90_ms": measures.percentile(reads_ms, 90),
             "setup_s": setup_s,
         }
         for m in spec["end_to_end"]:

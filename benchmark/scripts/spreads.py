@@ -5,9 +5,13 @@ spread = (third quartile - first quartile) / median, with the quartiles of
 
     python3 benchmark/scripts/spreads.py chiprun_out q4A q4B
 
-reads the last line of every ``<dir>/<label>_s<seed>.out`` and prints, for
-each metric, each set's median and spread, and the second median against
-the first.
+reads every ``<dir>/<label>_s<seed>.out`` and prints, for each metric of
+the result lines and for the facts a run's ``summary`` line states beside
+them (the sample behind each tail, the tails a bounded metric could be
+taken at, what compiled in the window), every run's value in seed order,
+each set's median and spread — also as the driver takes it for tightness,
+without the run farthest from the median — the second median against the
+first, and the spread of all the sets' runs pooled.
 """
 
 from __future__ import annotations
@@ -18,36 +22,87 @@ import os
 import statistics
 import sys
 
+#: facts of the summary line printed beside the metrics; a dot goes one
+#: level down
+FACTS = (
+    "window_seconds", "window_ticks", "window_reads", "reads_beyond_p95",
+    "reads_meeting_push", "read_ms.p50", "read_ms.p90", "read_ms.p95",
+    "read_ms.max", "delta_age_s.p50", "delta_age_s.p90", "delta_age_s.p95",
+    "delta_age_s.max", "compiles_in_window",
+    "step_programs_traced_in_window", "overflow_replays_in_window",
+    "peak_device_bytes")
 
-def last_line(path: str) -> dict:
+
+def read_run(path: str) -> dict:
+    """One run's numbers by name: the result line's metrics, then the
+    summary line's facts; ``correct`` under its own key."""
     with open(path) as f:
-        return json.loads(f.read().strip().splitlines()[-1])
+        lines = [json.loads(x) for x in f.read().splitlines()
+                 if x.startswith("{")]
+    result = [x for x in lines if "correct" in x and "metrics" in x][-1]
+    out = {"correct": result["correct"], "failed": result["failed"]}
+    for n, m in result["metrics"].items():
+        out[n] = m["value"]
+    summary = next((x for x in lines if x.get("phase") == "summary"), {})
+    for name in FACTS:
+        v = summary
+        for k in name.split("."):
+            v = v.get(k) if isinstance(v, dict) else None
+        if v is not None:
+            out["(" + name + ")"] = v
+    return out
+
+
+def spread(vs: list) -> float:
+    if len(vs) < 2 or not statistics.median(vs):
+        return float("nan")
+    q = statistics.quantiles(vs, n=4)
+    return (q[2] - q[0]) / statistics.median(vs)
+
+
+def without_farthest(vs: list) -> list:
+    med = statistics.median(vs)
+    far = max(vs, key=lambda v: abs(v - med))
+    rest = list(vs)
+    rest.remove(far)
+    return rest
 
 
 def main(argv) -> int:
     directory, labels = argv[1], argv[2:]
-    medians: dict = {}
+    sets: dict = {}
     for label in labels:
-        runs = [last_line(p) for p in sorted(
-            glob.glob(os.path.join(directory, f"{label}_s*.out")))]
-        bad = [r for r in runs if not r["correct"]]
-        print(f"{label}: {len(runs)} runs, {len(bad)} not correct")
-        names = sorted({n for r in runs for n in r["metrics"]})
-        for n in names:
-            vs = [r["metrics"][n]["value"] for r in runs if n in r["metrics"]]
+        paths = sorted(glob.glob(os.path.join(directory, f"{label}_s*.out")))
+        runs = [read_run(p) for p in paths]
+        bad = [r for r in runs if not r["correct"] or r["failed"]]
+        print(f"{label}: {len(runs)} runs, {len(bad)} not correct or with "
+              f"failed operations")
+        sets[label] = runs
+    names = []
+    for runs in sets.values():
+        for r in runs:
+            names += [n for n in r if n not in names
+                      and n not in ("correct", "failed")]
+    for n in names:
+        print(n)
+        medians, pooled = [], []
+        for label, runs in sets.items():
+            vs = [r[n] for r in runs if n in r]
+            if not vs:
+                continue
+            pooled += vs
             med = statistics.median(vs)
-            if len(vs) >= 2:
-                q = statistics.quantiles(vs, n=4)
-                spread = (q[2] - q[0]) / med
-            else:
-                spread = float("nan")
-            medians.setdefault(n, []).append(med)
-            print(f"  {n}: median {med:.6g} spread {100 * spread:.2f} % "
-                  f"min {min(vs):.6g} max {max(vs):.6g} n {len(vs)}")
-    for n, ms in medians.items():
-        if len(ms) == 2:
-            print(f"{n}: second median against first "
-                  f"{100 * (ms[1] / ms[0] - 1):+.2f} %")
+            medians.append(med)
+            trimmed = without_farthest(vs) if len(vs) > 2 else vs
+            print(f"  {label}: {' '.join(f'{v:.6g}' for v in vs)} | median "
+                  f"{med:.6g} spread {100 * spread(vs):.2f} % (without the "
+                  f"farthest {100 * spread(trimmed):.2f} %)")
+        if len(medians) == 2 and medians[0]:
+            print(f"  second median against first "
+                  f"{100 * (medians[1] / medians[0] - 1):+.2f} %")
+        if len(medians) > 1:
+            print(f"  pooled: median {statistics.median(pooled):.6g} spread "
+                  f"{100 * spread(pooled):.2f} % n {len(pooled)}")
     return 0
 
 

@@ -189,6 +189,18 @@ def window_read_spans(spans: list, run: dict, measures) -> list | None:
     return out
 
 
+def read_handler_ms(ctx: dict, q: float) -> float | None:
+    """Percentile ``q`` of the ``read`` spans of the window's answered
+    reads, ms; None unless every one of them is in the ring."""
+    win = window_of(ctx)
+    if win is None:
+        return None
+    reads = window_read_spans(win.spans, ctx["run"], ctx["measures"])
+    if not reads:
+        return None
+    return ctx["measures"].percentile([s.seconds * 1e3 for s in reads], q)
+
+
 def name_gaps(spans: list, gaps: list) -> list:
     """For each ``(start, end)`` gap in seconds on the spans' clock: the
     seconds of it by innermost open span, ``{name: seconds}``. Where a

@@ -153,3 +153,42 @@ def test_fault_reads_not_correct(fault, workload, monkeypatch):
     line = _run(workload)
     assert line["correct"] is False
     assert line["compared"]["rows_mismatched"]["value"] > 0
+
+
+# -- the four-chip cell: the exchange between chips left out ------------------
+
+_FOUR_WORKERS = """
+import sys
+sys.path.insert(0, {bench!r})
+sys.path.insert(0, {root!r})
+import jax
+import run as harness
+if {fault}:
+    # every bucketed row stays on the worker that held it
+    jax.lax.all_to_all = lambda x, *a, **kw: x
+sys.exit(harness.main([
+    "--workload", "nexmark-q4-4w.saturated", "--seed", "5", "--seconds", "2",
+    "--trace", "0", "--rehearse-events", "600"]))
+"""
+
+
+@pytest.mark.parametrize("fault", (False, True),
+                         ids=("sound", "exchange_left_out"))
+def test_four_workers_exchange_left_out_reads_not_correct(fault):
+    """``nexmark-q4-4w.saturated`` on four virtual devices, in a process of
+    its own (the device count is fixed when JAX starts): with
+    ``all_to_all`` made the identity each worker averages the maxima it
+    happens to hold, and the gathered view is not the reference's."""
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run(
+        [sys.executable, "-c", _FOUR_WORKERS.format(
+            bench=BENCH, root=os.path.dirname(BENCH), fault=fault)],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["device"]["count"] == 4
+    assert line["correct"] is (not fault)
+    assert (line["compared"]["rows_mismatched"]["value"] > 0) is fault

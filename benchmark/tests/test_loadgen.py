@@ -150,3 +150,70 @@ def test_one_batch_in_flight():
              if p == "/step" or p.startswith("/input_endpoint/")]
     assert posts == (["/input_endpoint/persons", "/input_endpoint/auctions",
                       "/input_endpoint/bids", "/step"] * 4)
+
+
+# -- the sample behind each tail ----------------------------------------------
+
+
+def _recorded_run(ticks: int, tick_s: float, push_s: float,
+                  read_every_s: float, read_ms: float) -> dict:
+    """A run record written by hand: ``ticks`` window ticks (set-up tick 0
+    before them), each a push of ``push_s`` then a step of ``tick_s``; a
+    read due every ``read_every_s`` that takes ``read_ms``, and waits out
+    the push where it meets one."""
+    run = {"open": 100.0, "push": {}, "step_sent": {}, "step_done": {},
+           "visible": {}, "reads": [], "ops": []}
+    t = run["open"]
+    for k in range(1, ticks + 1):
+        run["push"][str(k)] = (t, t + push_s)
+        run["step_sent"][str(k)] = t + push_s
+        t += push_s + tick_s
+        run["step_done"][str(k)] = t
+        run["visible"][str(k + 1)] = t + 0.01
+    run["close"] = t
+    i = 0
+    while run["open"] + i * read_every_s <= run["close"]:
+        due = run["open"] + i * read_every_s
+        got = due + read_ms / 1e3
+        for a, b in run["push"].values():
+            if a <= due < b:
+                got = b + read_ms / 1e3
+        run["reads"].append((due, due, got, True))
+        i += 1
+    return run
+
+
+def test_sample_counts_of_a_recorded_run():
+    """What the ``summary`` fact line states beside each tail: 40 ticks of
+    0.63 s with a 0.055 s push, a read every 100 ms."""
+    run = _recorded_run(40, 0.63, 0.055, 0.1, 2.5)
+    assert len(measures.window_ticks(run)) == 40
+    assert measures.beyond(40, 95) == 2            # the p95 tick is the 38th
+    reads = measures.read_latencies_ms(run)
+    assert len(reads) == 275                       # 27.4 s at 10 a second
+    assert measures.beyond(len(reads), 95) == 13   # the 262nd of 275
+    assert measures.beyond(120, 95) == 6           # the old window's p95
+    assert measures.beyond(0, 95) == 0 and measures.beyond(1, 95) == 0
+    met = measures.reads_meeting_push(run)
+    assert 20 <= met <= 40                         # 40 pushes of 0.055 s
+    # a read that met a push waited it out: the tail holds them, the median
+    # and the p90 do not
+    t = measures.tails(reads)
+    assert t["p50"] == pytest.approx(2.5) and t["p90"] == pytest.approx(2.5)
+    assert t["max"] > 50.0 and t["p95"] > 2.5
+    assert sum(1 for r in reads if r > 2.6) <= met
+    assert measures.tails([]) is None
+
+
+def test_committed_mix_supports_a_p95():
+    """The committed ``saturated`` mix holds at least 32 window ticks at a
+    reader interval of at most 100 ms: at the ledger's 0.63 s tick (PR 31,
+    q4) 200 reads or more, ten or more beyond a p95. An edit of the mix
+    that starves the tail again fails here."""
+    with open(os.path.join(BENCH, "traffic", "saturated.json")) as f:
+        mix = json.load(f)
+    assert mix["max_window_ticks"] >= 32
+    assert mix["reader_interval_ms"] <= 100
+    reads = int(mix["max_window_ticks"] * 0.63
+                / (mix["reader_interval_ms"] / 1e3))
+    assert reads >= 200 and measures.beyond(reads, 95) >= 10
