@@ -687,9 +687,8 @@ def _measure_compiled_query(qname: str, platform: str, detail: dict) -> float:
         k: int(v - consolidate_before.get(k, 0))
         for k, v in _zk.CONSOLIDATE_COUNTS.items()}
     # kernel-dispatch decisions (zset/kernels.py KERNEL_DISPATCH_COUNTS):
-    # which backend (native/xla/pallas) each kernel entry point selected
-    # during this query — the A/B evidence for DBSP_TPU_NATIVE /
-    # DBSP_TPU_PALLAS force-off runs
+    # which backend (native/xla) each kernel entry point selected during
+    # this query — the A/B evidence for DBSP_TPU_NATIVE force-off runs
     detail["kernel_paths"] = {
         f"{kern}:{backend}": int(v - kernel_paths_before.get((kern, backend),
                                                             0))

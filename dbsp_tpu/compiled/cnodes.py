@@ -756,7 +756,7 @@ class CAggregate(CNode):
         # (exact q_cap expansion: it holds one live row per present key),
         # the touched groups' ladder histories netted + reduced, and the
         # fast path's delta-side reduction (cursor.agg_ladder — native
-        # megakernel / Pallas / stitched XLA control)
+        # megakernel / stitched XLA control)
         (qkeys, qlive, nq, old_vals, old_present, lad_vals, lad_present,
          d_vals, d_present, gtot) = cursor.agg_ladder(
             delta, nk, out_trace, view.post, agg, q_cap,

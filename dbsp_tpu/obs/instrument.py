@@ -68,8 +68,8 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
     """Register a collector mirroring the kernel dispatch decisions
     (``zset/kernels.py::KERNEL_DISPATCH_COUNTS``) as
     ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` — which
-    implementation (native C++ custom call / pure XLA / Pallas) each Z-set
-    kernel entry point selected. Same counting convention as the
+    implementation (native C++ custom call / pure XLA) each Z-set kernel
+    entry point selected. Same counting convention as the
     consolidation-path counter: dispatch DECISIONS (per eval eagerly, per
     trace under jit), not per-tick kernel volume — the metric answers "is
     this pipeline on the kernels I think it is", e.g. after a
@@ -81,9 +81,9 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
     counter = registry.counter(
         "dbsp_tpu_zset_kernel_dispatch_total",
         "Z-set kernel dispatch decisions by entry point and backend "
-        "(native = C++ FFI custom call, xla = pure-XLA lowering, "
-        "pallas = hand-written Pallas program; an accelerator's XLA "
-        "formulation is named where it is not the CPU's: xla_bitonic = the "
+        "(native = C++ FFI custom call, xla = pure-XLA lowering; an "
+        "accelerator's XLA formulation is named where it is not the "
+        "CPU's: xla_bitonic = the "
         "bitonic merge network behind kernel=merge and kernel=sort_merge, "
         "a sort of more than SORT_CHUNK_ROWS rows; xla_shift = the shift "
         "compaction behind kernel=compact); the fused ladder-consumer "

@@ -58,8 +58,9 @@ ALLOWED_LABEL_NAMES = frozenset((
     "query", "kind",
     # kernel dispatch attribution: "kernel" names a Z-set kernel entry
     # point (merge/probe/expand/...), "backend" the implementation it
-    # dispatched to (native/xla/pallas) — both closed, enumerable sets
-    # (zset/native_merge.py::KERNELS x three backends)
+    # dispatched to (native, xla, or an accelerator's xla_* formulation)
+    # — both closed, enumerable sets (zset/native_merge.py::KERNELS x the
+    # backends kernels.count_kernel_dispatch is called with)
     "kernel", "backend",
     # tiered trace residency (dbsp_tpu/residency.py): "tier" and the
     # transition endpoints draw from the closed {device, host, disk} set

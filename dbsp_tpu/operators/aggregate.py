@@ -229,14 +229,6 @@ def segment_reduce(spec, val_cols, weights: jnp.ndarray, seg: jnp.ndarray,
     fused_ok = all(op != "avg" or jnp.dtype(dt) == jnp.int64
                    for (op, _), dt in zip(spec, out_dtypes))
     if fused_ok and weights.ndim == 1 and num_segments >= 1:
-        if kernels.pallas_requested():
-            from dbsp_tpu.zset import pallas_kernels
-
-            if pallas_kernels.use_pallas("segment_reduce",
-                                         (*val_cols, weights)):
-                kernels.count_kernel_dispatch("segment_reduce", "pallas")
-                return pallas_kernels.segment_reduce_pallas(
-                    spec, val_cols, weights, seg, num_segments, out_dtypes)
         if kernels.native_kernel("segment_reduce"):
             from dbsp_tpu.zset import native_merge
 

@@ -14,8 +14,7 @@ This module collapses that fan-out (the engine's answer to the reference's
 * :func:`lex_probe_ladder` — ONE vectorized lexicographic search over the
   whole level ladder: [K, m] (level, query) lanes share a single unrolled
   binary-search loop (on CPU with the native library, ONE ladder-wide C++
-  probe call; on accelerator backends a Pallas grid-over-levels program —
-  same result, same shape).
+  probe call — same result, same shape).
 * :func:`expand_ladder` — ONE ``expand_ranges``-style prefix-sum allocation
   whose [K*m] counts span levels: each output slot resolves to (level,
   query row, source row) through a single searchsorted over the cross-level
@@ -26,11 +25,11 @@ This module collapses that fan-out (the engine's answer to the reference's
   distinct old-weight lookup) as single fused kernels over the ladder.
   On CPU with the native library each consumer is ONE megakernel custom
   call (probe + expand + gather + weight-combine —
-  ``native_merge.join_ladder_native`` & co.); with Pallas selected it is
-  one grid-over-levels megakernel (``pallas_kernels.join_ladder_pallas``);
-  the stitched probe-ladder/expand/gather chain below is the pure-XLA
-  fallback and the force-off A/B control (``DBSP_TPU_NATIVE=join_ladder``
-  etc. — see ``native_merge.kernel_enabled``).
+  ``native_merge.join_ladder_native`` & co.); the stitched
+  probe-ladder/expand/gather chain below is the pure-XLA formulation —
+  what an accelerator runs — and the force-off A/B control
+  (``DBSP_TPU_NATIVE=join_ladder`` etc. — see
+  ``native_merge.kernel_enabled``).
 
 All functions are pure/traceable over 1-D row axes; sharded callers lift
 them per worker exactly like the per-level kernels they replace
@@ -72,17 +71,6 @@ def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
     m = query_cols[0].shape[0] if query_cols else 0
     if query_cols and query_cols[0].ndim == 1:
         dts = [c.dtype for t in tables for c in t]
-        # cheap pre-check before importing the pallas module: the CPU
-        # backend without an explicit override never selects it, and the
-        # import itself is not free on cold start
-        if kernels.pallas_requested():
-            from dbsp_tpu.zset import pallas_kernels
-
-            all_cols = (*(c for t in tables for c in t), *query_cols)
-            if pallas_kernels.use_pallas("probe_ladder", all_cols):
-                kernels.count_kernel_dispatch("probe_ladder", "pallas")
-                return pallas_kernels.lex_probe_ladder_pallas(
-                    tables, query_cols, side)
         if kernels.native_kernel("probe_ladder"):
             from dbsp_tpu.zset import native_merge
 
@@ -235,21 +223,14 @@ def join_ladder(delta: Batch, levels: Sequence[Batch], nk: int, fn,
 
     Backend dispatch (1-D operands, int64-widenable columns): ONE native
     megakernel custom call on CPU (probe + expand + both-side gathers +
-    weight product — ``native_merge.join_ladder_native``); one Pallas
-    grid-over-levels megakernel when Pallas is selected; else the stitched
-    probe-ladder/expand/gather chain below (also the
+    weight product — ``native_merge.join_ladder_native``); else the
+    stitched probe-ladder/expand/gather chain below (also the
     ``DBSP_TPU_NATIVE=join_ladder`` force-off control).
     """
     assert levels, "join_ladder: trace has no levels"
     dk = delta.keys[:nk]
     if nk >= 1 and delta.weights.ndim == 1 and out_cap >= 1:
-        # Pallas takes precedence: there is no sorted-emit Pallas mode
-        # (the TPU rank-merge regime owns consolidation there), and a
-        # DBSP_TPU_PALLAS force-on must actually measure the Pallas
-        # program — a native kernel preempting it would silently turn the
-        # Pallas-vs-XLA A/B into a native measurement
-        if sorted_emit is not None and not kernels.pallas_requested() and \
-                kernels.native_kernel("join_sorted"):
+        if sorted_emit is not None and kernels.native_kernel("join_sorted"):
             from dbsp_tpu.zset import native_merge
 
             n_out_keys, perm, out_dts = sorted_emit
@@ -258,22 +239,6 @@ def join_ladder(delta: Batch, levels: Sequence[Batch], nk: int, fn,
                 kernels.count_kernel_dispatch("join_sorted", "native")
                 return native_merge.join_ladder_sorted_native(
                     delta, levels, nk, perm, n_out_keys, out_dts, out_cap)
-        if kernels.pallas_requested():
-            from dbsp_tpu.zset import pallas_kernels
-
-            if pallas_kernels.use_pallas(
-                    "join_ladder",
-                    (*delta.cols, delta.weights,
-                     *(c for lvl in levels
-                       for c in (*lvl.cols, lvl.weights)))):
-                kernels.count_kernel_dispatch("join_ladder", "pallas")
-                qrow, rvals, w, valid, total = \
-                    pallas_kernels.join_ladder_pallas(
-                        dk, delta.weights, levels, nk, out_cap)
-                key_cols = tuple(c[qrow] for c in dk)
-                lvals = tuple(c[qrow] for c in delta.vals)
-                return _finish_join(fn, key_cols, lvals, rvals, w, valid,
-                                    total)
         if kernels.native_kernel("join_ladder"):
             from dbsp_tpu.zset import native_merge
 
@@ -327,9 +292,8 @@ def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
 
     Backend dispatch mirrors :func:`join_ladder`: ONE native megakernel
     custom call on CPU (``native_merge.gather_ladder_native`` — the part
-    comes back final, dead slots canonical), one Pallas megakernel when
-    selected, else the stitched chain (the ``DBSP_TPU_NATIVE=gather_ladder``
-    force-off control)."""
+    comes back final, dead slots canonical), else the stitched chain (the
+    ``DBSP_TPU_NATIVE=gather_ladder`` force-off control)."""
     assert levels, "gather_ladder: trace has no levels"
     nk = len(qkeys)
     q_cap = qlive.shape[-1]
@@ -337,14 +301,6 @@ def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
         _all_cols = (*qkeys, *(qhi_keys or ()),
                      *(c for lvl in levels
                        for c in (*lvl.cols, lvl.weights)))
-        if kernels.pallas_requested():
-            from dbsp_tpu.zset import pallas_kernels
-
-            if pallas_kernels.use_pallas("gather_ladder", _all_cols):
-                kernels.count_kernel_dispatch("gather_ladder", "pallas")
-                return pallas_kernels.gather_ladder_pallas(
-                    qkeys, qlive, levels, out_cap, qhi_keys=qhi_keys,
-                    gather_keys=gather_keys)
         if kernels.native_kernel("gather_ladder"):
             from dbsp_tpu.zset import native_merge
 
@@ -398,10 +354,9 @@ def agg_ladder(delta: Batch, nk: int, out_trace: Batch,
     Backend dispatch mirrors :func:`join_ladder`: ONE native megakernel
     custom call on CPU for spec'd aggregators
     (``native_merge.agg_ladder_native`` — the gathered history never
-    materializes at all); a composed Pallas lowering when Pallas is
-    selected (the grid-over-levels gather megakernel + the Pallas segment
-    reduce); else the stitched unique-keys/gather/net/reduce chain below
-    (also the ``DBSP_TPU_NATIVE=agg_ladder`` force-off control)."""
+    materializes at all); else the stitched unique-keys/gather/net/reduce
+    chain below (also the ``DBSP_TPU_NATIVE=agg_ladder`` force-off
+    control)."""
     from dbsp_tpu.operators import aggregate as A
 
     assert levels, "agg_ladder: trace has no levels"
@@ -430,14 +385,6 @@ def agg_ladder(delta: Batch, nk: int, out_trace: Batch,
                      out_trace.weights,
                      *(c for lvl in levels for c in (*lvl.cols,
                                                      lvl.weights)))
-        if kernels.pallas_requested():
-            from dbsp_tpu.zset import pallas_kernels
-
-            if pallas_kernels.use_pallas("agg_ladder", _all_cols):
-                kernels.count_kernel_dispatch("agg_ladder", "pallas")
-                return pallas_kernels.agg_ladder_pallas(
-                    delta, nk, out_trace, levels, agg, q_cap, gather_cap,
-                    fast, flag)
         if kernels.native_kernel("agg_ladder"):
             from dbsp_tpu.zset import native_merge
 
@@ -454,7 +401,7 @@ def agg_ladder(delta: Batch, nk: int, out_trace: Batch,
 def _agg_ladder_stitched(delta: Batch, nk: int, out_trace: Batch, levels,
                          agg, q_cap: int, gather_cap: int, fast: bool,
                          flag):
-    """The pure-XLA fallback and force-off A/B control: the chain
+    """The pure-XLA formulation and force-off A/B control: the chain
     CAggregate.eval used to stitch inline, with the run-boundary scan done
     ONCE (``_delta_groups_impl`` feeds both the unique-key compaction and
     the fast path's segment ids — the boundaries were previously scanned

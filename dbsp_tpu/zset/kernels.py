@@ -74,10 +74,10 @@ def count_consolidate_path(path: str) -> None:
 
 # Which implementation each kernel entry point dispatched to, keyed by
 # (kernel, backend) with backend one of "native" (C++ FFI custom call),
-# "xla" (pure-XLA lowering), "pallas" (hand-written Pallas program) or, where
-# an accelerator's XLA formulation is not the CPU's, its name: "xla_bitonic"
-# (the merge network behind "merge" and "sort_merge", a sort of more than
-# SORT_CHUNK_ROWS rows) and "xla_shift" (the shift compaction of "compact").
+# "xla" (pure-XLA lowering) or, where an accelerator's XLA formulation is
+# not the CPU's, its name: "xla_bitonic" (the merge network behind "merge"
+# and "sort_merge", a sort of more than SORT_CHUNK_ROWS rows) and
+# "xla_shift" (the shift compaction of "compact").
 # Same counting convention as CONSOLIDATE_COUNTS (eager calls per eval,
 # traced calls per trace); exported by obs as
 # ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` and embedded in
@@ -91,6 +91,16 @@ def count_kernel_dispatch(kernel: str, backend: str) -> None:
     KERNEL_DISPATCH_COUNTS[key] = KERNEL_DISPATCH_COUNTS.get(key, 0) + 1
 
 
+def accelerator() -> bool:
+    """Does this process compute off the CPU? The ONE place that decides
+    which formulation a kernel takes: its native C++ call on a CPU with the
+    library (:func:`native_kernel`), the accelerator's formulation (merge
+    network, shift compaction, chunked sort, doubling group sums) off the
+    CPU, the plain XLA one else. Asked at every call — never cached: tests
+    and the benchmark's rehearsals replace ``jax.default_backend``."""
+    return jax.default_backend() != "cpu"
+
+
 def native_kernel(kernel: str) -> bool:
     """Should ``kernel`` dispatch to its native C++ implementation HERE?
 
@@ -98,50 +108,12 @@ def native_kernel(kernel: str) -> bool:
     the kernel not forced off via ``DBSP_TPU_NATIVE`` (csv force-off list;
     ``0`` = all off — see ``native_merge.kernel_enabled``). Callers still
     check dtype support per call site."""
-    import jax
-
-    if jax.default_backend() != "cpu":
+    if accelerator():
         return False
     from dbsp_tpu.zset import native_merge
 
     return native_merge.available() and native_merge.kernel_enabled(kernel)
 
-
-# The DBSP_TPU_PALLAS spellings that select the Pallas kernels on the CPU
-# backend, where they run under the Pallas interpreter — the ONE definition
-# shared by the dispatch pre-checks here/in cursor.py and
-# pallas_kernels.enabled(), so the grammar cannot drift between the cheap
-# check and the real one.
-PALLAS_FORCE_ON = ("1", "on", "interpret")
-
-# The Pallas programs the TPU's compiler accepts, by dispatch name: off the
-# CPU backend the dispatch selects exactly these and every other kernel
-# takes its plain-XLA formulation. None today — compiled for a described
-# v5e with interpret=False, probe_ladder / join_ladder / gather_ladder are
-# refused for their (1, 1)-of-(K, 1) and (1, cap)-of-(K, cap) block shapes
-# (last two block dims must divide by 8 and 128 or equal the array's), and
-# rank_merge / segment_reduce for their int64 operands ("64-bit types are
-# not supported"). tests/test_tpu_compile.py holds every name to the
-# compiler's verdict: listed here <=> it compiles.
-PALLAS_TPU_COMPILED: frozenset = frozenset()
-
-
-def pallas_requested() -> bool:
-    """Cheap pre-check for the Pallas dispatch branch WITHOUT importing
-    the pallas module (not free on CPU cold start): an accelerator backend
-    with at least one program its compiler accepts, or an explicit
-    DBSP_TPU_PALLAS force-on on the CPU. The full gate (per-kernel
-    selection, the force-off spellings and dtype support) lives in
-    ``pallas_kernels.use_pallas`` — this only decides whether that module
-    is worth importing."""
-    import os
-
-    import jax
-
-    if jax.default_backend() != "cpu":
-        return bool(PALLAS_TPU_COMPILED)
-    return os.environ.get("DBSP_TPU_PALLAS", "").strip().lower() in \
-        PALLAS_FORCE_ON
 
 # ---------------------------------------------------------------------------
 # Sentinels
@@ -200,7 +172,7 @@ def sort_rows(cols: Sequence[jnp.ndarray], payload: Sequence[jnp.ndarray]
         return (), tuple(payload)
     ops = (*cols, *payload)
     if cols[0].ndim == 1 and cols[0].shape[0] > SORT_CHUNK_ROWS and \
-            jax.default_backend() != "cpu":
+            accelerator():
         count_kernel_dispatch("sort_merge", "xla_bitonic")
         out = _sort_rows_chunked(ops, len(cols), SORT_CHUNK_ROWS)
     else:
@@ -390,7 +362,7 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
         if native_merge.supports(c.dtype for c in cols):
             count_kernel_dispatch("compact", "native")
             return native_merge.compact_native(cols, weights, keep)
-    if weights.ndim == 1 and jax.default_backend() != "cpu":
+    if weights.ndim == 1 and accelerator():
         count_kernel_dispatch("compact", "xla_shift")
         *out_cols, w = _compact_shift((*cols, weights), keep)
         return tuple(out_cols), w
@@ -454,7 +426,7 @@ def _net_sorted(cols: Sequence[jnp.ndarray], w: jnp.ndarray
     group's rows are interchangeable)."""
     n = w.shape[0]
     dup = rows_equal_prev(cols, n=n)
-    if jax.default_backend() != "cpu":
+    if accelerator():
         return _group_sums(dup, w)
     seg = jnp.cumsum(~dup) - 1  # segment id per row
     sums = jax.ops.segment_sum(w, seg, num_segments=n)
@@ -536,9 +508,7 @@ def merge_strategy() -> str:
     build, the ``merge`` kernel is forced off (``DBSP_TPU_NATIVE``), or a
     column dtype (float) isn't int64-widenable.
     """
-    import jax
-
-    if jax.default_backend() != "cpu":
+    if accelerator():
         return "bitonic"
     return "native" if native_kernel("merge") else "sort"
 
@@ -561,33 +531,23 @@ def merge_sorted_cols(cols_a: Sequence[jnp.ndarray], w_a: jnp.ndarray,
     if not cols_a:  # zero-column (unit-row) sets: nothing to order
         return consolidate_cols((), jnp.concatenate([w_a, w_b]))
     strategy = merge_strategy()
-    if strategy == "native":
-        from dbsp_tpu.zset import native_merge
-
-        if w_a.ndim == 1 and \
-                native_merge.supports(c.dtype for c in cols_a):
-            count_kernel_dispatch("merge", "native")
-            return native_merge.merge_consolidated_cols(cols_a, w_a,
-                                                        cols_b, w_b)
-        strategy = "sort"
-    if strategy == "sort":
-        count_kernel_dispatch("merge", "xla")
-        cols = tuple(jnp.concatenate([a, b.astype(a.dtype)])
-                     for a, b in zip(cols_a, cols_b))
-        return consolidate_cols(cols, jnp.concatenate([w_a, w_b]))
-    from dbsp_tpu.zset import pallas_kernels
-
-    if pallas_kernels.use_pallas("rank_merge", (*cols_a, *cols_b)) and \
-            w_a.ndim == 1:
-        count_kernel_dispatch("merge", "pallas")
-        out_cols, w = pallas_kernels.rank_merge_scatter(
-            cols_a, w_a, cols_b, w_b)
-    else:
+    if strategy == "bitonic":
         count_kernel_dispatch("merge", "xla_bitonic")
         *out_cols, w = _merge_runs((*cols_a, w_a), (*cols_b, w_b),
                                    len(cols_a))
-    w = _net_sorted(out_cols, w)
-    return compact(out_cols, w, w != 0)
+        w = _net_sorted(out_cols, w)
+        return compact(out_cols, w, w != 0)
+    if strategy == "native" and w_a.ndim == 1:
+        from dbsp_tpu.zset import native_merge
+
+        if native_merge.supports(c.dtype for c in cols_a):
+            count_kernel_dispatch("merge", "native")
+            return native_merge.merge_consolidated_cols(cols_a, w_a,
+                                                        cols_b, w_b)
+    count_kernel_dispatch("merge", "xla")
+    cols = tuple(jnp.concatenate([a, b.astype(a.dtype)])
+                 for a, b in zip(cols_a, cols_b))
+    return consolidate_cols(cols, jnp.concatenate([w_a, w_b]))
 
 
 # ---------------------------------------------------------------------------

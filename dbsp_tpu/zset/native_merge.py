@@ -420,8 +420,7 @@ def _sentinel64(dtypes) -> tuple:
     """Per-dtype sentinel values widened to int64 (host ints — traceable),
     derived from the ONE dead-row sentinel definition
     (``kernels.sentinel_scalar``) so the native megakernels' dead slots
-    can never drift from the stitched/Pallas backends' bit-identity
-    contract."""
+    can never drift from the stitched backend's bit-identity contract."""
     from dbsp_tpu.zset import kernels
 
     return tuple(int(kernels.sentinel_scalar(d)) for d in dtypes)
@@ -533,8 +532,8 @@ def old_weights_ladder_native(delta, levels) -> jnp.ndarray:
 
 
 # Segment-reduction opcodes shared with the C++ SegAccum (zset_merge.cpp)
-# and the Pallas twin — ONE vocabulary for every backend of the Aggregator
-# zoo's five reductions (+ the presence mask).
+# — ONE vocabulary for every backend of the Aggregator zoo's five
+# reductions (+ the presence mask).
 SEG_OPS = {"count": 0, "sum": 1, "min": 2, "max": 3, "avg": 4, "present": 5}
 
 

@@ -49,6 +49,19 @@ def _bound_live_executables():
     gc.collect()
 
 
+@pytest.fixture
+def accelerator_dispatch(monkeypatch):
+    """Steer the backend-keyed dispatch (``zset.kernels.accelerator``) to
+    its accelerator branches on the CPU: ``jax.default_backend`` answers
+    ``"tpu"`` for the rest of the test. A jitted program traced under one
+    dispatch must not be served under the other, so JAX's caches are
+    dropped on both sides."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    jax.clear_caches()
+
+
 # ---------------------------------------------------------------------------
 # Test tiers: `pytest -m fast` is the <2-minute pre-commit subset — every
 # operator's correctness oracle at small scale. Tests/modules marked `slow`

@@ -106,18 +106,6 @@ def test_cell_rehearses_correct_on_four_virtual_devices(trace, monkeypatch):
 # -- (b) the accelerator formulations inside shard_map ------------------------
 
 
-@pytest.fixture
-def accelerator_dispatch(monkeypatch):
-    """Steer the backend-keyed dispatch to its accelerator branches, as
-    tests/test_zset.py does; programs traced under the other dispatch are
-    dropped on both sides."""
-    monkeypatch.delenv("DBSP_TPU_PALLAS", raising=False)
-    jax.clear_caches()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    yield
-    jax.clear_caches()
-
-
 def _mesh():
     from jax.sharding import Mesh
 

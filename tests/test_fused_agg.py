@@ -5,19 +5,21 @@ control.
 The tentpole collapsed CAggregate's reduce chain — unique-keys, out-trace
 probe + TupleMax, ladder gather, cross-level netting, aggregator segment
 reduction, fast-path delta reduction — into ONE ``cursor.agg_ladder`` call
-(native C++ megakernel on CPU, a composed Pallas lowering on accelerators,
-the stitched chain as fallback/control), rewired every built-in Aggregator
-through the shared five-op ``segment_reduce`` dispatch, and made the join
-emit each side as ONE consolidated run (``join_sorted``) so the post-join
-consolidate rank-folds instead of sorting. All of that is only legal
-because every backend produces identical values:
+(native C++ megakernel on CPU; the stitched chain as fallback/control and,
+over the accelerator's leaf formulations, what a chip runs), rewired every
+built-in Aggregator through the shared five-op ``segment_reduce`` dispatch,
+and made the join emit each side as ONE consolidated run (``join_sorted``)
+so the post-join consolidate rank-folds instead of sorting. All of that is
+only legal because every backend produces identical values:
 
-* kernel level: ``segment_reduce`` / ``agg_ladder`` / sorted-emit join
-  across native megakernel, Pallas interpret, the stitched-control
-  (``join_sorted,agg_ladder,segment_reduce`` forced off — the PR-12 code
-  path) and pure XLA — on adversarial inputs (all-retraction groups, empty
+* kernel level: ``segment_reduce`` / ``agg_ladder`` / sorted-emit join —
+  each backend of ``BACKENDS`` (the accelerator's formulation, the
+  stitched control ``join_sorted,agg_ladder,segment_reduce`` forced off —
+  the PR-12 code path — and pure XLA) a case of its own against the native
+  megakernel — on adversarial inputs (all-retraction groups, empty
   deltas, int32 weights, gather-cap overflow with exact unclamped totals,
-  duplicate keys across levels, runtime fast/slow flag both ways);
+  duplicate keys across levels, empty and full-capacity levels, runtime
+  fast/slow flag both ways);
 * engine level: q1–q8 accumulated outputs, host AND compiled, fused vs the
   reduction-off control, plus the fast→slow ``ever_negative`` transition
   bit-identical on BOTH sides of the flip;
@@ -39,35 +41,21 @@ from dbsp_tpu.operators.aggregate import (Average, Count, Max, Min, Sum,
                                           segment_reduce)
 from dbsp_tpu.operators.join import fn_permutation
 
-from test_fused_ladder import (REDUCE_OFF, _consolidated, _run_compiled,
-                               _run_host)
+from test_fused_ladder import (REDUCE_OFF, _adversarial_ladders,
+                               _consolidated, _run_compiled, _run_host,
+                               _took, _with_backend)
 
 pytestmark = pytest.mark.fast
 
-# env settings per backend: (DBSP_TPU_NATIVE, DBSP_TPU_PALLAS).
-# "stitched_control" is the committed A/B control (the PR-12 code path:
-# fused ladder consumers still native, the reduction layer forced off);
-# "pure_xla" strips the native kernels entirely.
-BACKENDS = {
-    "native_megakernel": ("1", "0"),
-    "pallas_interpret": ("0", "interpret"),
-    "stitched_control": (REDUCE_OFF, "0"),
-    "pure_xla": ("0", "0"),
-}
-
-
-def _with_backend(monkeypatch, backend, fn):
-    native, pallas = BACKENDS[backend]
-    monkeypatch.setenv("DBSP_TPU_NATIVE", native)
-    monkeypatch.setenv("DBSP_TPU_PALLAS", pallas)
-    try:
-        return fn()
-    finally:
-        monkeypatch.setenv("DBSP_TPU_NATIVE", "1")
-        monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
+# each a case of its own, compared with "native" (test_fused_ladder's
+# NATIVE_ENV)
+BACKENDS = ("accelerator", "stitched_control", "pure_xla")
+# segment_reduce has one XLA formulation, on the CPU and off it
+XLA_BACKENDS = ("stitched_control", "pure_xla")
 
 
 def _assert_same(got, want, ctx=""):
+    assert len(got) == len(want), ctx
     for i, (g, w) in enumerate(zip(got, want)):
         if g is None and w is None:
             continue
@@ -81,45 +69,47 @@ def _assert_same(got, want, ctx=""):
 # ---------------------------------------------------------------------------
 
 
+@pytest.mark.parametrize("backend", XLA_BACKENDS)
 @pytest.mark.parametrize("weight_dtype", [np.int64, np.int32])
-def test_segment_reduce_backends_bitidentical(monkeypatch, weight_dtype):
+def test_segment_reduce_backends_bitidentical(request, backend,
+                                              weight_dtype):
     rng = np.random.default_rng(0)
     spec = (("count", 0), ("sum", 0), ("min", 0), ("max", 1), ("avg", 1),
             ("present", 0))
-    for n, S in ((1, 1), (64, 7), (300, 41)):
+    cases = []
+    for n, S in ((1, 1), (64, 7), (300, 41), (500, 130)):
         v1 = jnp.asarray(rng.integers(-1000, 1000, n))
         v2 = jnp.asarray(rng.integers(-9, 9, n).astype(np.int32))
         w = jnp.asarray(rng.integers(-3, 4, n).astype(weight_dtype))
         # seg ids PAST num_segments must be dropped on every backend
         seg = jnp.asarray(rng.integers(0, S + 3, n).astype(np.int32))
-        ref = None
-        for backend in BACKENDS:
-            got = _with_backend(
-                monkeypatch, backend,
-                lambda: segment_reduce(spec, (v1, v2), w, seg, S))
-            if ref is None:
-                ref = got
-            else:
-                _assert_same(got, ref, f"segment_reduce {backend} n={n}")
+        cases.append(((v1, v2), w, seg, S))
+
+    def reduce_all():
+        return [o for vals, w, seg, S in cases
+                for o in segment_reduce(spec, vals, w, seg, S)]
+
+    want = _with_backend(request, "native", reduce_all)
+    _assert_same(_with_backend(request, backend, reduce_all), want,
+                 f"segment_reduce {backend}")
 
 
-def test_segment_reduce_all_retractions(monkeypatch):
+@pytest.mark.parametrize("backend", XLA_BACKENDS)
+def test_segment_reduce_all_retractions(request, backend):
     """Groups whose every row is a retraction: the additive ops see zero
     positive mass, min/max stay at their identity, present stays 0."""
     v = jnp.asarray([5, 9, -2, 7])
     w = jnp.asarray([-1, -2, -1, 3])
     seg = jnp.asarray([0, 0, 1, 2], jnp.int32)
     spec = (("count", 0), ("sum", 0), ("max", 0), ("present", 0))
-    ref = None
-    for backend in BACKENDS:
-        got = _with_backend(
-            monkeypatch, backend,
-            lambda: segment_reduce(spec, (v,), w, seg, 3))
-        if ref is None:
-            ref = got
-        else:
-            _assert_same(got, ref, f"all-retraction {backend}")
-    cnt, s, mx, pres = (np.asarray(x) for x in ref)
+
+    def reduce():
+        return segment_reduce(spec, (v,), w, seg, 3)
+
+    want = _with_backend(request, "native", reduce)
+    _assert_same(_with_backend(request, backend, reduce), want,
+                 f"all-retraction {backend}")
+    cnt, s, mx, pres = (np.asarray(x) for x in want)
     assert cnt[0] == 0 and s[0] == 0 and pres[0] == 0
     assert mx[0] == np.iinfo(np.int64).min  # identity never escapes raw
     assert cnt[2] == 3 and pres[2] == 1
@@ -148,55 +138,70 @@ def _agg_case(rng, weight_dtype=np.int64, empty_delta=False,
     return delta, levels, out_trace
 
 
+def _agg_all(cases, aggs, gather_cap=512):
+    """Every leaf of ``cursor.agg_ladder`` over ``cases`` x ``aggs`` x the
+    runtime flag's values, as one flat list."""
+    def run():
+        return [leaf
+                for delta, levels, out_trace in cases
+                for agg, fast in aggs
+                for flag in ((True, False) if fast else (True,))
+                for leaf in jax.tree_util.tree_leaves(cursor.agg_ladder(
+                    delta, 2, out_trace, levels, agg, 16, gather_cap, fast,
+                    jnp.asarray(flag)))]
+    return run
+
+
+def _assert_agg_backend(request, backend, run, ctx):
+    """``run`` under ``backend`` equals the native megakernels' leaves,
+    which are returned."""
+    want = _with_backend(request, "native", run)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    _assert_same(_with_backend(request, backend, run), want,
+                 f"{ctx} {backend}")
+    if backend == "accelerator":  # the chain a chip's kernel_dispatch shows
+        assert _took(before) >= {
+            ("agg_ladder", "xla"), ("gather_ladder", "xla"),
+            ("segment_reduce", "xla"), ("compact", "xla_shift")}
+    return want
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("weight_dtype", [np.int64, np.int32])
-def test_agg_ladder_backends_bitidentical(monkeypatch, weight_dtype):
+def test_agg_ladder_backends_bitidentical(request, backend, weight_dtype):
     rng = np.random.default_rng(1)
-    for case in ({}, {"empty_delta": True}, {"all_retract": True}):
-        delta, levels, out_trace = _agg_case(rng, weight_dtype, **case)
-        for agg, fast in AGGS:
-            for flag in ((True, False) if fast else (True,)):
-                ref = None
-                for backend in BACKENDS:
-                    got = _with_backend(
-                        monkeypatch, backend,
-                        lambda: cursor.agg_ladder(
-                            delta, 2, out_trace, levels, agg, 16, 512,
-                            fast, jnp.asarray(flag)))
-                    leaves = jax.tree_util.tree_leaves(got)
-                    if ref is None:
-                        ref = leaves
-                    else:
-                        _assert_same(
-                            leaves, ref,
-                            f"agg_ladder {backend} {agg.name} {case} "
-                            f"flag={flag}")
+    cases = [_agg_case(rng, weight_dtype, **case)
+             for case in ({}, {"empty_delta": True}, {"all_retract": True})]
+    _assert_agg_backend(request, backend, _agg_all(cases, AGGS),
+                        "agg_ladder")
 
 
-def test_agg_ladder_gather_overflow_exact(monkeypatch):
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_agg_ladder_adversarial_ladders(request, backend):
+    """Duplicate keys across four levels, an EMPTY level, a FULL-capacity
+    level without a dead tail: both values of the fast path's flag."""
+    rng = np.random.default_rng(21)
+    cases = [(_consolidated(rng, 20, 32), ladder, _consolidated(rng, 10, 16))
+             for ladder in _adversarial_ladders(rng)]
+    aggs = [(Max(0), True), (Count(), False), (Average(0), False)]
+    _assert_agg_backend(request, backend, _agg_all(cases, aggs),
+                        "agg_ladder adversarial")
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_agg_ladder_gather_overflow_exact(request, backend):
     """gather-cap overflow: every backend must report the SAME unclamped
     total (the requirement the runner's grow/replay keys off) AND the same
     clamped buffers — the megakernel counts raw rows in the stitched
     level-major order, so even the discarded overflow launch matches."""
     rng = np.random.default_rng(2)
-    delta = _consolidated(rng, 30, 32, key_range=5)
-    levels = [_consolidated(rng, 60, 128, key_range=5),
-              _consolidated(rng, 40, 64, key_range=5)]
-    out_trace = _consolidated(rng, 8, 16, key_range=5)
-    ref = None
-    totals = {}
-    for backend in BACKENDS:
-        got = _with_backend(
-            monkeypatch, backend,
-            lambda: cursor.agg_ladder(delta, 2, out_trace, levels, Sum(0),
-                                      16, 8, False, jnp.asarray(True)))
-        totals[backend] = int(got[-1])
-        leaves = jax.tree_util.tree_leaves(got)
-        if ref is None:
-            ref = leaves
-        else:
-            _assert_same(leaves, ref, f"agg overflow {backend}")
-    assert len(set(totals.values())) == 1, totals
-    assert totals["pure_xla"] > 8, "shape must actually overflow the cap"
+    case = (_consolidated(rng, 30, 32, key_range=5),
+            [_consolidated(rng, 60, 128, key_range=5),
+             _consolidated(rng, 40, 64, key_range=5)],
+            _consolidated(rng, 8, 16, key_range=5))
+    run = _agg_all([case], [(Sum(0), False)], gather_cap=8)
+    want = _assert_agg_backend(request, backend, run, "agg overflow")
+    assert int(want[-1]) > 8, "shape must actually overflow the cap"
 
 
 def test_agg_ladder_counts_dispatch(monkeypatch):
@@ -205,7 +210,6 @@ def test_agg_ladder_counts_dispatch(monkeypatch):
     DBSP_TPU_NATIVE force-off."""
     rng = np.random.default_rng(3)
     delta, levels, out_trace = _agg_case(rng)
-    monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
     monkeypatch.setenv("DBSP_TPU_NATIVE", "1")
     cursor.agg_ladder(delta, 2, out_trace, levels, Max(0), 16, 256, True,

@@ -5,15 +5,17 @@ the control.
 The trace-tax tentpole collapsed each trace consumer (incremental join,
 aggregate group gather, distinct old-weight lookup) from a stitched
 probe-ladder/expand/gather chain — 4+ dispatches with XLA where-mask glue —
-into ONE megakernel call (native C++ on CPU, a Pallas grid-over-levels
-program on accelerators), and made the compiled CTrace post view LAZY
-(consumers probe the appended delta as its own ladder level instead of
-re-reading the written slot). All of that is only legal because every
-backend produces identical batches:
+into ONE native C++ megakernel call on the CPU, and made the compiled
+CTrace post view LAZY (consumers probe the appended delta as its own ladder
+level instead of re-reading the written slot). Off the CPU the stitched
+chain IS the ladder kernel, composed over the accelerator's leaf
+formulations. All of that is only legal because every backend produces
+identical batches:
 
 * kernel level: join_ladder / gather_ladder (equality AND range form) /
-  old_weights_ladder across native megakernel, Pallas interpret, stitched
-  native, and pure XLA — on adversarial ladders (duplicate keys across
+  old_weights_ladder — each backend of ``BACKENDS`` (the accelerator's
+  formulation, stitched native, pure XLA) a case of its own against the
+  native megakernel — on adversarial ladders (duplicate keys across
   levels, EMPTY levels, full-capacity levels, cancelling weights, dead
   query rows, int32 weights, out_cap overflow with exact unclamped totals);
 * engine level: q1–q8 accumulated outputs, host AND compiled, fused vs the
@@ -72,72 +74,117 @@ def _adversarial_ladders(rng, weight_dtype=np.int64):
                                weight_dtype=weight_dtype)]
 
 
-# env settings per backend: (DBSP_TPU_NATIVE, DBSP_TPU_PALLAS)
-BACKENDS = {
-    "native_megakernel": ("1", "0"),
-    "pallas_interpret": ("0", "interpret"),
-    "stitched_native": (FUSED_OFF, "0"),
-    "pure_xla": ("0", "0"),
+# DBSP_TPU_NATIVE per backend. "accelerator" is what a chip runs: no
+# native kernel, and the dispatch steered off the CPU (conftest's
+# ``accelerator_dispatch``), so the ladder kernels' XLA chains compose over
+# the shift compaction, the merge network and the doubling group sums.
+# "stitched_control" is the committed A/B control (the PR-12 code path:
+# fused ladder consumers still native, the reduction layer forced off);
+# "pure_xla" strips the native kernels entirely.
+NATIVE_ENV = {
+    "native": "1",
+    "accelerator": "0",
+    "stitched_native": FUSED_OFF,
+    "stitched_control": REDUCE_OFF,
+    "pure_xla": "0",
 }
+# each a case of its own, compared with "native"
+BACKENDS = ("accelerator", "stitched_native", "pure_xla")
+# where the chain meets no leaf kernel that has an accelerator formulation
+# of its own, the steer changes nothing over "pure_xla"
+XLA_BACKENDS = ("stitched_native", "pure_xla")
 
 
-def _with_backend(monkeypatch, backend, fn):
-    native, pallas = BACKENDS[backend]
-    monkeypatch.setenv("DBSP_TPU_NATIVE", native)
-    monkeypatch.setenv("DBSP_TPU_PALLAS", pallas)
-    try:
-        return fn()
-    finally:
-        monkeypatch.setenv("DBSP_TPU_NATIVE", "1")
-        monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
+def _with_backend(request, backend, fn):
+    """``fn()`` under one entry of ``NATIVE_ENV``. The steer is one-way
+    within a test: take the native reference first."""
+    request.getfixturevalue("monkeypatch").setenv("DBSP_TPU_NATIVE",
+                                                  NATIVE_ENV[backend])
+    if backend == "accelerator":
+        request.getfixturevalue("accelerator_dispatch")
+    return fn()
+
+
+def _took(before):
+    """The (kernel, backend) pairs counted since ``before`` was copied."""
+    return {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
+            if n > before.get(k, 0)}
 
 
 def _assert_same(got, want, ctx=""):
+    assert len(got) == len(want), ctx
     for g, w in zip(got, want):
         g, w = np.asarray(g), np.asarray(w)
         assert g.dtype == w.dtype, f"{ctx}: dtype {g.dtype} != {w.dtype}"
         np.testing.assert_array_equal(g, w, err_msg=ctx)
 
 
+@pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("weight_dtype", [np.int64, np.int32])
-def test_join_ladder_backends_bitidentical(monkeypatch, weight_dtype):
+def test_join_ladder_backends_bitidentical(request, backend, weight_dtype):
+    """The raw join buffer, and what CJoin makes of it: its consolidation
+    (4,096 slots, above SORT_CHUNK_ROWS — off the CPU the chunked sort,
+    the doubling group sums and the shift compaction)."""
     fn = lambda k, lv, rv: (k, (*lv, *rv))  # noqa: E731
     rng = np.random.default_rng(0)
-    for ladder in _adversarial_ladders(rng, weight_dtype):
-        delta = _consolidated(rng, 20, 32, weight_dtype=weight_dtype)
-        ref = None
-        for backend in BACKENDS:
-            out, total = _with_backend(
-                monkeypatch, backend,
-                lambda: cursor.join_ladder(delta, ladder, 2, fn, 1024))
-            cur = (*out.cols, out.weights, np.asarray(total))
-            if ref is None:
-                ref = cur
-            else:
-                _assert_same(cur, ref, f"join_ladder {backend}")
+    cases = [(ladder, _consolidated(rng, 20, 32, weight_dtype=weight_dtype))
+             for ladder in _adversarial_ladders(rng, weight_dtype)]
+
+    def join_all():
+        outs = []
+        for ladder, delta in cases:
+            out, total = cursor.join_ladder(delta, ladder, 2, fn, 4096)
+            net = out.consolidate()
+            outs += [*out.cols, out.weights, np.asarray(total),
+                     *net.cols, net.weights]
+        return outs
+
+    want = _with_backend(request, "native", join_all)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    _assert_same(_with_backend(request, backend, join_all), want,
+                 f"join_ladder {backend}")
+    if backend == "accelerator":
+        assert _took(before) >= {
+            ("join_ladder", "xla"), ("sort_merge", "xla_bitonic"),
+            ("compact", "xla_shift")}
 
 
-def test_gather_ladder_backends_bitidentical(monkeypatch):
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_gather_ladder_backends_bitidentical(request, backend):
+    """The gathered part, and what CAggregate makes of it: cross-level
+    netting (a consolidation) and the per-group reduction."""
+    from dbsp_tpu.operators.aggregate import Max, _reduce_groups_impl
+
     rng = np.random.default_rng(1)
+    cases = []
     for ladder in _adversarial_ladders(rng):
         delta = _consolidated(rng, 24, 32)
-        qkeys = delta.keys
         qlive = np.asarray(delta.weights) != 0
         qlive[-3:] = False
-        qlive = jnp.asarray(qlive)
-        ref = None
-        for backend in BACKENDS:
-            (qrow, vals, w), total = _with_backend(
-                monkeypatch, backend,
-                lambda: cursor.gather_ladder(qkeys, qlive, ladder, 1024))
-            cur = (qrow, *vals, w, np.asarray(total))
-            if ref is None:
-                ref = cur
-            else:
-                _assert_same(cur, ref, f"gather_ladder {backend}")
+        cases.append((ladder, delta.keys, jnp.asarray(qlive)))
+
+    def gather_all():
+        outs = []
+        for ladder, qkeys, qlive in cases:
+            part, total = cursor.gather_ladder(qkeys, qlive, ladder, 1024)
+            qrow, vals, w = part
+            reduced, present = _reduce_groups_impl(
+                (part,), Max(0), qlive.shape[0], net=True)
+            outs += [qrow, *vals, w, np.asarray(total), *reduced, present]
+        return outs
+
+    want = _with_backend(request, "native", gather_all)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    _assert_same(_with_backend(request, backend, gather_all), want,
+                 f"gather_ladder {backend}")
+    if backend == "accelerator":
+        assert _took(before) >= {
+            ("gather_ladder", "xla"), ("compact", "xla_shift"),
+            ("segment_reduce", "xla")}
 
 
-def test_range_gather_ladder_backends_bitidentical(monkeypatch):
+@pytest.mark.parametrize("backend", XLA_BACKENDS)
+def test_range_gather_ladder_backends_bitidentical(request, backend):
     """The range form (distinct qhi bounds + probed-key gather-back — the
     CRolling/radix consumers), including EMPTY ranges where qhi < qlo."""
     rng = np.random.default_rng(2)
@@ -146,36 +193,36 @@ def test_range_gather_ladder_backends_bitidentical(monkeypatch):
     qlo = jnp.asarray(rng.integers(0, 20, 16).astype(np.int64))
     qhi = qlo + jnp.asarray(rng.integers(-2, 10, 16).astype(np.int64))
     qlive = jnp.asarray(rng.integers(0, 2, 16).astype(bool))
-    ref = None
-    for backend in BACKENDS:
-        (qrow, vals, w), total = _with_backend(
-            monkeypatch, backend,
-            lambda: cursor.gather_ladder((qp, qlo), qlive, levels, 512,
-                                         qhi_keys=(qp, qhi), gather_keys=1))
-        cur = (qrow, *vals, w, np.asarray(total))
-        if ref is None:
-            ref = cur
-        else:
-            _assert_same(cur, ref, f"range gather {backend}")
+
+    def gather():
+        (qrow, vals, w), total = cursor.gather_ladder(
+            (qp, qlo), qlive, levels, 512, qhi_keys=(qp, qhi), gather_keys=1)
+        return (qrow, *vals, w, np.asarray(total))
+
+    want = _with_backend(request, "native", gather)
+    _assert_same(_with_backend(request, backend, gather), want,
+                 f"range gather {backend}")
 
 
+@pytest.mark.parametrize("backend", XLA_BACKENDS)
 @pytest.mark.parametrize("weight_dtype", [np.int64, np.int32])
-def test_old_weights_ladder_backends_bitidentical(monkeypatch, weight_dtype):
+def test_old_weights_ladder_backends_bitidentical(request, backend,
+                                                  weight_dtype):
     rng = np.random.default_rng(3)
-    for ladder in _adversarial_ladders(rng, weight_dtype):
-        delta = _consolidated(rng, 16, 32, weight_dtype=weight_dtype)
-        ref = None
-        for backend in ("native_megakernel", "stitched_native", "pure_xla"):
-            old = _with_backend(
-                monkeypatch, backend,
-                lambda: cursor.old_weights_ladder(delta, ladder))
-            if ref is None:
-                ref = np.asarray(old)
-            else:
-                _assert_same((old,), (ref,), f"old_weights {backend}")
+    cases = [(ladder, _consolidated(rng, 16, 32, weight_dtype=weight_dtype))
+             for ladder in _adversarial_ladders(rng, weight_dtype)]
+
+    def old_weights():
+        return [cursor.old_weights_ladder(delta, ladder)
+                for ladder, delta in cases]
+
+    want = _with_backend(request, "native", old_weights)
+    _assert_same(_with_backend(request, backend, old_weights), want,
+                 f"old_weights {backend}")
 
 
-def test_overflow_totals_exact_on_every_backend(monkeypatch):
+@pytest.mark.parametrize("backend", XLA_BACKENDS)
+def test_overflow_totals_exact_on_every_backend(request, backend):
     """out_cap overflow: every backend must report the SAME unclamped
     total — it is the requirement the runner's grow/replay contract keys
     off (a clamped or drifted total silently loses rows)."""
@@ -183,19 +230,16 @@ def test_overflow_totals_exact_on_every_backend(monkeypatch):
     rng = np.random.default_rng(4)
     delta = _consolidated(rng, 40, 64, key_range=5)
     levels = [_consolidated(rng, 60, 128, key_range=5) for _ in range(2)]
-    totals = {}
-    for backend in BACKENDS:
-        _, jt = _with_backend(
-            monkeypatch, backend,
-            lambda: cursor.join_ladder(delta, levels, 2, fn, 16))
-        (_, _, _), gt = _with_backend(
-            monkeypatch, backend,
-            lambda: cursor.gather_ladder(
-                delta.keys, delta.weights != 0, levels, 16))
-        totals[backend] = (int(jt), int(gt))
-    vals = set(totals.values())
-    assert len(vals) == 1, f"overflow totals drifted: {totals}"
-    assert totals["pure_xla"][0] > 16, "shape must actually overflow"
+
+    def totals():
+        _, jt = cursor.join_ladder(delta, levels, 2, fn, 16)
+        _, gt = cursor.gather_ladder(delta.keys, delta.weights != 0, levels,
+                                     16)
+        return int(jt), int(gt)
+
+    want = _with_backend(request, "native", totals)
+    assert _with_backend(request, backend, totals) == want, backend
+    assert want[0] > 16, "shape must actually overflow"
 
 
 def test_fused_kernels_count_dispatch(monkeypatch):
@@ -206,7 +250,6 @@ def test_fused_kernels_count_dispatch(monkeypatch):
     rng = np.random.default_rng(5)
     levels = [_consolidated(rng, 10, 32), _consolidated(rng, 5, 16)]
     delta = _consolidated(rng, 8, 16)
-    monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
     monkeypatch.setenv("DBSP_TPU_NATIVE", "1")
     cursor.join_ladder(delta, levels, 2, fn, 256)

@@ -419,12 +419,11 @@ def test_the_maintenance_drains_have_their_scope():
     assert "jit(_drain_slice)/maintain.drain/" in text
 
 
-def test_the_chunked_sort_has_its_scope(monkeypatch):
+def test_the_chunked_sort_has_its_scope(accelerator_dispatch):
     # off the CPU sort_rows takes the chunked merge sort: its loops are what
     # a TPU trace shows as %while
     from dbsp_tpu.zset import kernels
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     x = jnp.arange(5000, dtype=jnp.int64)[::-1]
     text = jax.jit(lambda c: kernels.sort_rows((c,), (c,))).lower(
         x).as_text(debug_info=True)

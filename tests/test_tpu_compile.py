@@ -1,19 +1,19 @@
 """Ask the TPU's compiler, without a TPU: the main path's kernels compiled
 for one DESCRIBED v5e chip (``jax.experimental.topologies``; nothing runs).
 
-Guards what interpret mode and the CPU backend cannot: that the plain-XLA
-kernels of the served q4 path lower at a 40,000-row delta (capacity bucket
-65,536) and at a maintenance drain's shapes (65,536 rows into 1,048,576),
-that the large sort is the chunked merge sort there, that no merge of sorted
-runs gathers or scatters, and that the dispatch selects on a TPU exactly the
-Pallas programs its compiler accepts (``kernels.PALLAS_TPU_COMPILED`` <=>
-compiles).
+Guards what the CPU backend cannot: that the plain-XLA kernels of the
+served q4 path lower at a 40,000-row delta (capacity bucket 65,536) and at
+a maintenance drain's shapes (65,536 rows into 1,048,576), that no merge of
+sorted runs gathers or scatters, that the large sort is the chunked merge
+sort there, that the exchange and the output's gather compile as one SPMD
+program for the four chips of a host, and that the maintenance programs
+hand their levels back one slice a worker.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU's library, and every xdist
 worker imports this file); every compile runs in the test's own process.
-The code under test picks its branch from ``jax.default_backend()``, which
-still says ``cpu`` here — the ``tpu_dispatch`` fixture steers it.
+The code under test picks its branch from ``kernels.accelerator()``, which
+still sees the CPU here — conftest's ``accelerator_dispatch`` steers it.
 """
 
 import jax
@@ -21,15 +21,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from dbsp_tpu.zset import cursor, kernels, pallas_kernels
+from dbsp_tpu.zset import cursor, kernels
 from dbsp_tpu.zset.batch import Batch
 
 I64, I32 = jnp.int64, jnp.int32
 CAP = 65_536  # bucket_cap of a 40,000-event tick's bid delta
 # bids row: key (auction) + vals (bidder, price, channel, date_time)
 BID = (I64, I64, I64, I32, I64)
-PALLAS_PROGRAMS = ("probe_ladder", "join_ladder", "gather_ladder",
-                   "segment_reduce", "rank_merge")
 
 
 @pytest.fixture(scope="module")
@@ -61,14 +59,7 @@ def no_persistent_cache():
 
 
 @pytest.fixture
-def tpu_dispatch(monkeypatch):
-    """Steer the backend-keyed dispatch to its accelerator branches."""
-    monkeypatch.delenv("DBSP_TPU_PALLAS", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-@pytest.fixture
-def compile_for(one_chip, no_persistent_cache, tpu_dispatch):
+def compile_for(one_chip, no_persistent_cache, accelerator_dispatch):
     def shape(n, dtype=I64):
         return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
 
@@ -95,7 +86,7 @@ def _case(name, c):
         n = 17 * CAP
         return kernels.consolidate_cols, (
             tuple(c.shape(n, d) for d in BID), c.shape(n))
-    if name in ("merge_sorted", "rank_merge"):
+    if name == "merge_sorted":
         return kernels.merge_sorted_cols, (rows, c.shape(CAP),
                                            rows, c.shape(CAP))
     if name == "merge_sorted_drain":  # 65,536 rows into 1,048,576
@@ -105,9 +96,6 @@ def _case(name, c):
         return (lambda t, q: kernels.lex_probe(t, q, "left")), (
             (c.shape(16 * CAP), c.shape(16 * CAP)),
             (c.shape(CAP), c.shape(CAP)))
-    if name == "probe_ladder":
-        return (lambda ts, q: cursor.lex_probe_ladder(ts, q, "left")), (
-            [(c.shape(n),) for n in ladder], (c.shape(CAP),))
     if name == "join_ladder":  # q4-join: bids delta x auctions trace
         def fn(k, bv, av):
             return (k[0], av[0]), (bv[1], bv[3], av[1], av[2])
@@ -119,11 +107,6 @@ def _case(name, c):
             qk, ql, lv, 2 * CAP)), (
             (c.shape(CAP), c.shape(CAP)), c.shape(CAP, jnp.bool_),
             [c.batch(n, (I64, I64), (I64,)) for n in ladder])
-    if name == "segment_reduce":
-        from dbsp_tpu.operators.aggregate import segment_reduce
-        return (lambda v, w, seg: segment_reduce(
-            (("max", 0),), (v,), w, seg, CAP)), (
-            c.shape(2 * CAP), c.shape(2 * CAP), c.shape(2 * CAP, I32))
     raise AssertionError(name)
 
 
@@ -182,42 +165,6 @@ def test_large_sort_is_chunked_on_tpu(compile_for):
     assert "stablehlo.scatter" not in text
 
 
-@pytest.mark.parametrize("name", PALLAS_PROGRAMS)
-def test_pallas_program_selected_on_tpu_iff_it_compiles(name, compile_for,
-                                                        monkeypatch):
-    """The static tier decision against the compiler's verdict: a program
-    is listed in PALLAS_TPU_COMPILED exactly when Mosaic accepts it, and
-    the dispatch selects it on a TPU exactly when it is listed."""
-    ints = (jnp.zeros((8,), I64),)
-    listed = name in kernels.PALLAS_TPU_COMPILED
-    assert pallas_kernels.use_pallas(name, ints) == listed
-    assert not pallas_kernels.interpret_mode()  # never interpreted off-CPU
-    # compile the program itself, whatever the dispatch would pick
-    monkeypatch.setattr(kernels, "PALLAS_TPU_COMPILED",
-                        frozenset(PALLAS_PROGRAMS))
-    assert pallas_kernels.use_pallas(name, ints)
-    fn, args = _case(name, compile_for)
-    try:
-        text = compile_for(fn, *args).as_text()
-    except Exception as e:  # noqa: BLE001 — the compiler's refusal
-        accepted, why = False, f"{type(e).__name__}: {e}"
-    else:
-        accepted, why = "tpu_custom_call" in text, "no Mosaic kernel in HLO"
-    assert accepted == listed, (
-        f"{name}: listed={listed} but the v5e compiler says "
-        f"accepted={accepted} ({why[:300]}) — update "
-        "kernels.PALLAS_TPU_COMPILED")
-
-
-def test_interpreter_is_refused_off_the_cpu(tpu_dispatch, monkeypatch):
-    monkeypatch.setenv("DBSP_TPU_PALLAS", "interpret")
-    with pytest.raises(RuntimeError, match="CPU backend only"):
-        pallas_kernels.enabled()
-    monkeypatch.setenv("DBSP_TPU_PALLAS", "0")
-    assert not pallas_kernels.enabled()
-    assert kernels.pallas_requested() == bool(kernels.PALLAS_TPU_COMPILED)
-
-
 # -- the exchange, for the four chips of a v5e host ---------------------------
 
 WORKERS = 4
@@ -246,7 +193,7 @@ def four_chips(topo):
 @pytest.mark.parametrize("name", EXCHANGES)
 def test_exchange_compiles_for_four_v5e_chips(name, four_chips,
                                               no_persistent_cache,
-                                              tpu_dispatch):
+                                              accelerator_dispatch):
     """Bucketize + ``all_to_all`` + per-worker consolidation (and the
     output's ``all_gather``) as ONE SPMD program over a described 2x2 mesh,
     with the accelerator formulations behind the consolidation."""
@@ -278,7 +225,7 @@ def test_exchange_compiles_for_four_v5e_chips(name, four_chips,
 @pytest.mark.parametrize("name", ["drain_pair", "drain_slice", "copy_tree"])
 def test_maintenance_keeps_levels_on_their_workers(name, four_chips,
                                                    no_persistent_cache,
-                                                   tpu_dispatch):
+                                                   accelerator_dispatch):
     """What maintenance hands back to the step program stays one slice a
     worker. Left to itself the v5e's compiler returned a drain's emptied
     level (all constants) replicated on the four chips, and every level

@@ -208,7 +208,7 @@ def test_chunked_sort_bit_identical_to_lax_sort(n, chunk):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_sort_rows_takes_the_chunked_path_off_cpu(monkeypatch):
+def test_sort_rows_takes_the_chunked_path_off_cpu(request, monkeypatch):
     """sort_rows keys its formulation on the backend, statically: plain
     lax.sort on the CPU, the chunked merge sort elsewhere — and the
     consolidation built on it gives the same canonical batch."""
@@ -224,7 +224,7 @@ def test_sort_rows_takes_the_chunked_path_off_cpu(monkeypatch):
     real = kernels._sort_rows_chunked
     monkeypatch.setattr(kernels, "_sort_rows_chunked",
                         lambda *a: calls.append(1) or real(*a))
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    request.getfixturevalue("accelerator_dispatch")
     got = kernels.consolidate_cols(cols, w)
     assert calls, "off the CPU a large sort must take the chunked path"
     for x, y in zip(jax.tree_util.tree_leaves(want),
@@ -314,6 +314,12 @@ def _sort_and_net(cols, w):
         np.asarray([x for _, x in live] + [0] * pad, np.int64))
 
 
+def _took(before):
+    """The (kernel, backend) pairs counted since ``before`` was copied."""
+    return {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
+            if n > before.get(k, 0)}
+
+
 def _assert_same(got, want):
     got = (*got[0], got[1])
     want = (*want[0], want[1])
@@ -385,26 +391,45 @@ def _merge_case(name):
             for _ in range(n)}.items())
         return (*_sorted_run(rows(700), 1024, five),
                 *_sorted_run(rows(100), 128, five))
+    if name == "one_column_full_capacity":  # half of b cancels half of a
+        one = (jnp.int64,)
+        return (*_sorted_run([((k,), 1) for k in range(16)], 16, one),
+                *_sorted_run([((k,), -1) for k in range(8, 24)], 16, one))
+    if name.startswith("three_int64_"):
+        # consolidated batches of two key columns and a value column, few
+        # distinct values a column, weights of either sign, 64 slots into
+        # 128: a random number of live rows by seed, or ("dense") 40 into
+        # 70 over ten values a column, where many rows meet and some cancel
+        dense = name == "three_int64_dense"
+        rng = np.random.default_rng(20 if dense else int(name[-2:]))
+        three = (jnp.int64,) * 3
+
+        def netted(live):
+            net = {}
+            for _ in range(live):
+                row = tuple(int(v) for v in
+                            rng.integers(0, 10 if dense else 12, 3))
+                net[row] = net.get(row, 0) + (int(rng.integers(-3, 4)) or 1)
+            return [(r, w) for r, w in net.items() if w]
+
+        la, lb = (40, 70) if dense else (int(rng.integers(0, 50)),
+                                         int(rng.integers(0, 100)))
+        return (*_sorted_run(netted(la), 64, three),
+                *_sorted_run(netted(lb), 128, three))
     raise AssertionError(name)
 
 
 MERGE_CASES = ["odd_sizes", "one_row_into_many", "all_dead_side",
                "zero_length_side", "full_capacity", "everything_cancels",
                "long_equal_runs", "live_sentinel_row", "nan_inf_floats",
-               "int32_into_int64", "five_columns"]
-
-
-@pytest.fixture
-def accelerator_dispatch(monkeypatch):
-    """Steer the backend-keyed dispatch to its accelerator branches."""
-    import jax
-
-    monkeypatch.delenv("DBSP_TPU_PALLAS", raising=False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+               "int32_into_int64", "five_columns",
+               "one_column_full_capacity", "three_int64_seed10",
+               "three_int64_seed11", "three_int64_seed12",
+               "three_int64_seed13", "three_int64_dense"]
 
 
 @pytest.mark.parametrize("name", MERGE_CASES)
-def test_accelerator_merge_bit_identical(name, monkeypatch):
+def test_accelerator_merge_bit_identical(name, request):
     import jax
 
     cols_a, w_a, cols_b, w_b = _merge_case(name)
@@ -413,12 +438,11 @@ def test_accelerator_merge_bit_identical(name, monkeypatch):
     want = _sort_and_net(cols, jnp.concatenate([w_a, w_b]))
     if name != "long_equal_runs":  # the native walk nets across sides only
         _assert_same(kernels.merge_sorted_cols(cols_a, w_a, cols_b, w_b), want)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    request.getfixturevalue("accelerator_dispatch")
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
     got = jax.jit(kernels.merge_sorted_cols)(cols_a, w_a, cols_b, w_b)
-    took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
-            if n > before.get(k, 0)}
-    assert took == {("merge", "xla_bitonic"), ("compact", "xla_shift")}
+    assert _took(before) == {("merge", "xla_bitonic"),
+                             ("compact", "xla_shift")}
     _assert_same(got, want)
 
 
@@ -455,7 +479,7 @@ def test_accelerator_zero_column_rows(accelerator_dispatch):
 
 @pytest.mark.parametrize("pattern", ["all", "none", "prefix", "suffix",
                                      "alternate", "random", "one_at_end"])
-def test_accelerator_compact_bit_identical(pattern, monkeypatch):
+def test_accelerator_compact_bit_identical(pattern, request, monkeypatch):
     import jax
 
     n = 333
@@ -472,7 +496,7 @@ def test_accelerator_compact_bit_identical(pattern, monkeypatch):
     keep = jnp.asarray(keep)
     monkeypatch.setenv("DBSP_TPU_NATIVE", "0")  # floats: the XLA reference
     want = kernels.compact(cols, w, keep)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    request.getfixturevalue("accelerator_dispatch")
     _assert_same(jax.jit(kernels.compact)(cols, w, keep), want)
 
 
@@ -502,3 +526,97 @@ def test_accelerator_merge_under_shard_map(accelerator_dispatch):
         cols = tuple(jnp.concatenate([a, b]) for a, b in zip(ca, cb))
         _assert_same((tuple(c[k] for c in got[0]), got[1][k]),
                      _sort_and_net(cols, jnp.concatenate([wa, wb])))
+
+
+# ---------------------------------------------------------------------------
+# Which formulation each public kernel takes off the CPU: the (kernel,
+# backend) pairs it counts under the steer are the ones a run on the chip
+# prints in its ``kernel_dispatch`` line (PERF.md 3)
+# ---------------------------------------------------------------------------
+
+# every pair a q4 run on the chip has printed (my chip runs, PR 33)
+CHIP_PAIRS = {
+    ("agg_ladder", "xla"), ("compact", "xla_shift"), ("consolidate", "xla"),
+    ("expand", "xla"), ("gather", "xla"), ("gather_ladder", "xla"),
+    ("join_ladder", "xla"), ("merge", "xla_bitonic"), ("probe", "xla"),
+    ("probe_ladder", "xla"), ("rank_fold", "xla"),
+    ("segment_reduce", "xla"), ("sort_merge", "xla_bitonic")}
+
+_NET = {("consolidate", "xla"), ("compact", "xla_shift")}
+_CHAIN = {("probe_ladder", "xla"), ("expand", "xla"), ("gather", "xla")}
+ACCELERATOR_PAIRS = {
+    "consolidate_cols": _NET | {("sort_merge", "xla_bitonic")},
+    "consolidate_cols_one_chunk": _NET,  # SORT_CHUNK_ROWS rows: lax.sort
+    "merge_sorted_cols": {("merge", "xla_bitonic"), ("compact", "xla_shift")},
+    "compact": {("compact", "xla_shift")},
+    "rank_fold": {("rank_fold", "xla"), ("merge", "xla_bitonic"),
+                  ("compact", "xla_shift")},
+    "lex_probe": {("probe", "xla")},
+    "expand_ranges": {("expand", "xla")},
+    "lex_probe_ladder": {("probe_ladder", "xla")},
+    "join_ladder": _CHAIN | {("join_ladder", "xla")},
+    "gather_ladder": _CHAIN | {("gather_ladder", "xla")},
+    "old_weights_ladder": {("old_weights", "xla"), ("probe_ladder", "xla")},
+    "segment_reduce": {("segment_reduce", "xla")},
+    "agg_ladder": _CHAIN | _NET | {
+        ("agg_ladder", "xla"), ("gather_ladder", "xla"), ("probe", "xla"),
+        ("segment_reduce", "xla")},
+}
+
+
+def _dispatch_call(name):
+    from dbsp_tpu.operators.aggregate import Max, segment_reduce
+    from dbsp_tpu.zset import cursor
+
+    def batch(keys, vals=()):
+        keys = np.sort(np.asarray(keys, np.int64))
+        return Batch.from_columns(
+            [keys, keys % 3], [keys + v for v in vals],
+            np.ones(len(keys), np.int64), cap=2 * len(keys))
+
+    delta, levels = batch(range(0, 20, 2), (1,)), [
+        batch(range(40), (2,)), batch(range(5, 25), (3,))]
+    n = kernels.SORT_CHUNK_ROWS
+    col = jnp.arange(2 * n, dtype=jnp.int64) % 7
+    fn = lambda k, lv, rv: (k, (*lv, *rv))  # noqa: E731
+    return {
+        "consolidate_cols": lambda: kernels.consolidate_cols(
+            (col,), jnp.ones_like(col)),
+        "consolidate_cols_one_chunk": lambda: kernels.consolidate_cols(
+            (col[:n],), jnp.ones_like(col[:n])),
+        "merge_sorted_cols": lambda: kernels.merge_sorted_cols(
+            delta.cols, delta.weights, levels[0].cols, levels[0].weights),
+        "compact": lambda: kernels.compact((col,), col, col > 3),
+        "rank_fold": lambda: concat_batches([delta, levels[0]]).consolidate(),
+        "lex_probe": lambda: kernels.lex_probe(levels[0].keys, delta.keys),
+        "expand_ranges": lambda: kernels.expand_ranges(
+            jnp.asarray([0, 3], jnp.int32), jnp.asarray([2, 7], jnp.int32), 8),
+        "lex_probe_ladder": lambda: cursor.lex_probe_ladder(
+            [lvl.keys for lvl in levels], delta.keys),
+        "join_ladder": lambda: cursor.join_ladder(delta, levels, 2, fn, 64),
+        "gather_ladder": lambda: cursor.gather_ladder(
+            delta.keys, delta.weights != 0, levels, 64),
+        "old_weights_ladder": lambda: cursor.old_weights_ladder(
+            delta, levels),
+        "segment_reduce": lambda: segment_reduce(
+            (("max", 0),), (col,), jnp.ones_like(col),
+            (col % 4).astype(jnp.int32), 4),
+        "agg_ladder": lambda: cursor.agg_ladder(
+            delta, 2, batch(range(0, 20, 4), (1,)), levels, Max(0), 16, 64,
+            False, jnp.asarray(True)),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ACCELERATOR_PAIRS)
+def test_kernel_counts_the_pairs_a_chip_run_prints(name, accelerator_dispatch):
+    call = _dispatch_call(name)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    call()
+    took = _took(before)
+    assert took == ACCELERATOR_PAIRS[name]
+    assert "native" not in {backend for _, backend in took}
+
+
+def test_every_pair_of_a_chip_run_has_a_case():
+    covered = set().union(*ACCELERATOR_PAIRS.values())
+    assert CHIP_PAIRS <= covered, CHIP_PAIRS - covered

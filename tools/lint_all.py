@@ -496,11 +496,7 @@ def run_kernel_dryrun() -> list:
         return []
 
     def child(extra_env):
-        # pin the Pallas knob too: an inherited DBSP_TPU_PALLAS force-on
-        # would dispatch join_ladder:pallas instead of :native and turn
-        # both assertions below falsely red on a healthy tree
-        env = dict(os.environ, JAX_PLATFORMS="cpu", DBSP_TPU_PALLAS="0",
-                   **extra_env)
+        env = dict(os.environ, JAX_PLATFORMS="cpu", **extra_env)
         try:
             p = subprocess.run(
                 [sys.executable, "-c",

@@ -21,8 +21,8 @@ Kernels & shapes (ROOFLINE §1):
                        gather) of 4096 query keys against a 4-level
                        ladder (262k..4k rows) into 8192 slots — ROOFLINE
                        §1's "group gather" row, end to end. Dispatches the
-                       ONE-call megakernel (native on CPU, Pallas on
-                       accelerators) unless forced off;
+                       ONE-call megakernel (native, on the CPU) unless
+                       forced off;
   * join_ladder      — the fused incremental-join consumer (both probes +
                        expansion + both-side gathers + weight product +
                        pair apply) of a 16k-row delta against the same
@@ -45,7 +45,7 @@ Kernels & shapes (ROOFLINE §1):
                        end, megakernel dispatch included.
 
 Every entry dispatches through the engine's own backend switch, so the
-measured path follows DBSP_TPU_NATIVE / DBSP_TPU_PALLAS — A/B a single
+measured path follows DBSP_TPU_NATIVE — A/B a single
 kernel with e.g. ``DBSP_TPU_NATIVE=expand python tools/microbench_kernels.py``
 (forces expand alone onto XLA; see zset/native_merge.py::kernel_enabled).
 

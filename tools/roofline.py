@@ -73,7 +73,7 @@ REF_GAP = REF_KERNEL_MS / REF_PRED_MS  # the "4.7x" ROADMAP item 5 names
 # one already recorded here (re-calibrating against an old committed pair
 # must not multiply its own ratio in twice).
 RECORDED_REFITS = (
-    ("PR-7 native/Pallas kernel set", "BENCH_local_native_kernels", 0.87),
+    ("PR-7 native kernel set", "BENCH_local_native_kernels", 0.87),
     ("PR-12 fused ladder megakernels + lazy post view",
      "BENCH_local_megakernels", 0.70),
 )
@@ -166,15 +166,13 @@ def kernel_table():
             .at[pos_b].set(wb)
         return tuple(out), w
 
-    # force the pure-XLA path for analysis (native custom calls and
-    # Pallas programs are opaque to cost analysis; the XLA HLO is the
-    # backend-independent traffic model)
+    # force the pure-XLA path for analysis (native custom calls are
+    # opaque to cost analysis; the XLA HLO is the backend-independent
+    # traffic model)
     saved = {k: os.environ.get(k) for k in
-             ("DBSP_TPU_NATIVE_MERGE", "DBSP_TPU_NATIVE",
-              "DBSP_TPU_PALLAS")}
+             ("DBSP_TPU_NATIVE_MERGE", "DBSP_TPU_NATIVE")}
     os.environ["DBSP_TPU_NATIVE_MERGE"] = "0"
     os.environ["DBSP_TPU_NATIVE"] = "0"
-    os.environ["DBSP_TPU_PALLAS"] = "0"
     try:
         rows.append(("spine drain merge (rank)",
                      f"{na}+{nb} rows x {k} cols",
@@ -618,11 +616,8 @@ def main():
       "galloping block-copy merges; dispatch visible in "
       "`dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}` and bench "
       "JSON `kernel_paths`, per-kernel A/B via DBSP_TPU_NATIVE). On "
-      "accelerator backends the dispatch selects a hand-written Pallas "
-      "program (zset/pallas_kernels.py) only where the TPU's compiler "
-      "accepts it (kernels.PALLAS_TPU_COMPILED — none today; "
-      "tests/test_tpu_compile.py) and the plain-XLA formulation "
-      "otherwise; interpret-mode bit-identity is tier-1-gated. What "
+      "accelerator backends the dispatch takes the plain-XLA "
+      "formulations (tests/test_tpu_compile.py). What "
       "remained aggregate "
       "here — WHICH step-program glue the gap lives in — is now a "
       "per-operator measurement: §3c below names it, from the committed "
