@@ -164,8 +164,11 @@ class CompiledCircuitDriver:
             feeds: Dict = {}
             for op, drain in self._inputs:
                 with spans.span("tick.build_inputs", "tick",
-                                args={"table": self.input_labels[op]}):
+                                args={"table": self.input_labels[op]}) as sp:
                     feeds[op] = drain()
+                    path, rows = getattr(  # (an UpsertInput has none)
+                        op, "last_drain", ("device", feeds[op].cap))
+                    sp.note(path=path, rows=rows)
         finally:
             Runtime._swap(prev)
         if not self._retained:

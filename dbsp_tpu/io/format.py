@@ -12,7 +12,7 @@ tuples (the transports and tests). ``take_columns()`` gives one
 :class:`~dbsp_tpu.zset.batch.ColumnBlock` — a numpy array per schema column
 and a weight vector, held to the columns' domain — which is what the HTTP
 ingest route pushes into an input handle, so a POSTed body reaches
-``Batch.from_columns`` with no row tuple in between. The JSON parser reads
+``Batch.from_block`` with no row tuple in between. The JSON parser reads
 *regular* NDJSON (envelopes or bare arrays of plain numbers, the schema's
 arity, spelled as ``json.dumps`` spells them by default or with compact
 separators) a chunk of whole lines at a time, as text: the lines' shape is

@@ -37,6 +37,14 @@ class ZSetInput(SourceOperator):
     # so one tap serves both. Opt-in: None = zero cost.
     lineage_tap = None
 
+    # (path, rows) of the latest drain, for the ``tick.build_inputs`` span:
+    # ``host_block`` = the whole batch came through Batch.from_block's one
+    # program, ``mixed`` = row tuples or pushed batches were folded into
+    # it, ``device`` = no block at all (each array operation of
+    # from_columns its own program); rows as pushed, a pushed batch
+    # counting its capacity
+    last_drain = ("device", 0)
+
     def __init__(self, key_dtypes: Sequence, val_dtypes: Sequence = ()):
         self.key_dtypes = tuple(key_dtypes)
         self.val_dtypes = tuple(val_dtypes)
@@ -65,12 +73,15 @@ class ZSetInput(SourceOperator):
             parts.append(Batch.from_tuples(
                 rows, self.key_dtypes, self.val_dtypes))
         if blocks:
-            # the tick's POSTs as one batch, column by column: the same
-            # shapes and dtypes from_tuples would hand from_columns
+            # the tick's POSTs as one batch: host columns padded on the
+            # host and consolidated by one program per (schema, capacity),
+            # the same batch from_tuples would build
             block = ColumnBlock.concat(blocks)
-            nk = len(self.key_dtypes)
-            parts.append(Batch.from_columns(
-                block.cols[:nk], block.cols[nk:], block.weights))
+            parts.append(Batch.from_block(block, len(self.key_dtypes)))
+        self.last_drain = (
+            "host_block" if blocks and len(parts) == 1 else
+            "mixed" if blocks else "device",
+            len(rows) + sum(map(len, blocks)) + sum(b.cap for b, _ in batches))
         if not parts:
             return Batch.empty(self.key_dtypes, self.val_dtypes,
                                lead=(workers,) if workers > 1 else ())

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+from functools import partial
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -155,6 +156,22 @@ class Batch:
             jnp.asarray(weights, WEIGHT_DTYPE))
         b = Batch(keys, vals, w, runs=(cap,) if consolidated else None)
         return b if consolidated else b.consolidate()
+
+    @staticmethod
+    def from_block(block: "ColumnBlock", nk: int) -> "Batch":
+        """The consolidated batch of a host :class:`ColumnBlock` whose first
+        ``nk`` columns are the keys: what :meth:`from_columns` builds from
+        the same columns, bit for bit, in ONE device program. numpy pads
+        every column to ``bucket_cap(n)`` with its sentinel and the weights
+        with 0, and the padded arrays cross to the device as the arguments
+        of :func:`_consolidate_block`. The padding carries ``n``, so blocks
+        of one capacity bucket share a program."""
+        pad = (0, bucket_cap(len(block)) - len(block))
+        return _consolidate_block(
+            tuple(np.pad(c, pad,
+                         constant_values=kernels.sentinel_scalar(c.dtype))
+                  for c in block.cols),
+            np.pad(block.weights.astype(WEIGHT_DTYPE, copy=False), pad), nk)
 
     @staticmethod
     def from_tuples(rows: Sequence[Tuple[Row, int]], key_dtypes: Sequence,
@@ -322,6 +339,12 @@ def _merge_kernel(a: Batch, b: Batch) -> Batch:
     return Batch(cols[:nk], cols[nk:], w, runs=(w.shape[-1],))
 
 
+@partial(jax.jit, static_argnames=("nk",))
+def _consolidate_block(cols, weights, nk: int) -> Batch:
+    """:meth:`Batch.from_block`'s program, one per (dtypes, ``nk``, cap)."""
+    return Batch(cols[:nk], cols[nk:], weights).consolidate()
+
+
 def consolidate_regime(batch: Batch) -> Batch:
     """Single-worker regime dispatch behind :meth:`Batch.consolidate` (also
     the per-worker body of the lifted sharded consolidate — arrays are 1-D
@@ -422,7 +445,7 @@ class ColumnBlock:
     column (keys then values, in the schema's dtypes) and a weight vector.
     What a parsed POST is pushed as (io/format.py -> InputHandle.extend):
     the tick's batch is built from the buffered blocks by
-    :meth:`Batch.from_columns`, with no row tuple in between. Supports
+    :meth:`Batch.from_block`, with no row tuple in between. Supports
     ``len()`` and slicing like the list of weighted rows it replaces."""
 
     __slots__ = ("cols", "weights")

@@ -356,15 +356,15 @@ def test_two_posts_blocks_concatenate():
 def test_a_block_pushed_while_eval_runs_lands_in_the_next_tick(monkeypatch):
     handle, h, out = _input()
     late = ColumnBlock.from_rows(_rows(5, 500), I64_I32)
-    real, calls = Batch.from_columns, {"n": 0}
+    real, calls = Batch.from_block, {"n": 0}
 
-    def from_columns(*a, **kw):
+    def from_block(*a, **kw):
         calls["n"] += 1
         if calls["n"] == 1:  # the buffers are swapped out by now
             h.extend(late)
         return real(*a, **kw)
 
-    monkeypatch.setattr(Batch, "from_columns", staticmethod(from_columns))
+    monkeypatch.setattr(Batch, "from_block", staticmethod(from_block))
     h.extend(ColumnBlock.from_rows(_rows(8), I64_I32))
     handle.step()
     assert out.to_dict() == dict(_rows(8))
@@ -401,6 +401,115 @@ def test_on_four_workers_the_sharded_batch_equals_the_tuple_path_s():
         assert got.dtype == want.dtype
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert int(by_block.live_count()) == len({r for r, _ in rows})
+
+
+# -- one program per table shape (ISSUE 35) -----------------------------------
+
+I32_I64_I32 = (jnp.int32, jnp.int64, jnp.int32)
+
+
+def _scattered(n, mod):
+    return [((k * 7919 % mod, (k * 31 % 11) * 10 ** 10, -(k % 3)), 1 + k % 2)
+            for k in range(n)]
+
+
+# name -> (dtypes, key columns, weighted rows in arrival order)
+BLOCKS = {
+    # (more rows than one chunk of the accelerator's merge sort)
+    "unsorted": (I32_I64_I32, 2, _scattered(2500, 10 ** 6)),
+    "duplicates_that_net": (I32_I64_I32, 1, _scattered(300, 40)),
+    "rows_that_net_to_zero": (
+        I64_I32, 1, _rows(50) + _rows(20, 10, weight=-1) + _rows(50, 30, -1)
+        + _rows(30, 50)),
+    "all_net_to_zero": (I64_I32, 2, _rows(20) + _rows(20, weight=-1)),
+    "nothing_to_pad": (I32_I64_I32, 3, _scattered(256, 10 ** 6)),
+    "one_row": (I64_I32, 1, [((2 ** 40, -7), -2)]),
+    "no_value_columns": ((jnp.int64, jnp.int64), 2, [
+        ((k % 9, 2 ** 62 - k % 4), 1) for k in range(100)]),
+}
+
+
+@pytest.mark.parametrize("dispatch", ["native", "accelerator"])
+@pytest.mark.parametrize("name", sorted(BLOCKS))
+def test_a_block_s_batch_is_from_columns_batch_bit_for_bit(name, dispatch,
+                                                            request):
+    if dispatch == "accelerator":
+        request.getfixturevalue("accelerator_dispatch")
+    dtypes, nk, rows = BLOCKS[name]
+    block = ColumnBlock.from_rows(rows, dtypes)
+    got = Batch.from_block(block, nk)
+    want = Batch.from_columns(block.cols[:nk], block.cols[nk:], block.weights)
+    assert (got.cap, got.runs) == (want.cap, want.runs) == \
+        (got.cap, (got.cap,))
+    assert (len(got.keys), len(got.vals)) == (nk, len(dtypes) - nk)
+    for g, w in zip((*got.cols, got.weights), (*want.cols, want.weights)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    net = Counter()
+    for row, w in rows:
+        net[row] += w
+    assert got.to_dict() == {r: w for r, w in net.items() if w}
+    if name == "nothing_to_pad":
+        assert got.cap == len(rows)
+
+
+def _parsed(rows):
+    p = JsonParser(I64_I32)
+    p.feed(b"".join(b"[%d, %d]\n" % r for r, _ in rows))
+    return p.take_columns()
+
+
+@pytest.mark.parametrize("build", [
+    lambda rows: ColumnBlock.from_rows(rows, I64_I32),
+    lambda rows: Batch.from_tuples(rows, [jnp.int64], [jnp.int32]),
+    _parsed], ids=["block_from_rows", "from_tuples", "parser"])
+def test_the_sentinel_is_refused_where_host_columns_are_made(build):
+    """from_block, like from_columns, is handed columns already held to the
+    domain contract: the refusal is where it was."""
+    build(_rows(3))
+    with pytest.raises(ValueError, match="sentinel"):
+        build(_rows(3) + [((7, I32_MAX), 1)])
+
+
+def test_one_capacity_bucket_is_one_trace_whatever_n():
+    """Traces, not seconds: the consolidation inside the program counts its
+    path once per trace (kernels.CONSOLIDATE_COUNTS)."""
+    from dbsp_tpu.zset import kernels
+
+    dtypes = (jnp.int32, jnp.int32, jnp.int64, jnp.int32)  # no other test's
+
+    def traces_of(n):
+        before = sum(kernels.CONSOLIDATE_COUNTS.values())
+        rows = [((k % 50, k, -k, 3), 1) for k in range(n)]
+        b = Batch.from_block(ColumnBlock.from_rows(rows, dtypes), 3)
+        assert int(b.live_count()) == n and b.cap == (512 if n <= 512
+                                                      else 1024)
+        return sum(kernels.CONSOLIDATE_COUNTS.values()) - before
+
+    assert [traces_of(n) for n in (300, 417, 512, 513, 700, 300)] == \
+        [1, 0, 0, 1, 0, 0]
+
+
+def test_the_handle_says_which_path_built_its_tick():
+    handle, h, out = _input()
+    op = h._op
+    assert op.last_drain == ("device", 0)
+    h.extend(ColumnBlock.from_rows(_rows(9), I64_I32))
+    h.extend(ColumnBlock.from_rows(_rows(4, 20), I64_I32))
+    handle.step()
+    assert op.last_drain == ("host_block", 13)
+    h.extend(ColumnBlock.from_rows(_rows(9), I64_I32))
+    h.push((5, 5), 1)
+    handle.step()
+    assert op.last_drain == ("mixed", 10)
+    h.push_batch(Batch.from_tuples(_rows(3), [jnp.int64], [jnp.int32]))
+    handle.step()
+    assert op.last_drain == ("device", 8)  # (a pushed batch: its capacity)
+    h.extend(_rows(3))
+    handle.step()
+    assert op.last_drain == ("device", 3)
+    handle.step()
+    assert op.last_drain == ("device", 0) and out.to_dict() == {}
 
 
 # -- the served route ---------------------------------------------------------

@@ -179,6 +179,38 @@ def test_a_served_tick_is_one_span_with_every_phase_nested(served):
     assert dispatch.args["retraced"] is True
 
 
+def test_build_inputs_spans_say_the_one_program_built_each_table(served):
+    for k, tick in _ticks(served.rec).items():
+        built = [c for c in tick.children if c.name == "tick.build_inputs"]
+        assert [c.args["path"] for c in built] == ["host_block"] * 3, k
+        assert sum(c.args["rows"] for c in built) == tick.args["rows_in"]
+        assert all(c.args["rows"] > 0 for c in built)
+
+
+def test_a_tick_fed_by_push_batch_says_device():
+    from dbsp_tpu.circuit import Runtime
+    from dbsp_tpu.compiled.driver import CompiledCircuitDriver
+    from dbsp_tpu.operators import add_input_zset
+    from dbsp_tpu.zset.batch import Batch, ColumnBlock
+
+    def build(c):
+        s, h = add_input_zset(c, [jnp.int64], [jnp.int32])
+        return h, s.output()
+
+    handle, (h, out) = Runtime.init_circuit(1, build)
+    driver = CompiledCircuitDriver(handle, validate_every=1)
+    driver.spans = rec = SpanRecorder(max_steps=64)
+    rows = [((k, k % 3), 1) for k in range(6)]
+    h.push_batch(Batch.from_tuples(rows, [jnp.int64], [jnp.int32]))
+    driver.step()
+    h.extend(ColumnBlock.from_rows(rows, (jnp.int64, jnp.int32)))
+    h.push((9, 9), 1)
+    driver.step()
+    assert out.to_dict() == {**dict(rows), (9, 9): 1}
+    assert [(s.args["path"], s.args["rows"]) for s in _spans(rec)
+            if s.name == "tick.build_inputs"] == [("device", 8), ("mixed", 7)]
+
+
 def test_tick_args_hold_the_trace_ids_of_the_ingests_that_caused_it(served):
     ticks = _ticks(served.rec)
     ingests = {s.args["trace"]: s for s in _spans(served.rec)

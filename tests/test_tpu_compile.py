@@ -82,6 +82,10 @@ def _case(name, c):
     level = tuple(c.shape(16 * CAP, d) for d in BID)  # a drain's target
     if name == "consolidate":
         return kernels.consolidate_cols, (rows, c.shape(CAP))
+    if name == "consolidate_block":  # a tick's bids from host columns
+        from dbsp_tpu.zset.batch import _consolidate_block
+        return (lambda cols, w: _consolidate_block(cols, w, 1)), (
+            rows, c.shape(CAP))
     if name == "consolidate_drain":  # the same rows, unsorted
         n = 17 * CAP
         return kernels.consolidate_cols, (
@@ -111,8 +115,8 @@ def _case(name, c):
 
 
 @pytest.mark.parametrize("name", [
-    "consolidate", "consolidate_drain", "merge_sorted", "merge_sorted_drain",
-    "lex_probe", "join_ladder", "gather_ladder"])
+    "consolidate", "consolidate_block", "consolidate_drain", "merge_sorted",
+    "merge_sorted_drain", "lex_probe", "join_ladder", "gather_ladder"])
 def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     fn, args = _case(name, compile_for)
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
