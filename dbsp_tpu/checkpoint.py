@@ -133,6 +133,12 @@ STATE_SCHEMA: Dict[str, Dict[str, str]] = {
         "_tick_host": "derived",
         "_steady_guard": "runtime",
         "_checks": "derived",
+        # what the time nodes observe behind the requirements (re-made by
+        # every trace), the nodes of a windowed view (from the graph), and
+        # the GC'd levels the last snapshot copied (a span's arg)
+        "_observed": "derived",
+        "_windowed": "derived",
+        "snapshot_gc_levels": "derived",
         "_req": "derived",
         "_max_jit": "derived",
         "last_req": "derived",

@@ -253,7 +253,8 @@ class _Leveled:
             ctx.require(self, self.level_keys[0],
                         (occ + jnp.where(has, 1, 0)) * dcap)
             if self.TAIL_KEY != self.level_keys[0]:
-                ctx.require(self, self.TAIL_KEY, base + l0_live)
+                ctx.require(self, self.TAIL_KEY,
+                            self._deep_live(base, new) + l0_live)
             return (tuple(new), base)
         if getattr(self, "_slot_cap", None) is not None:
             # l0 may hold slot runs: canonicalize before the merge (whose
@@ -266,9 +267,21 @@ class _Leveled:
         live0 = m0.live_count()
         ctx.require(self, self.level_keys[0], live0)
         if self.TAIL_KEY != self.level_keys[0]:
-            ctx.require(self, self.TAIL_KEY, base + live0)
+            ctx.require(self, self.TAIL_KEY,
+                        self._deep_live(base, new) + live0)
         new[0] = m0.with_cap(self.caps[self.level_keys[0]]).tagged(None)
         return (tuple(new), base)
+
+    def _deep_live(self, base, levels):
+        """Live rows of levels 1..K-1 for the whole-trace requirement:
+        ``base_live``, which maintenance sets to the SUM of what its drains
+        moved — or, in a windowed view (``CTrace._counts_lives``), a count.
+        There half of what a drain moves cancels in the merge, so the sum
+        stands far above the rows held, and a capacity grown to fit it
+        holds rows that are gone."""
+        if getattr(self, "_counts_lives", False):
+            return sum(lvl.live_count() for lvl in levels[1:])
+        return base
 
     def _view_levels(self, levels) -> Tuple[Batch, ...]:
         """The level tuple consumers probe: slotted level 0 expands into
@@ -462,6 +475,12 @@ class CNode:
         reclassify a capacity once observed behavior contradicts its static
         assumption (see CAggregate's gather)."""
 
+    def note_observations(self, values: Dict[str, int]) -> None:
+        """Hook fired, after a validation that found no overflow, with the
+        scalars this node handed ``ctx.observe``: facts of the interval
+        that ride the requirement vector and are checked against no
+        capacity (the time nodes fill ``timeseries/counters.py``)."""
+
     def eval(self, ctx, state, inputs):  # -> (state', output)
         raise NotImplementedError
 
@@ -604,6 +623,26 @@ class CTrace(CNode, _Leveled):
 
     def repad_state(self, st):
         return self._levels_repad(st)
+
+    # the trace of a windowed view (compiler.__init__ sets it: the trace
+    # under a GC bound and every trace downstream of its window): rows
+    # leave as fast as they arrive, so the step program counts each level's
+    # live rows and maintenance plans from those counts
+    _counts_lives = False
+    # each level's live rows at the last validation, until maintenance
+    # consumes them, and their sum
+    observed_lives: Optional[List[int]] = None
+    live_rows = 0
+
+    def note_observations(self, values: Dict[str, int]) -> None:
+        from dbsp_tpu.timeseries import counters
+
+        self.observed_lives = [values[f"live.{k}"] for k in self.level_keys]
+        self.live_rows = sum(self.observed_lives)
+        if "gc_truncated" in values:
+            counters.note_gc(self.node.index, self.live_rows,
+                             values["gc_truncated"],
+                             sum(self.caps[k] for k in self.level_keys))
 
     def eval(self, ctx, state, inputs):
         delta = inputs[0]
@@ -1211,7 +1250,16 @@ class CWatermark(CNode):
         wm1 = jnp.where(any_live,
                         jnp.maximum(wm0, m - self.op.lateness), wm0)
         valid1 = valid0 | any_live
+        ctx.observe(self, "watermark_ms", jnp.where(valid1, wm1, 0))
         return (wm1, valid1), CMaybe(valid1, wm1)
+
+    watermark_ms = 0
+
+    def note_observations(self, values: Dict[str, int]) -> None:
+        from dbsp_tpu.timeseries import counters
+
+        self.watermark_ms = values["watermark_ms"]
+        counters.note_watermark(self.node.index, self.watermark_ms)
 
 
 class CApply(CNode):
@@ -1242,6 +1290,11 @@ class CWindow(CNode):
         super().__init__(node, op)
         self.caps["slide_out"] = 0
         self.caps["slide_in"] = 0
+        self.caps["out"] = 0
+        # rows slid out of / into the window, summed over the trace's
+        # levels: of the last validated tick, and since the circuit began
+        self.slid_last = {"out": 0, "in": 0}
+        self.slid_total = {"out": 0, "in": 0}
 
     def init_state(self):
         # (a0, b0, had_bounds) — per worker under a mesh (the bounds stream
@@ -1265,23 +1318,41 @@ class CWindow(CNode):
         b0e = jnp.where(had, b0, a1)
 
         if not self.caps["slide_out"]:
-            cap = max(64, view.delta.cap)
-            self.caps["slide_out"] = cap
-            self.caps["slide_in"] = cap
+            # seeds, like an aggregate's ``queries``: what the validations
+            # read sizes them. (A first guess of the delta's capacity each
+            # made q5's window hand on a delta of 9 x 327,680 rows — its
+            # share and 8 slices — for 76,000 live ones, and every kernel
+            # downstream was sized by it.) The output starts at the
+            # delta's capacity: a tick's first rows are all inflow.
+            self.caps["slide_out"] = self.caps["slide_in"] = 64
+            self.caps["out"] = max(64, view.delta.cap)
         # slide ranges are extracted per trace level (shared slide caps —
         # the requirement's running max sizes them to the worst level)
         parts = [_filter_window(view.delta, a1, b1)]
+        # what comes in must go out: a full window slides out in a tick
+        # what a tick brings in, so the slide-out capacity is asked to hold
+        # a tick's inflow from the first tick on, when nothing slides yet
+        # and no reading of its own could size it
+        inflow = jnp.where(valid1, parts[0].live_count(), 0)
+        slid_out = slid_in = jnp.zeros((), jnp.int64)
         for lvl in view.pre:
             out_b, n_out = _slice_range(lvl, a0e, jnp.minimum(a1, b0e),
                                         self.caps["slide_out"])
-            ctx.require(self, "slide_out", n_out)
+            ctx.require(self, "slide_out", jnp.maximum(n_out, inflow))
             parts.append(out_b.neg())
             in_b, n_in = _slice_range(lvl, jnp.maximum(b0e, a1), b1,
                                       self.caps["slide_in"])
             ctx.require(self, "slide_in", n_in)
             parts.append(in_b)
-        # masked: everything is dead until bounds exist
+            slid_out, slid_in = slid_out + n_out, slid_in + n_in
+        # summed over the levels, where the requirements keep the worst one
+        ctx.observe(self, "out", jnp.where(valid1, slid_out, 0))
+        ctx.observe(self, "in", jnp.where(valid1, slid_in, 0))
+        # masked: everything is dead until bounds exist. Consolidated, the
+        # live rows stand first: the capacity handed on is the output's own
         out = concat_batches(parts).consolidate().masked(valid1)
+        ctx.require(self, "out", out.live_count())
+        out = out.with_cap(self.caps["out"])
 
         if self.op.gc:
             ctx.gc_bounds[self.node.inputs[0]] = \
@@ -1289,6 +1360,14 @@ class CWindow(CNode):
         state2 = (jnp.where(valid1, a1, a0), jnp.where(valid1, b1, b0),
                   had | valid1)
         return state2, out
+
+    def note_observations(self, values: Dict[str, int]) -> None:
+        from dbsp_tpu.timeseries import counters
+
+        self.slid_last = dict(values)
+        for direction, rows in values.items():
+            self.slid_total[direction] += rows
+        counters.note_slide(self.node.index, values["out"], values["in"])
 
 
 # ---------------------------------------------------------------------------

@@ -78,6 +78,13 @@ from dbsp_tpu.zset.batch import Batch, bucket_cap
 from dbsp_tpu.trace.spine import MAINTAIN_BUDGET_ROWS  # noqa: E402
 
 
+# Room an overflowed capacity of a windowed view gets while its window
+# fills (see CompiledHandle.grow): NEXmark q5's capacities end 6 to 15 times
+# their first reading above 0 (CPU rehearsal at the benchmark's size,
+# PERF.md 6, PR 36), and each re-trace is a whole-program compile.
+RAMP_ROOM = 8
+
+
 class CompiledOverflow(RuntimeError):
     """A static capacity was exceeded since the last validation point.
 
@@ -101,6 +108,8 @@ class _Ctx:
         self.outputs: Dict[int, Batch] = {}
         self.reqs: List[jnp.ndarray] = []
         self.req_index: List[Tuple[CNode, str]] = []
+        self.obs: List[jnp.ndarray] = []
+        self.obs_index: List[Tuple[CNode, str]] = []
         # trace-node index -> lower bound: window GC feeding back into the
         # trace state within the same program (TraceBound semantics)
         self.gc_bounds: Dict[int, jnp.ndarray] = {}
@@ -108,6 +117,14 @@ class _Ctx:
     def require(self, cnode: CNode, key: str, scalar) -> None:
         self.req_index.append((cnode, key))
         self.reqs.append(jnp.asarray(scalar, jnp.int64))
+
+    def observe(self, cnode: CNode, key: str, scalar) -> None:
+        """A non-negative device scalar that rides the requirement vector
+        (behind the requirements) and is checked against no capacity:
+        validation hands it to ``cnode.note_observations``. The time
+        nodes' counters come this way, at no device fetch of their own."""
+        self.obs_index.append((cnode, key))
+        self.obs.append(jnp.asarray(scalar, jnp.int64))
 
 
 def _cnode_for(node) -> CNode:
@@ -291,6 +308,36 @@ class CompiledHandle:
                     # host cache only ever sees drains grow them) or the
                     # base_live requirement integrates upward forever
                     tgt._gc_refresh = True
+        # A WINDOWED VIEW: the trace under a GC bound and every node
+        # downstream of its window. Rows leave such a view as fast as they
+        # arrive, so its state is bounded by the window's span and its
+        # per-delta capacities ramp while the window fills and then stay
+        # (see grow). Its traces count their levels' live rows in the step
+        # program (``_run_nodes``): maintenance plans from exact counts,
+        # where its host-side sums of drained rows only ever grow — under
+        # steady retraction they drift above the rows held without bound
+        # and end in a capacity grown for rows that cancelled long ago.
+        self._windowed: set = set()
+        for cn in self.cnodes:
+            if isinstance(cn, cnodes.CWindow) and cn.op.gc:
+                self._windowed.update((cn.node.index, cn.node.inputs[0]))
+            elif any(i in self._windowed for i in cn.node.inputs) and \
+                    cn.node.index not in self._windowed:
+                self._windowed.add(cn.node.index)
+        for cn in self.cnodes:
+            if isinstance(cn, cnodes.CTrace) and \
+                    cn.node.index in self._windowed:
+                cn._counts_lives = True
+                # ... and keep the merged level 0: half of a delta cancels
+                # rows level 0 already holds, and a merge nets them at
+                # once. Slots also pin their size at the FIRST trace, when
+                # a producer's capacity is still its seed (an aggregate's
+                # 64 ``queries``: a delta of 128); once that has grown no
+                # delta matches the pin, every tick sorts level 0 on the
+                # fallback path and consumers probe it as cap / 128 slot
+                # runs (q5's by_window trace: 2,048 of them, and 262 s of
+                # a step program's compile for a v5e in that trace alone)
+                cn._no_slots = True
         # map host InputHandle ops -> node indices (for feeds dicts)
         self._op_to_index = {id(n.operator): n.index for n in self.order}
         self._gen_fn = gen_fn
@@ -321,6 +368,9 @@ class CompiledHandle:
         # steady tick raise with a stack
         self._steady_guard: Optional[str] = None
         self._checks: List[Tuple[CNode, str]] = []
+        # what the nodes observe (``_Ctx.observe``): behind the
+        # requirements in the same vector
+        self._observed: List[Tuple[CNode, str]] = []
         self._req = None          # device running-max of requirements
         self._max_jit = jax.jit(jnp.maximum)
         self.last_outputs: Dict[int, Batch] = {}
@@ -902,10 +952,19 @@ class CompiledHandle:
                 new_states[key] = (tuple(
                     cnodes.truncate_below(lvl, bound)
                     for lvl in levels), base)
+                ctx.observe(self.by_index[idx], "gc_truncated", sum(
+                    a.live_count() - b.live_count()
+                    for a, b in zip(levels, new_states[key][0])))
+        for cn in self.cnodes:
+            if getattr(cn, "_counts_lives", False):
+                for k, lvl in zip(cn.level_keys,
+                                  new_states[str(cn.node.index)][0]):
+                    ctx.observe(cn, f"live.{k}", lvl.live_count())
         new_states = self._without_cold(new_states, cold)
-        req = (jnp.stack(ctx.reqs) if ctx.reqs
+        req = (jnp.stack(ctx.reqs + ctx.obs) if ctx.reqs or ctx.obs
                else jnp.zeros((0,), jnp.int64))
         self._checks = ctx.req_index  # same order every trace
+        self._observed = ctx.obs_index
         return new_states, ctx.outputs, req
 
     def _make_step(self):
@@ -1172,10 +1231,18 @@ class CompiledHandle:
             cn.note_requirement(key, int(r))
             if int(r) > cn.caps[key]:
                 items.append((cn, key, int(r)))
-        self.last_req = req  # validated requirement levels (for presize)
+        # validated requirement levels (for presize)
+        self.last_req = req[:len(self._checks)]
         self._req = jnp.zeros_like(self._req)
         if items:
             raise CompiledOverflow(items)
+        # what the nodes observed, a node at a time: only of an interval
+        # that stands (an overflowed one is replayed and validated again)
+        seen: Dict[CNode, Dict[str, int]] = {}
+        for (cn, key), v in zip(self._observed, req[len(self._checks):]):
+            seen.setdefault(cn, {})[key] = int(v)
+        for cn, values in seen.items():
+            cn.note_observations(values)
 
     def _req_value(self, cn: CNode, key: str) -> Optional[int]:
         """The last validated requirement for (cn, key), if any."""
@@ -1264,7 +1331,14 @@ class CompiledHandle:
                 # bounds — netting may shrink the real count; an over-
                 # estimate only triggers an early drain, never an error).
                 cache = getattr(cn, "_live_cache", None)
-                if cache is None or len(cache) != K or \
+                observed = getattr(cn, "observed_lives", None)
+                counted = observed is not None and len(observed) == K
+                if counted:
+                    # a windowed view's trace: the step program counted
+                    # every level (exact where the sums below only grow),
+                    # once per validation
+                    cache, cn.observed_lives = list(observed), None
+                elif cache is None or len(cache) != K or \
                         getattr(cn, "_gc_refresh", False):
                     cache = [int(b.max_worker_live()) for b in levels]
                 lives = cache
@@ -1272,7 +1346,9 @@ class CompiledHandle:
                 due0 = lives[0]
                 if req is not None:
                     due0 = req
-                    if getattr(cn, "_slot_cap", None):
+                    if counted:
+                        pass  # level 0's rows were counted with the rest
+                    elif getattr(cn, "_slot_cap", None):
                         # SLOTTED l0: the l0 requirement is slot CAPACITY
                         # consumed, not rows — using it as a row count
                         # would inflate every downstream lives[] (sparse
@@ -1435,6 +1511,8 @@ class CompiledHandle:
         # re-heated (and anything newly over budget), promote re-hot
         # levels under headroom — every transition logged with its cause
         changed |= self._enforce_residency(cause="budget")
+        if self._observed:
+            self._record_time_tick()
         if stats["rows_moved"] > rows_before:
             self._note_cause("maintain")
         if changed:
@@ -1442,6 +1520,42 @@ class CompiledHandle:
             self._step_jit = None
             self._scan_jits = {}
         return changed
+
+    def time_facts(self) -> Dict[str, int]:
+        """What this circuit's time nodes observed in the last validated
+        interval (``timeseries/counters.py`` has them per node): the args
+        of the driver's ``tick.validate`` span and, with the traces' rows,
+        a record of ``VALIDATED_TICKS``. Empty without time nodes."""
+        if not self._observed:
+            return {}
+        nodes = {cn for cn, _ in self._observed}
+        wins = [cn for cn in nodes if isinstance(cn, cnodes.CWindow)]
+        marks = [cn.watermark_ms for cn in nodes
+                 if isinstance(cn, cnodes.CWatermark)]
+        return {"retired_rows": sum(cn.slid_last["out"] for cn in wins),
+                "slid_in_rows": sum(cn.slid_last["in"] for cn in wins),
+                "watermark_ms": max(marks, default=0)}
+
+    def _record_time_tick(self) -> None:
+        from dbsp_tpu.timeseries import counters
+
+        traces = [cn for cn in self.cnodes
+                  if getattr(cn, "_counts_lives", False)]
+        gcd = [counters.TRACE_GC_ROWS.get(cn.node.index, {})
+               for cn in traces if getattr(cn, "_gc_refresh", False)]
+        counters.VALIDATED_TICKS.append({
+            **self.time_facts(),
+            "gc_live_rows": sum(g.get("live", 0) for g in gcd),
+            "gc_capacity_rows": sum(g.get("capacity", 0) for g in gcd),
+            "gc_truncated_rows": sum(g.get("truncated", 0) for g in gcd),
+            "trace_live_rows": sum(cn.live_rows for cn in traces)})
+
+    def _windows_filling(self) -> bool:
+        """True while a window of this circuit's windowed view has yet to
+        slide a row out: the view is still filling."""
+        wins = [cn for cn in self.cnodes if isinstance(cn, cnodes.CWindow)
+                and cn.node.index in self._windowed]
+        return any(cn.slid_total["out"] == 0 for cn in wins)
 
     def _enforce_ladders(self) -> bool:
         """Re-establish geometric level capacities between l0 and the tail.
@@ -1572,10 +1686,23 @@ class CompiledHandle:
         accelerator each re-trace costs a full program compile (minutes),
         so one projected grow beats a doubling ladder by several compiles.
 
+        A third kind of capacity, beside the monotone and the per-delta:
+        in a WINDOWED VIEW (``__init__``) every capacity ramps while the
+        window fills — the deltas carry ever more retractions, the groups
+        ever more rows — and then stays. Many read 0 in the first tick, so
+        no presize can size them, and they ramp together. While a window
+        of the view has yet to slide a row out, an overflowed capacity of
+        the view gets :data:`RAMP_ROOM` times its requirement, and those
+        past half their capacity are raised in the same re-trace. (A
+        requirement read downstream of a capacity that overflowed is of a
+        truncated delta and reads low: room is what keeps the replay from
+        uncovering the next overflow.)
+
         State since the last validated snapshot is invalid — callers MUST
         follow with :meth:`restore` of a validated snapshot (which re-pads
         it to the new capacities)."""
         exchange_hit = False
+        ramping = self._windows_filling()
         for cn, key, required in overflow.items:
             # exchange-bucket overflow: a skewed tick routed more rows to a
             # worker than the static per-worker capacity — the replay that
@@ -1594,11 +1721,26 @@ class CompiledHandle:
                 exchange_hit = True
             factor = max(headroom, project_ratio * 1.3) \
                 if key in cn.MONOTONE_CAPS else headroom
+            floor = cn.caps[key]
+            if ramping and cn.node.index in self._windowed:
+                factor = RAMP_ROOM
+                if isinstance(cn, cnodes.CJoin):
+                    # a join's sides ramp together, and the reading of one
+                    # that overflows downstream of another overflow is of
+                    # a truncated delta: no less than its sibling
+                    floor = max(cn.caps["left"], cn.caps["right"])
             # max: a capacity key can overflow at several sites in one
             # interval (e.g. one requirement per trace level) — never let a
             # later, smaller item shrink the grown cap
-            cn.caps[key] = max(cn.caps[key],
-                               bucket_cap(int(required * factor)))
+            cn.caps[key] = max(floor, bucket_cap(int(required * factor)))
+        if ramping:
+            # the neighbours that ramp with them (a requirement past half
+            # its capacity, in a view still filling, is on its way past
+            # it): raised in this re-trace, not each in one of its own
+            for (cn, key), r in zip(self._checks, self.last_req):
+                if cn.node.index in self._windowed and \
+                        2 * int(r) > cn.caps[key]:
+                    cn.caps[key] = bucket_cap(int(r) * 2 * headroom)
         if exchange_hit:
             self.exchange_overflows += 1
         self._enforce_ladders()
@@ -1639,10 +1781,16 @@ class CompiledHandle:
         use), so sharing them across snapshots is safe."""
         to_copy: Dict[str, Any] = {}
         reuse: Dict[str, Dict[int, Batch]] = {}
+        # levels copied because a trace under a GC bound is never clean
+        # (the driver's ``tick.snapshot`` span carries it)
+        self.snapshot_gc_levels = 0
         for key, st in self.states.items():
             cn = self._snap_cacheable(key)
             if cn is None:
                 to_copy[key] = st
+                if getattr(self.by_index.get(int(key)), "_gc_refresh",
+                           False):
+                    self.snapshot_gc_levels += len(st[0])
                 continue
             levels, b = st
             vers = self._level_versions.setdefault(key, [0] * len(levels))

@@ -177,6 +177,7 @@ class CompiledCircuitDriver:
             # observability (obs histogram + flight recorder) as bench runs
             with spans.span("tick.snapshot", "tick") as sp:
                 self._snap = self.ch.snapshot()
+                sp.note(gc_levels=self.ch.snapshot_gc_levels)
             self.ch.host_overhead_ns["snapshot"].append(sp.elapsed_ns)
             # the previous interval's snapshot is gone: zero-reference
             # cold blobs can be swept without endangering any replay
@@ -215,6 +216,10 @@ class CompiledCircuitDriver:
                             self.ch.step(tick=tick, feeds=feeds)
                             self._out_buffer.append(
                                 dict(self.ch.last_outputs))
+            facts = self.ch.time_facts()  # of a circuit with time nodes
+            if facts:
+                sp.note(retired_rows=facts["retired_rows"],
+                        watermark_ms=facts["watermark_ms"])
         self.ch.host_overhead_ns["validate"].append(sp.elapsed_ns)
         stats = self.ch.maintain_stats
         drains0 = stats["drains"] + stats["partial_drains"]

@@ -129,6 +129,16 @@ def q5(persons: Stream, auctions: Stream, bids: Stream) -> Stream:
                             preserves_first_key=True)
 
 
+#: q5 under NEXmark's own name for it ("Hot Items"). The served deployment
+#: ``nexmark-q5`` (benchmark/configs) asks for the query by this name, new
+#: in PR 36: a tree from before it can build q5 but cannot serve that
+#: deployment — a stale slot pin in the by_window trace makes each of its
+#: step programs take ~530 s to compile for a v5e, tick 0 alone 1,600 s
+#: (PERF.md 6, PR 36) — and, asked for a name it lacks, fails at once
+#: instead of running for an hour.
+hot_items = q5
+
+
 Q7_WINDOW_MS = 10_000
 
 

@@ -146,6 +146,63 @@ def export_exchange_overflows(registry: MetricsRegistry) -> None:
     registry.register_collector(_collect)
 
 
+def export_time_counters(registry: MetricsRegistry) -> None:
+    """Register a collector mirroring the time nodes' process-wide
+    counters (``timeseries/counters.py``, filled at validation): per
+    ``CWindow`` the rows slid out of and into the window
+    (``dbsp_tpu_window_slide_rows_total{node,dir}``), per trace under a GC
+    bound the rows truncated and the rows left
+    (``dbsp_tpu_trace_gc_rows_total{node}``,
+    ``dbsp_tpu_trace_gc_live_rows{node}``; its levels' capacity beside
+    them), per ``CWatermark`` where it stands (``dbsp_tpu_watermark_ms``)."""
+    if getattr(registry, "_time_counters_exported", False):
+        return
+    registry._time_counters_exported = True
+    slide = registry.counter(
+        "dbsp_tpu_window_slide_rows_total",
+        "Rows a window retracted because its bounds moved past them "
+        "(dir = out) or admitted (dir = in), summed over the levels of "
+        "its trace", labels=("node", "dir"))
+    gc_total = registry.counter(
+        "dbsp_tpu_trace_gc_rows_total",
+        "Rows the trace-bound GC truncated from a windowed trace inside "
+        "the step program", labels=("node",))
+    gc_live = registry.gauge(
+        "dbsp_tpu_trace_gc_live_rows",
+        "Live rows of a trace under a GC bound after the last validated "
+        "tick's truncation", labels=("node",))
+    gc_cap = registry.gauge(
+        "dbsp_tpu_trace_gc_capacity_rows",
+        "Capacity of the levels of a trace under a GC bound: what the "
+        "truncation, the window's slices and the snapshot walk every tick",
+        labels=("node",))
+    watermark = registry.gauge(
+        "dbsp_tpu_watermark_ms",
+        "The watermark of a compiled watermark node at the last "
+        "validated tick, ms of event time", labels=("node",))
+    advance = registry.gauge(
+        "dbsp_tpu_watermark_advance_ms",
+        "How far the last validated tick moved the watermark",
+        labels=("node",))
+
+    def _collect() -> None:
+        from dbsp_tpu.timeseries import counters
+
+        for node, total in list(counters.WINDOW_SLIDE_TOTAL.items()):
+            for direction, rows in total.items():
+                slide.labels(node=str(node), dir=direction).set_total(rows)
+        for node, ent in list(counters.TRACE_GC_ROWS.items()):
+            gc_total.labels(node=str(node)).set_total(
+                ent["truncated_total"])
+            gc_live.labels(node=str(node)).set(ent["live"])
+            gc_cap.labels(node=str(node)).set(ent["capacity"])
+        for node, ent in list(counters.WATERMARK_MS.items()):
+            watermark.labels(node=str(node)).set(ent["ms"])
+            advance.labels(node=str(node)).set(ent["advance"])
+
+    registry.register_collector(_collect)
+
+
 def _gid_str(gid: Tuple[int, ...]) -> str:
     return ".".join(map(str, gid))
 
@@ -210,6 +267,7 @@ class CircuitInstrumentation:
         export_consolidate_paths(registry)
         export_kernel_dispatch(registry)
         export_exchange_overflows(registry)
+        export_time_counters(registry)
         circuit.register_scheduler_event_handler(self._on_event)
         # mark exchange operators so they accumulate rows/bytes moved —
         # this costs one scalar device->host sync per exchange per tick
@@ -401,6 +459,7 @@ class CompiledInstrumentation:
         export_consolidate_paths(registry)
         export_kernel_dispatch(registry)
         export_exchange_overflows(registry)
+        export_time_counters(registry)
         if spans is not None:
             driver.spans = spans  # driver records tick/validate spans
 

@@ -144,6 +144,9 @@ class _SegCtx:
         self.req_index.append((cnode, key))
         self.reqs.append(jnp.asarray(scalar, jnp.int64))
 
+    def observe(self, cnode, key: str, scalar) -> None:
+        """Counters are the serving step's; a probe tick fills none."""
+
 
 def _cost_of(executable) -> Dict[str, float]:
     """XLA cost analysis of one compiled segment (flops / bytes accessed —

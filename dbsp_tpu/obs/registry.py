@@ -38,9 +38,11 @@ from dbsp_tpu.testing.tsan import maybe_instrument as _tsan_hook
 
 # final name segment must be a unit (prometheus naming conventions; "total"
 # is the counter suffix, "info" the build-info idiom; "timestamp" covers
-# event-time domains whose unit the engine cannot know)
+# event-time domains whose unit the engine cannot know; "ms" is event time
+# where it can: the time nodes' watermarks, milliseconds by the contract
+# of ``timeseries/`` — never a duration, those are "seconds")
 ALLOWED_UNITS = ("total", "seconds", "rows", "bytes", "count", "ratio",
-                 "info", "timestamp")
+                 "info", "timestamp", "ms")
 
 _NAME_RE = re.compile(r"^dbsp_tpu_[a-z0-9]+(_[a-z0-9]+)+$")
 _LABEL_RE = re.compile(r"^[a-z_][a-z0-9_]*$")
@@ -84,6 +86,9 @@ ALLOWED_LABEL_NAMES = frozenset((
     # set obs.tracing.E2E_STAGES (queue_wait, tick, publish, transport,
     # apply, serve)
     "stage",
+    # time nodes (timeseries/counters.py): "dir" is the way a row crossed
+    # a window's bounds — the closed set {out, in}
+    "dir",
 ))
 
 

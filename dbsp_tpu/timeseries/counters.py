@@ -1,0 +1,59 @@
+"""Process-wide counters of the time nodes of compiled circuits: what the
+windows slid, what the trace-bound GC truncated, where the watermarks stand.
+
+Filled at validation from scalars that ride the requirement vector the
+handle fetches anyway (``compiler._Ctx.observe``): no device sync of their
+own. Keyed by node index like ``parallel/exchange.py::EXCHANGE_SITE_ROWS``;
+under a worker mesh a row count is the worst worker's. With a validation
+cadence above one, "the last tick" is the largest tick of the interval.
+Exported by ``obs/instrument.py::export_time_counters`` as
+``dbsp_tpu_window_slide_rows_total{node,dir}``,
+``dbsp_tpu_trace_gc_rows_total{node}``, ``dbsp_tpu_trace_gc_live_rows{node}``
+and ``dbsp_tpu_watermark_ms{node}``. Empty for a circuit without time nodes.
+"""
+
+from __future__ import annotations
+
+import collections
+from typing import Dict
+
+# CWindow node -> {"out": rows, "in": rows} slid out of / into the window,
+# summed over the levels of its trace: of the last validated tick, in total
+WINDOW_SLIDE_LAST: Dict[int, Dict[str, int]] = {}
+WINDOW_SLIDE_TOTAL: Dict[int, Dict[str, int]] = {}
+
+# trace under a GC bound -> {"live": rows left after the last truncation,
+# "truncated": rows it dropped, "truncated_total": since the circuit began,
+# "capacity": of its levels}
+TRACE_GC_ROWS: Dict[int, Dict[str, int]] = {}
+
+# CWatermark node -> {"ms": the watermark, "advance": over the last tick}
+WATERMARK_MS: Dict[int, Dict[str, int]] = {}
+
+# one record per validated interval of a circuit with time nodes, oldest
+# first, bounded (``CompiledHandle.maintain`` appends it: the sums over
+# that circuit's nodes): ``retired_rows`` / ``slid_in_rows`` (windows),
+# ``gc_live_rows`` / ``gc_capacity_rows`` / ``gc_truncated_rows`` (traces
+# under a GC bound), ``trace_live_rows`` (every leveled trace of the
+# windowed view, counted in the step program), ``watermark_ms``
+VALIDATED_TICKS: collections.deque = collections.deque(maxlen=4096)
+
+
+def note_slide(node: int, out_rows: int, in_rows: int) -> None:
+    WINDOW_SLIDE_LAST[node] = {"out": out_rows, "in": in_rows}
+    total = WINDOW_SLIDE_TOTAL.setdefault(node, {"out": 0, "in": 0})
+    total["out"] += out_rows
+    total["in"] += in_rows
+
+
+def note_gc(node: int, live: int, truncated: int, capacity: int) -> None:
+    before = TRACE_GC_ROWS.get(node, {}).get("truncated_total", 0)
+    TRACE_GC_ROWS[node] = {"live": live, "truncated": truncated,
+                           "truncated_total": before + truncated,
+                           "capacity": capacity}
+
+
+def note_watermark(node: int, ms: int) -> None:
+    before = WATERMARK_MS.get(node, {}).get("ms", 0)
+    WATERMARK_MS[node] = {"ms": ms,
+                          "advance": ms - before if before else 0}
