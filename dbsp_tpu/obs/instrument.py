@@ -86,7 +86,9 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
         "CPU's: xla_bitonic = the "
         "bitonic merge network behind kernel=merge and kernel=sort_merge, "
         "a sort of more than SORT_CHUNK_ROWS rows; xla_shift = the shift "
-        "compaction behind kernel=compact); the fused ladder-consumer "
+        "compaction behind kernel=compact; xla_merge = a level of "
+        "kernel=probe_ladder in which sorted queries were ranked by one "
+        "merge, its xla rows the levels searched); the fused ladder-consumer "
         "megakernels report as kernel=join_ladder / gather_ladder / "
         "old_weights and the reduction offensive as kernel=segment_reduce "
         "/ agg_ladder / join_sorted, whose xla rows are the stitched-chain "

@@ -11,10 +11,24 @@ the delta alone.
 This module collapses that fan-out (the engine's answer to the reference's
 ``CursorList`` k-way merge cursor, ``trace/cursor/cursor_list.rs``):
 
-* :func:`lex_probe_ladder` — ONE vectorized lexicographic search over the
-  whole level ladder: [K, m] (level, query) lanes share a single unrolled
-  binary-search loop (on CPU with the native library, ONE ladder-wide C++
-  probe call — same result, same shape).
+* :func:`lex_probe_ladder` — the insertion points of the query rows in
+  EVERY level, ``[K, m]``. On CPU with the native library ONE ladder-wide
+  C++ probe call. Else one of two formulations PER LEVEL, same lanes bit
+  for bit: the binary search (``kernels._probe_search``: one gather of
+  ``m`` elements a key column at each of the level's ``cap.bit_length()``
+  steps), or — on an accelerator, for a caller that states its queries
+  are SORTED — one merge (``kernels.rank_sorted``: both operands are
+  sorted, so a query's insertion point is the count of table rows ahead
+  of it in the merged order; the merge network and the shift compaction
+  stream whole columns and gather nothing). ``kernels.rank_by_merge``
+  chooses from the two shapes alone, by two rates read on a TPU v5 lite:
+  ``PROBE_GATHER_NS`` (a gathered element) and ``PROBE_PASS_NS`` (a row
+  of one column through one stage). A wide delta against levels of its
+  own order is merged; a few thousand lanes against millions of rows keep
+  the search. ``join_ladder``, ``old_weights_ladder`` (a delta tagged as
+  one consolidated run) and the aggregate's equality ``gather_ladder``
+  (the front-packed unique keys of such a delta) make the statement; the
+  range form and every other caller search.
 * :func:`expand_ladder` — ONE ``expand_ranges``-style prefix-sum allocation
   whose [K*m] counts span levels: each output slot resolves to (level,
   query row, source row) through a single searchsorted over the cross-level
@@ -57,19 +71,28 @@ Cols = Tuple[jnp.ndarray, ...]
 # ---------------------------------------------------------------------------
 
 
+@kernels._scoped
 def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
-                     side: str = "left") -> jnp.ndarray:
+                     side: str = "left", sorted_queries: bool = False
+                     ) -> jnp.ndarray:
     """Insertion points of ``query`` rows into EVERY sorted table at once.
 
     ``tables`` is one tuple of key columns per trace level (heterogeneous
     capacities are fine — each level's lanes clamp to its own row count);
     returns ``[K, m]`` int32. Lane (k, i) equals
     ``lex_probe(tables[k], query_cols, side)[i]`` exactly.
+
+    ``sorted_queries`` is the caller's statement that ``query_cols`` are
+    sorted (a consolidated delta's keys, dead sentinel rows at the tail).
+    On an accelerator each level then takes whichever formulation is
+    cheaper for its two shapes (:func:`kernels.rank_by_merge`): the merge
+    of :func:`kernels.rank_sorted`, or the binary search with that level's
+    own number of steps.
     """
     assert tables, "lex_probe_ladder: empty ladder"
-    K = len(tables)
-    m = query_cols[0].shape[0] if query_cols else 0
-    if query_cols and query_cols[0].ndim == 1:
+    m = query_cols[0].shape[-1]
+    flat = query_cols[0].ndim == 1
+    if flat:
         dts = [c.dtype for t in tables for c in t]
         if kernels.native_kernel("probe_ladder"):
             from dbsp_tpu.zset import native_merge
@@ -79,21 +102,17 @@ def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
                 kernels.count_kernel_dispatch("probe_ladder", "native")
                 return native_merge.lex_probe_ladder_native(
                     tables, query_cols, side)
-    kernels.count_kernel_dispatch("probe_ladder", "xla")
-    caps = [t[0].shape[0] for t in tables]
-    steps = max(c.bit_length() for c in caps)
-    strict = side == "left"
-    lo = jnp.zeros((K, m), jnp.int32)
-    hi = jnp.stack([jnp.full((m,), c, jnp.int32) for c in caps])
-    for _ in range(steps):
-        active = lo < hi
-        mid = (lo + hi) >> 1
-        go_right = jnp.stack([
-            kernels._lex_le_rows(t, mid[k], query_cols, strict=strict)
-            for k, t in enumerate(tables)])
-        lo = jnp.where(active & go_right, mid + 1, lo)
-        hi = jnp.where(active & ~go_right, mid, hi)
-    return lo
+    may_merge = sorted_queries and flat and kernels.accelerator()
+    out = []
+    for t in tables:
+        if may_merge and kernels.rank_by_merge(m, t[0].shape[0],
+                                               len(query_cols)):
+            kernels.count_kernel_dispatch("probe_ladder", "xla_merge")
+            out.append(kernels.rank_sorted(t, query_cols, side))
+        else:
+            kernels.count_kernel_dispatch("probe_ladder", "xla")
+            out.append(kernels._probe_search(t, query_cols, side))
+    return jnp.stack(out)
 
 
 # ---------------------------------------------------------------------------
@@ -101,6 +120,7 @@ def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
 # ---------------------------------------------------------------------------
 
 
+@kernels._scoped
 def expand_ladder(lo: jnp.ndarray, hi: jnp.ndarray, out_cap: int):
     """Flatten ``[K, m]`` per-(level, query) ranges into ONE static buffer.
 
@@ -143,6 +163,7 @@ def expand_ladder(lo: jnp.ndarray, hi: jnp.ndarray, out_cap: int):
     return level, qrow, src.astype(jnp.int32), valid, total
 
 
+@kernels._scoped
 def _select_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
                    src: jnp.ndarray) -> Cols:
     """Gather column values from the level each output slot resolved to:
@@ -251,8 +272,10 @@ def join_ladder(delta: Batch, levels: Sequence[Batch], nk: int, fn,
                                     total)
     kernels.count_kernel_dispatch("join_ladder", "xla")
     tables = [lvl.keys[:nk] for lvl in levels]
-    lo = lex_probe_ladder(tables, dk, side="left")
-    hi = lex_probe_ladder(tables, dk, side="right")
+    # a consolidated delta's keys are sorted: the probes may rank by merge
+    claim = delta.sorted_runs == 1
+    lo = lex_probe_ladder(tables, dk, "left", sorted_queries=claim)
+    hi = lex_probe_ladder(tables, dk, "right", sorted_queries=claim)
     # dead delta rows carry sentinel keys, which match every level's dead
     # tail — zero their ranges instead of emitting weight-0 garbage
     live = delta.weights != 0
@@ -269,7 +292,7 @@ def join_ladder(delta: Batch, levels: Sequence[Batch], nk: int, fn,
 
 def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
                   out_cap: int, qhi_keys: Cols = None,
-                  gather_keys: int = 0):
+                  gather_keys: int = 0, sorted_queries: bool = False):
     """Gather the query keys' rows from ALL trace levels into one
     (qrow, val_cols, w) part of capacity ``out_cap``. Dead slots carry
     qrow == q_cap (the trash segment) and sentinel vals — the same contract
@@ -284,6 +307,10 @@ def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
     ranges, qhi < qlo, gather nothing); ``gather_keys`` returns that many
     trailing PROBED KEY columns ahead of the vals (range gathers need the
     time column back; equality gathers already hold their keys).
+    ``sorted_queries`` states that ``qkeys`` are sorted (the front-packed
+    unique keys of a consolidated delta): the equality form's probes may
+    then rank by merge (:func:`lex_probe_ladder`); the range form always
+    searches.
 
     NOTE: with K > 1 the part may hold cross-level insert/retract rows for
     one (qrow, vals) — reducers must net them
@@ -311,9 +338,10 @@ def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
                     gather_keys=gather_keys)
     kernels.count_kernel_dispatch("gather_ladder", "xla")
     tables = [lvl.keys[:nk] for lvl in levels]
-    lo = lex_probe_ladder(tables, qkeys, side="left")
+    claim = sorted_queries and qhi_keys is None
+    lo = lex_probe_ladder(tables, qkeys, "left", sorted_queries=claim)
     hi = lex_probe_ladder(tables, qkeys if qhi_keys is None else qhi_keys,
-                          side="right")
+                          "right", sorted_queries=claim)
     lo = jnp.where(qlive[None, :], lo, 0)
     # probes are monotone, so with distinct bounds an empty query range
     # (qhi < qlo) lands hi <= lo — the clamp makes it gather nothing;
@@ -431,7 +459,9 @@ def _agg_ladder_stitched(delta: Batch, nk: int, out_trace: Batch, levels,
     else:
         d_vals, d_present = None, None  # general path never reads them
     mask = qlive & jnp.broadcast_to(flag, qlive.shape)
-    part, gtot = gather_ladder(qkeys, mask, levels, gather_cap)
+    # the unique keys of a consolidated delta, front-packed: sorted
+    part, gtot = gather_ladder(qkeys, mask, levels, gather_cap,
+                               sorted_queries=delta.sorted_runs == 1)
     lad_vals, lad_present = A._reduce_groups_impl(
         (part,), agg, q_cap, net=len(levels) > 1)
     return (qkeys, qlive, nq, old_vals, old_present, lad_vals, lad_present,
@@ -456,8 +486,9 @@ def old_weights_ladder(delta: Batch, levels: Sequence[Batch]) -> jnp.ndarray:
     kernels.count_kernel_dispatch("old_weights", "xla")
     cols = delta.cols
     tables = [lvl.cols for lvl in levels]
-    lo = lex_probe_ladder(tables, cols, side="left")
-    hi = lex_probe_ladder(tables, cols, side="right")
+    claim = delta.sorted_runs == 1  # whole rows of a consolidated delta
+    lo = lex_probe_ladder(tables, cols, "left", sorted_queries=claim)
+    hi = lex_probe_ladder(tables, cols, "right", sorted_queries=claim)
     live = delta.weights != 0
     found = (hi > lo) & live[None, :]
     old = jnp.zeros_like(delta.weights)

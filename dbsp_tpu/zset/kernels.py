@@ -77,7 +77,8 @@ def count_consolidate_path(path: str) -> None:
 # "xla" (pure-XLA lowering) or, where an accelerator's XLA formulation is
 # not the CPU's, its name: "xla_bitonic" (the merge network behind "merge"
 # and "sort_merge", a sort of more than SORT_CHUNK_ROWS rows) and
-# "xla_shift" (the shift compaction of "compact").
+# "xla_shift" (the shift compaction of "compact"), "xla_merge" (a level of
+# "probe_ladder" ranked by one merge; that kernel counts per LEVEL).
 # Same counting convention as CONSOLIDATE_COUNTS (eager calls per eval,
 # traced calls per trace); exported by obs as
 # ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` and embedded in
@@ -99,6 +100,37 @@ def accelerator() -> bool:
     CPU, the plain XLA one else. Asked at every call — never cached: tests
     and the benchmark's rehearsals replace ``jax.default_backend``."""
     return jax.default_backend() != "cpu"
+
+
+# The two rates behind :func:`rank_by_merge`, both read on a TPU v5 lite
+# with each formulation alone and warm (``tools/probe_rates.py``; my chip
+# run, PR 37, call 1; PERF.md 6). A binary search gathers single elements:
+# 14.3-14.5 ns a gathered int64 for 4,096 to 65,536 lanes against tables of
+# 4,096 to 2,097,152 rows (27-38 ns at 262,144 lanes; PR 29 read 16.5). The
+# merge network, the count of table rows ahead and the shift compaction
+# stream whole columns: 0.012-0.014 ns a row for each column a stage
+# carries, from 262,144 padded rows up (65,536 queries in 262,144 rows:
+# 0.62 ms against the search's 17.9 ms; below that a call's ~0.2 ms of loop
+# overhead shows, as the search's ~20 us a step does).
+PROBE_GATHER_NS = 14.4
+PROBE_PASS_NS = 0.013
+
+
+def rank_by_merge(m: int, cap: int, nk: int) -> bool:
+    """Is ranking ``m`` SORTED queries in a sorted table of ``cap`` rows
+    cheaper by one merge (:func:`rank_sorted`) than by a binary search, on
+    an accelerator? A static cost of the two shapes and nothing else. The
+    search gathers ``m`` elements a key column at each of its
+    ``cap.bit_length()`` steps. The merge streams the padded union through
+    one stage per binary digit of its length: the network carries the key
+    columns and a row number, the count of table rows ahead and the shift
+    of the queries back to the front three narrow columns more. So a wide
+    delta against a level of its own order takes the merge, and a few
+    thousand lanes against a level of millions keep the search."""
+    search = cap.bit_length() * m * nk * PROBE_GATHER_NS
+    total = 1 << (cap + m - 1).bit_length()
+    merge = (total.bit_length() - 1) * total * (nk + 4) * PROBE_PASS_NS
+    return merge < search
 
 
 def native_kernel(kernel: str) -> bool:
@@ -292,23 +324,31 @@ def _bitonic_merge(ops: Sequence[jnp.ndarray], num_keys: int, span: int
     return lax.fori_loop(0, span.bit_length() - 1, stage, tuple(ops))
 
 
-@partial(jax.jit, static_argnames=("num_keys",))
-def _merge_runs(ops_a: Sequence[jnp.ndarray], ops_b: Sequence[jnp.ndarray],
-                num_keys: int) -> Tuple[jnp.ndarray, ...]:
-    """Stable merge of two runs sorted by their first ``num_keys`` operands
-    (of equal rows ``a``'s come first): ``a``, pad rows that sort last and
-    ``b`` reversed are one bitonic sequence for :func:`_bitonic_merge`."""
+def _bitonic_rows(ops_a: Sequence[jnp.ndarray], ops_b: Sequence[jnp.ndarray]
+                  ) -> Tuple[jnp.ndarray, ...]:
+    """Two sorted runs as ONE bitonic sequence of a power-of-two length:
+    ``a``, pad rows that sort last and ``b`` reversed, with a last operand
+    of row numbers in (a, b, pad) order — of equal rows ``a``'s come first
+    and pads go last — for :func:`_bitonic_merge`."""
     na, nb = ops_a[0].shape[0], ops_b[0].shape[0]
     total = 1 << (na + nb - 1).bit_length()
     rows = [jnp.concatenate([a, jnp.full((total - na - nb,),
                                          _pad_last(a.dtype)),
                              b.astype(a.dtype)[::-1]])
             for a, b in zip(ops_a, ops_b)]
-    # row numbers in (a, b, pad) order: ties go to a, pads go last
     t = jnp.arange(total, dtype=jnp.int32)
-    t = jnp.concatenate([t[:na], t[na + nb:], t[na:na + nb][::-1]])
-    out = _bitonic_merge((*rows, t), num_keys, total)
-    return tuple(o[:na + nb] for o in out[:-1])
+    return (*rows, jnp.concatenate([t[:na], t[na + nb:], t[na:na + nb][::-1]]))
+
+
+@partial(jax.jit, static_argnames=("num_keys",))
+def _merge_runs(ops_a: Sequence[jnp.ndarray], ops_b: Sequence[jnp.ndarray],
+                num_keys: int) -> Tuple[jnp.ndarray, ...]:
+    """Stable merge of two runs sorted by their first ``num_keys`` operands
+    (of equal rows ``a``'s come first): :func:`_bitonic_rows` through
+    :func:`_bitonic_merge`."""
+    rows = _bitonic_rows(ops_a, ops_b)
+    out = _bitonic_merge(rows, num_keys, rows[0].shape[0])
+    return tuple(o[:ops_a[0].shape[0] + ops_b[0].shape[0]] for o in out[:-1])
 
 
 def _col_eq(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -379,28 +419,33 @@ def compact(cols: Sequence[jnp.ndarray], weights: jnp.ndarray,
     return tuple(out_cols), w
 
 
-@jax.jit
-def _compact_shift(ops: Sequence[jnp.ndarray], keep: jnp.ndarray
-                   ) -> Tuple[jnp.ndarray, ...]:
-    """:func:`compact` without a gather: a kept row moves left by the
-    number of dropped rows before it, one binary digit of that distance per
-    elementwise pass, lowest digit first. After the digits below 2**k a
-    kept row i sits at ``final_i + (dist_i >> k << k)``, which grows
-    strictly with i, so kept rows never collide and never pass each other.
-    As many passes as the longest distance has digits: none when the kept
-    rows are a prefix already (an insert-only merge). The last operand is
-    the weight column; dropped slots come out dead."""
+def _dropped_before(keep: jnp.ndarray) -> jnp.ndarray:
+    """Dropped rows at or before each row, by doubling (a cumsum of an odd
+    length can take the TPU's compiler half a minute: 1,114,112 rows,
+    30 s)."""
     n = keep.shape[0]
     i = jnp.arange(n, dtype=jnp.int32)
 
-    # dropped rows at or before i, by doubling (a cumsum of an odd length
-    # can take the TPU's compiler half a minute: 1,114,112 rows, 30 s)
     def count(s, c):
         j = jnp.int32(1) << s
         return c + jnp.where(i >= j, _rolled(c, n - j), 0)
 
-    dist = lax.fori_loop(0, (n - 1).bit_length(), count,
+    return lax.fori_loop(0, (n - 1).bit_length(), count,
                          (~keep).astype(jnp.int32))
+
+
+def _shift_front(ops: Sequence[jnp.ndarray], keep: jnp.ndarray,
+                 dist: jnp.ndarray):
+    """Move each kept row left by its ``dist`` — the dropped rows before it
+    — one binary digit of that distance per elementwise pass, lowest digit
+    first. After the digits below 2**k a kept row i sits at ``final_i +
+    (dist_i >> k << k)``, which grows strictly with i, so kept rows never
+    collide and never pass each other. As many passes as the longest
+    distance has digits: none when the kept rows are a prefix already (an
+    insert-only merge). Returns ``(keep, dist, ops)`` as moved: each
+    arrived row still knows how far it came."""
+    n = keep.shape[0]
+    i = jnp.arange(n, dtype=jnp.int32)
     dist = jnp.where(keep, dist, 0)
 
     def stage(carry):
@@ -412,9 +457,20 @@ def _compact_shift(ops: Sequence[jnp.ndarray], keep: jnp.ndarray
                 tuple(jnp.where(arrives, _rolled(o, j), o) for o in ops))
 
     top = jnp.max(dist)
-    _, keep, _, (*cols, w) = lax.while_loop(
+    _, keep, dist, ops = lax.while_loop(
         lambda carry: carry[0] <= top, stage,
         (jnp.int32(1), keep, dist, tuple(ops)))
+    return keep, dist, ops
+
+
+@jax.jit
+def _compact_shift(ops: Sequence[jnp.ndarray], keep: jnp.ndarray
+                   ) -> Tuple[jnp.ndarray, ...]:
+    """:func:`compact` without a gather: a kept row moves left by the
+    number of dropped rows before it (:func:`_dropped_before`,
+    :func:`_shift_front`). The last operand is the weight column; dropped
+    slots come out dead."""
+    keep, _, (*cols, w) = _shift_front(ops, keep, _dropped_before(keep))
     return (*(jnp.where(keep, c, sentinel_for(c.dtype)) for c in cols),
             jnp.where(keep, w, 0))
 
@@ -652,20 +708,64 @@ def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
             return native_merge.lex_probe_native(table_cols, query_cols,
                                                  side)
     count_kernel_dispatch("probe", "xla")
-    n = table_cols[0].shape[0]
-    m = query_cols[0].shape[0]
-    lo = jnp.zeros((m,), jnp.int32)
-    hi = jnp.full((m,), n, jnp.int32)
+    return _probe_search(table_cols, query_cols, side)
+
+
+def _probe_search(table_cols, query_cols, side: str) -> jnp.ndarray:
+    """The binary search of :func:`lex_probe`, unrolled: one gather of the
+    table per step per key column, as many steps as the table's row count
+    has binary digits. Any leading axes ride along."""
+    n = table_cols[0].shape[-1]
+    shape = query_cols[0].shape
+    lo = jnp.zeros(shape, jnp.int32)
+    hi = jnp.full(shape, n, jnp.int32)
     # n+1 candidate insertion points [0, n] => ceil(log2(n+1)) halvings
-    steps = n.bit_length()
     strict = side == "left"
-    for _ in range(steps):
+    for _ in range(n.bit_length()):
         active = lo < hi
         mid = (lo + hi) >> 1  # < hi <= n on active lanes; clamped gather else
         go_right = _lex_le_rows(table_cols, mid, query_cols, strict=strict)
         lo = jnp.where(active & go_right, mid + 1, lo)
         hi = jnp.where(active & ~go_right, mid, hi)
     return lo
+
+
+@_scoped
+def rank_sorted(table_cols: Tuple[jnp.ndarray, ...],
+                query_cols: Tuple[jnp.ndarray, ...],
+                side: str = "left") -> jnp.ndarray:
+    """:func:`lex_probe` for SORTED queries, by one merge instead of a
+    gather per search step: lane for lane the same insertion points.
+
+    Both operands are sorted, so a query's insertion point is the number
+    of table rows ahead of it in the merged order — ties to the queries
+    for ``side="left"``, to the table for ``"right"``. The merge network
+    (:func:`_bitonic_merge`) carries the key columns and a row number that
+    says which rows are queries; the table rows ahead of each
+    (:func:`_dropped_before`) are also how far it moves when the shift
+    compaction brings the queries back to the front in their own order
+    (:func:`_shift_front`), so the ranks are that compaction's distances.
+    Elementwise passes over contiguous rows only: nothing is gathered,
+    scattered, sorted or searched. Dead lanes included: sentinel queries
+    against a table's sentinel tail rank as the search ranks them, and the
+    network's pad rows sort behind both. No ``jit`` of its own: its callers
+    are traced, and its loops keep the caller's scope path in the lowered
+    program (``n6.CJoin/k.lex_probe_ladder/k.rank_sorted/while/body``)."""
+    assert len(table_cols) == len(query_cols) and table_cols
+    n = table_cols[0].shape[0]
+    m = query_cols[0].shape[0]
+    dts = [jnp.promote_types(t.dtype, q.dtype)
+           for t, q in zip(table_cols, query_cols)]
+    table = [t.astype(dt) for t, dt in zip(table_cols, dts)]
+    query = [q.astype(dt) for q, dt in zip(query_cols, dts)]
+    # of equal rows the first run's come first in the merged order
+    rows = _bitonic_rows(query, table) if side == "left" \
+        else _bitonic_rows(table, query)
+    q0 = 0 if side == "left" else n   # the queries' first row number
+    t = _bitonic_merge(rows, len(dts), rows[0].shape[0])[-1]
+    is_query = (t >= q0) & (t < q0 + m)
+    _, rank, _ = _shift_front((), is_query, _dropped_before(is_query))
+    return rank[:m]
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,7 @@ it replaced:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from dbsp_tpu.zset import cursor, kernels
@@ -193,6 +194,235 @@ def test_old_weights_ladder_matches_per_level_sum():
     ref = sum(np.asarray(_old_weights_level_impl(delta, lvl))
               for lvl in levels)
     np.testing.assert_array_equal(fused, ref)
+
+
+# ---------------------------------------------------------------------------
+# the probe's second formulation: sorted queries ranked by one merge
+# ---------------------------------------------------------------------------
+
+
+def _total_order_key(row):
+    """Sort key of a row under the order lax.sort uses: NaN greatest."""
+    return tuple((1, 0.0) if isinstance(v, float) and np.isnan(v)
+                 else (0, v) for v in row)
+
+
+def _sorted_cols(rng, n, n_dead, nk, dtype, special):
+    """``nk`` columns of ``n`` rows sorted under the total order: few
+    distinct values so rows repeat within and across operands, ``special``
+    values (NaN for floats) among them, ``n_dead`` sentinel rows."""
+    dt = np.dtype(dtype)
+    sent = kernels.sentinel_scalar(dt)
+    vals = [dt.type(v).item() for v in (-3, 0, 1, 2, 5, 9)] + list(special)
+    rows = [tuple(vals[int(rng.integers(0, len(vals)))] for _ in range(nk))
+            for _ in range(n - n_dead)] + [(sent,) * nk] * n_dead
+    rows.sort(key=_total_order_key)
+    return tuple(jnp.asarray(np.array([r[i] for r in rows], dt))
+                 for i in range(nk))
+
+
+_rank_sorted = jax.jit(kernels.rank_sorted, static_argnames=("side",))
+
+RANK_SHAPES = {  # (table rows, dead among them, queries, dead among them)
+    "m_lt_cap": (96, 20, 24, 5),
+    "m_gt_cap": (24, 5, 96, 20),
+    "empty_level": (0, 0, 24, 5),
+    "dead_level": (32, 32, 24, 5),
+}
+
+
+@pytest.mark.parametrize("shape", RANK_SHAPES)
+@pytest.mark.parametrize("dtype", ["int64", "int32", "float32"])
+@pytest.mark.parametrize("nk", [1, 2])
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_rank_sorted_equals_lex_probe(side, nk, dtype, shape,
+                                      accelerator_dispatch):
+    n, n_dead, m, m_dead = RANK_SHAPES[shape]
+    rng = np.random.default_rng(n * 7 + m + nk)
+    special = (float("nan"), float("inf")) if dtype == "float32" else ()
+    table = _sorted_cols(rng, n, n_dead, nk, dtype, special)
+    query = _sorted_cols(rng, m, m_dead, nk, dtype, special)
+    want = np.asarray(kernels.lex_probe(table, query, side))
+    got = np.asarray(_rank_sorted(table, query, side))
+    assert got.dtype == np.int32 and got.shape == (m,)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_rank_sorted_widens_a_narrow_table_as_the_search_does(
+        accelerator_dispatch):
+    table = (jnp.asarray(np.array([10, 20, 30, 40], np.int32)),)
+    query = (jnp.asarray(np.array([-(1 << 33), 25, (1 << 33) + 5],
+                                  np.int64)),)
+    for side in ("left", "right"):
+        np.testing.assert_array_equal(
+            np.asarray(_rank_sorted(table, query, side)),
+            np.asarray(kernels.lex_probe(table, query, side)))
+
+
+def _primitives(jaxpr, into=None):
+    """Names of every primitive of a jaxpr, those of its loop bodies and
+    inner programs included."""
+    into = set() if into is None else into
+    for eqn in jaxpr.eqns:
+        into.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, into)
+    return into
+
+
+def test_rank_sorted_program_streams_and_nothing_else():
+    rng = np.random.default_rng(5)
+    table = _sorted_cols(rng, 200, 40, 2, "int64", ())
+    query = _sorted_cols(rng, 50, 10, 2, "int64", ())
+    used = _primitives(jax.make_jaxpr(
+        lambda t, q: kernels.rank_sorted(t, q, "right"))(table, query).jaxpr)
+    assert "while" in used and "dynamic_slice" in used
+    banned = {p for p in used
+              if any(w in p for w in ("gather", "scatter", "sort", "cumsum"))}
+    assert not banned, banned
+    # the search it replaces is made of gathers: the walk does find them
+    assert "gather" in _primitives(jax.make_jaxpr(
+        lambda t, q: kernels._probe_search(t, q, "right"))(table,
+                                                           query).jaxpr)
+
+
+def test_rank_rule_on_the_cells_shapes():
+    """The shapes the cells' step programs probe (my chip run, PR 37, call
+    1) and what the chip measured cheaper at each (tools/probe_rates.py)."""
+    merge, search = kernels.rank_by_merge, lambda *a: not merge(*a)
+    # q4's bids delta, 65,536 lanes, in the auctions' trace: a slot of one
+    # auctions delta, the deep levels, the tail (0.28-0.62 ms against
+    # 12.2-17.9 ms by the search)
+    assert all(merge(65_536, cap, 1)
+               for cap in (4_096, 32_768, 131_072, 262_144))
+    # q4's auctions delta, 4,096 lanes, in the bids' trace: a slot merges
+    # (0.23 against 1.06 ms), the levels of millions keep the search (1.39
+    # against 5.88 ms at 2,097,152 rows)
+    assert merge(4_096, 65_536, 1)
+    assert all(search(4_096, cap, 1)
+               for cap in (524_288, 2_097_152, 4_194_304))
+    # q4's aggregate: 8,192 unique keys of two columns in the joined trace
+    assert merge(8_192, 262_144, 2) and merge(8_192, 1_048_576, 2)
+    assert search(8_192, 2_097_152, 2) and search(8_192, 4_194_304, 2)
+    # q3's joins: 1,024 and 4,096 lanes in levels of 1,024 to 32,768 rows
+    assert all(merge(m, cap, 1) for m in (1_024, 4_096)
+               for cap in (1_024, 4_096, 16_384, 32_768))
+    # measured either side of the line at 2,097,152 rows
+    assert search(16_384, 2_097_152, 1) and merge(65_536, 2_097_152, 1)
+    # a handful of lanes against a level: the search
+    assert search(64, 65_536, 2)
+
+
+def _took(before):
+    return {k for k, v in kernels.KERNEL_DISPATCH_COUNTS.items()
+            if v != before.get(k, 0)}
+
+
+def test_probe_ladder_sends_each_level_its_cheaper_way(accelerator_dispatch):
+    rng = np.random.default_rng(6)
+    caps = (1 << 15, 64, 128)  # a level of the search, two of the merge
+    m = 48
+    assert [kernels.rank_by_merge(m, c, 2) for c in caps] == \
+        [False, True, True]
+    tables = [_sorted_cols(rng, c, c // 3, 2, "int64", ()) for c in caps]
+    query = _sorted_cols(rng, m, 9, 2, "int64", ())
+    for side in ("left", "right"):
+        before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+        fused = np.asarray(cursor.lex_probe_ladder(
+            tables, query, side, sorted_queries=True))
+        counts = {k: v - before.get(k, 0)
+                  for k, v in kernels.KERNEL_DISPATCH_COUNTS.items()
+                  if k[0] == "probe_ladder" and v != before.get(k, 0)}
+        assert counts == {("probe_ladder", "xla"): 1,
+                          ("probe_ladder", "xla_merge"): 2}
+        for k, t in enumerate(tables):
+            np.testing.assert_array_equal(
+                fused[k], np.asarray(kernels.lex_probe(t, query, side)))
+    # no claim, no merge
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    cursor.lex_probe_ladder(tables, query, "left")
+    assert _took(before) == {("probe_ladder", "xla")}
+
+
+@pytest.mark.parametrize("consumer", ["join_ladder", "gather_ladder",
+                                      "agg_ladder", "old_weights_ladder"])
+def test_consumers_claim_of_sorted_queries_holds(consumer,
+                                                 accelerator_dispatch):
+    """Each consumer that states its queries are sorted gives, on random
+    consolidated deltas, what it gives without the statement (an untagged
+    delta keeps the search)."""
+    from dbsp_tpu.operators.aggregate import Max, _unique_keys_impl
+
+    rng = np.random.default_rng(7)
+    fn = lambda k, lv, rv: (k, (*lv, *rv))  # noqa: E731
+    for trial in range(3):
+        levels = _ladder(rng, allow_neg=trial != 1)
+        delta = _consolidated(rng, 12 + 9 * trial, 64)
+        check_runs(delta, consumer)
+        outs = []
+        for claim in (True, False):
+            d = delta if claim else delta.tagged(None)
+            before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+            if consumer == "join_ladder":
+                out, total = cursor.join_ladder(d, levels, 2, fn, 2048)
+                flat = (*_batch_arrays(out), np.asarray(total))
+            elif consumer == "gather_ladder":
+                qkeys, qlive = _unique_keys_impl(delta, 2)
+                (qrow, vals, w), total = cursor.gather_ladder(
+                    qkeys, qlive, levels, 2048, sorted_queries=claim)
+                flat = (qrow, *vals, w, total)
+            elif consumer == "agg_ladder":
+                out_trace = _consolidated(rng, 8, 32, nk=2, nv=1)
+                flat = [x for x in cursor._agg_ladder_stitched(
+                    d, 2, out_trace, levels, Max(0), 64, 2048, False,
+                    jnp.asarray(True)) if x is not None]
+                flat = [y for x in flat
+                        for y in (x if isinstance(x, tuple) else (x,))]
+            else:
+                flat = (cursor.old_weights_ladder(d, levels),)
+            merged = ("probe_ladder", "xla_merge") in _took(before)
+            assert merged == claim, (consumer, claim)
+            outs.append([np.asarray(x) for x in flat])
+        if consumer == "agg_ladder":
+            continue  # a fresh random out trace each way: the claim's
+            # engagement is what this case checks
+        for a, b in zip(*outs):
+            np.testing.assert_array_equal(a, b, err_msg=consumer)
+
+
+def test_ladder_steps_carry_their_scopes(accelerator_dispatch):
+    """A device trace splits a join into probe, expand and gather
+    (tools/trace_scopes.py) because each step lowers under its own name:
+    the merge inside the probe too, under the caller's scopes."""
+    rng = np.random.default_rng(9)
+    levels, delta = _ladder(rng), _consolidated(rng, 20, 32)
+    fn = lambda k, lv, rv: (k, (*lv, *rv))  # noqa: E731
+    text = jax.jit(lambda d, lv: cursor.join_ladder(d, lv, 2, fn, 256)).lower(
+        delta, levels).as_text(debug_info=True)
+    for scope in ("k.lex_probe_ladder/k.rank_sorted/while/body/",
+                  "k.expand_ladder/", "k._select_gather/"):
+        assert scope in text, scope
+
+
+def test_range_gather_never_ranks_by_merge(accelerator_dispatch):
+    rng = np.random.default_rng(8)
+    levels = _ladder(rng)
+    delta = _consolidated(rng, 24, 32)
+    qlive = delta.weights != 0
+    qhi = (delta.keys[0], delta.keys[1] + 5)
+    want = cursor.gather_ladder(delta.keys, qlive, levels, 2048,
+                                qhi_keys=qhi, gather_keys=1)
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    got = cursor.gather_ladder(delta.keys, qlive, levels, 2048, qhi_keys=qhi,
+                               gather_keys=1, sorted_queries=True)
+    assert ("probe_ladder", "xla_merge") not in _took(before)
+    (qrow, vals, w), total = got
+    (qrow0, vals0, w0), total0 = want
+    for a, b in zip((qrow, *vals, w, total), (qrow0, *vals0, w0, total0)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------

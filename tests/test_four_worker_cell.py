@@ -201,6 +201,33 @@ def test_exchange_accelerator_formulations_under_shard_map(
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_probe_by_merge_under_shard_map(accelerator_dispatch):
+    """The ladder probe of a sorted delta, each worker ranking its own
+    slice in its own levels by the merge network inside ``shard_map``: lane
+    for lane the binary search of that worker's slices."""
+    from dbsp_tpu.zset import cursor
+
+    delta = _sharded_batch(256, 150, True, seed=6)
+    levels = [_sharded_batch(1024, 700, True, seed=7),
+              _sharded_batch(128, 90, True, seed=8)]
+    assert all(kernels.rank_by_merge(256, lvl.cap, 2) for lvl in levels)
+    for side in ("left", "right"):
+        before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+        got = np.asarray(jax.jit(exchange.spmd(
+            _mesh(), lambda d, lv: cursor.lex_probe_ladder(
+                [x.keys for x in lv], d.keys, side,
+                sorted_queries=d.sorted_runs == 1)))(delta, levels))
+        took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
+                if n > before.get(k, 0) and k[0] == "probe_ladder"}
+        assert took == {("probe_ladder", "xla_merge")}
+        for w in range(W):
+            for k, lvl in enumerate(levels):
+                want = kernels._probe_search(
+                    tuple(c[w] for c in lvl.keys),
+                    tuple(c[w] for c in delta.keys), side)
+                assert np.array_equal(got[w, k], np.asarray(want)), (w, k)
+
+
 def test_gather_and_shard_batch_accelerator_formulations(accelerator_dispatch):
     """The other two boundaries of a sharded circuit under the same
     dispatch: ``shard_batch`` (input handle) places every row on the worker

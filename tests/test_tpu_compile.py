@@ -100,6 +100,9 @@ def _case(name, c):
         return (lambda t, q: kernels.lex_probe(t, q, "left")), (
             (c.shape(16 * CAP), c.shape(16 * CAP)),
             (c.shape(CAP), c.shape(CAP)))
+    if name == "rank_sorted":  # a bids delta's keys in a 262,144-row level
+        return (lambda t, q: kernels.rank_sorted(t, q, "right")), (
+            (c.shape(4 * CAP),), (c.shape(CAP),))
     if name == "join_ladder":  # q4-join: bids delta x auctions trace
         def fn(k, bv, av):
             return (k[0], av[0]), (bv[1], bv[3], av[1], av[2])
@@ -116,7 +119,8 @@ def _case(name, c):
 
 @pytest.mark.parametrize("name", [
     "consolidate", "consolidate_block", "consolidate_drain", "merge_sorted",
-    "merge_sorted_drain", "lex_probe", "join_ladder", "gather_ladder"])
+    "merge_sorted_drain", "lex_probe", "rank_sorted", "join_ladder",
+    "gather_ladder"])
 def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     fn, args = _case(name, compile_for)
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
@@ -124,8 +128,10 @@ def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     assert "tpu_custom_call" not in compiled.as_text()  # no Mosaic kernel
     took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
             if n > before.get(k, 0)}
-    assert took and {b for _, b in took} <= {
-        "xla", "xla_bitonic", "xla_shift"}, took
+    assert (took or name == "rank_sorted") and {b for _, b in took} <= {
+        "xla", "xla_bitonic", "xla_shift", "xla_merge"}, took
+    if name == "join_ladder":  # a sorted delta of its levels' order: merged
+        assert ("probe_ladder", "xla_merge") in took
 
 
 def _sort_of(n):
@@ -136,7 +142,8 @@ def _sort_of(n):
 
 
 @pytest.mark.parametrize("name", ["merge_sorted", "merge_sorted_drain",
-                                  "consolidate", "consolidate_drain"])
+                                  "rank_sorted", "consolidate",
+                                  "consolidate_drain"])
 def test_merges_of_sorted_runs_gather_nothing_on_tpu(name, compile_for):
     """Off the CPU a merge of sorted runs, the merge levels of a large
     sort, and the netting and compaction behind both are elementwise
@@ -148,7 +155,7 @@ def test_merges_of_sorted_runs_gather_nothing_on_tpu(name, compile_for):
     text = jax.jit(fn).lower(*args).as_text()
     assert "stablehlo.gather" not in text
     assert "stablehlo.scatter" not in text
-    if name.startswith("merge"):
+    if name.startswith(("merge", "rank")):
         assert "stablehlo.sort" not in text
     else:
         assert text.count("stablehlo.sort") == 1

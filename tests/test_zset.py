@@ -534,16 +534,22 @@ def test_accelerator_merge_under_shard_map(accelerator_dispatch):
 # prints in its ``kernel_dispatch`` line (PERF.md 3)
 # ---------------------------------------------------------------------------
 
-# every pair a q4 run on the chip has printed (my chip runs, PR 33)
+# every pair a q4 run on the chip has printed (my chip runs, PR 33; PR 37
+# for the probe's merge)
 CHIP_PAIRS = {
     ("agg_ladder", "xla"), ("compact", "xla_shift"), ("consolidate", "xla"),
     ("expand", "xla"), ("gather", "xla"), ("gather_ladder", "xla"),
     ("join_ladder", "xla"), ("merge", "xla_bitonic"), ("probe", "xla"),
-    ("probe_ladder", "xla"), ("rank_fold", "xla"),
-    ("segment_reduce", "xla"), ("sort_merge", "xla_bitonic")}
+    ("probe_ladder", "xla"), ("probe_ladder", "xla_merge"),
+    ("rank_fold", "xla"), ("segment_reduce", "xla"),
+    ("sort_merge", "xla_bitonic")}
 
 _NET = {("consolidate", "xla"), ("compact", "xla_shift")}
 _CHAIN = {("probe_ladder", "xla"), ("expand", "xla"), ("gather", "xla")}
+# a consumer that states its queries are sorted: at these few rows every
+# level is cheaper by the merge (kernels.rank_by_merge)
+_CHAIN_SORTED = {("probe_ladder", "xla_merge"), ("expand", "xla"),
+                 ("gather", "xla")}
 ACCELERATOR_PAIRS = {
     "consolidate_cols": _NET | {("sort_merge", "xla_bitonic")},
     "consolidate_cols_one_chunk": _NET,  # SORT_CHUNK_ROWS rows: lax.sort
@@ -554,11 +560,12 @@ ACCELERATOR_PAIRS = {
     "lex_probe": {("probe", "xla")},
     "expand_ranges": {("expand", "xla")},
     "lex_probe_ladder": {("probe_ladder", "xla")},
-    "join_ladder": _CHAIN | {("join_ladder", "xla")},
+    "join_ladder": _CHAIN_SORTED | {("join_ladder", "xla")},
     "gather_ladder": _CHAIN | {("gather_ladder", "xla")},
-    "old_weights_ladder": {("old_weights", "xla"), ("probe_ladder", "xla")},
+    "old_weights_ladder": {("old_weights", "xla"),
+                           ("probe_ladder", "xla_merge")},
     "segment_reduce": {("segment_reduce", "xla")},
-    "agg_ladder": _CHAIN | _NET | {
+    "agg_ladder": _CHAIN_SORTED | _NET | {
         ("agg_ladder", "xla"), ("gather_ladder", "xla"), ("probe", "xla"),
         ("segment_reduce", "xla")},
 }

@@ -6,7 +6,9 @@
 
 The programs carry their own names: ``CompiledHandle._run_nodes`` wraps each
 node's eval in ``jax.named_scope("n<index>.<CNode class>")``, the public
-kernels of ``zset/kernels.py`` in ``k.<kernel>``, the exchange's collectives
+kernels of ``zset/kernels.py`` and the ladder steps of ``zset/cursor.py``
+(``k.lex_probe_ladder``, ``k.expand_ladder``, ``k._select_gather``) in
+``k.<kernel>`` (the outermost one names an operation), the exchange's collectives
 in ``x.all_to_all`` / ``x.all_gather`` (``parallel/exchange.py``; counted
 with the kernels), the maintenance drains in ``maintain.drain``; XLA keeps
 the scope path in each operation's metadata and the TPU's trace keeps it
