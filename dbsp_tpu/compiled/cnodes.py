@@ -481,6 +481,13 @@ class CNode:
         that ride the requirement vector and are checked against no
         capacity (the time nodes fill ``timeseries/counters.py``)."""
 
+    def settle(self) -> bool:
+        """Hook fired after every validation that found no overflow: a node
+        whose capacities were PROVISIONAL until then (static bounds that no
+        interval can overflow) sets them from what the interval read.
+        True when a capacity changed, so the step program is traced anew."""
+        return False
+
     def eval(self, ctx, state, inputs):  # -> (state', output)
         raise NotImplementedError
 
@@ -876,14 +883,51 @@ class CTopK(CNode):
     """Incremental per-key top-K (operators/topk.py): recompute touched
     groups' top-K from the input trace view, diff against the previous
     output kept in a static out trace (k live rows per key — NOT leveled,
-    see module doc; the old gather is exact at k*q_cap)."""
+    see module doc; the old gather is exact at k*q_cap).
 
-    MONOTONE_CAPS = frozenset({"out_trace", "gather"})
+    ``gather`` holds what a tick RE-READS: the whole histories of the
+    groups the delta touched. It is bounded by the groups a tick touches
+    times the rows a group holds, so it is a PER-DELTA capacity, not a
+    monotone one, and presize does not project it as if it integrated the
+    stream. (NEXmark q6's top-1 per auction re-reads 36,782 rows in tick 0
+    and plateaus at ~120,000 from tick 10 on, as hot auctions rotate: the
+    linear projection gave it 4,194,304 rows, 3 % full, and every tick
+    sorted all of them; PERF.md 6, PR 38.) Where the histories ramp before
+    they plateau, a deployment states the plateau as a multiple of the
+    first reading (``deployment.per_delta_headroom``, as for an
+    aggregate's ``queries``). ``out`` is the output delta's own capacity:
+    the rows whose top-K changed — at most k retracted and k inserted per
+    touched key — requirement-checked like ``CWindow``'s, so every node
+    downstream is sized by what changed and not by the gather.
+
+    Until an interval has validated, the three are PROVISIONAL: each is the
+    static bound of what it can hold — ``queries`` the delta's width,
+    ``gather`` the input trace's summed level capacities, ``out`` the new
+    part's and the old part's widths, bucketed — so none can overflow, and the first
+    trace of a tick whose upstream has grown reads them all exactly. On a
+    capacity seeded small instead, each replay of the first tick read a
+    truncated delta and uncovered the next overflow downstream (q6's tick
+    0 traced seven step programs, one per seed uncovered; PERF.md 7, fault
+    13). :meth:`settle` then sets each to twice its first reading, as
+    ``grow`` would have.
+
+    What a tick did rides the requirement vector (``_Ctx.observe``):
+    groups touched, rows re-read, rows inserted and retracted; validation
+    hands them to ``timeseries/counters.py``."""
+
+    MONOTONE_CAPS = frozenset({"out_trace"})
 
     def __init__(self, node, op):
         super().__init__(node, op)
         self.caps["gather"] = 0
         self.caps["out_trace"] = 0
+        self.caps["out"] = 0
+        # what the last validated tick observed (note_observations)
+        self.observed: Dict[str, int] = {}
+        # the last requirement read of each capacity (note_requirement),
+        # and whether the provisional capacities have been set from them
+        self._read: Dict[str, int] = {}
+        self._settled = False
 
     def init_state(self):
         migrated = _migrate_spine(self.op.out_spine)
@@ -903,24 +947,63 @@ class CTopK(CNode):
         view: CView = inputs[0]
         nk = len(self.op.schema[0])
         delta = view.delta
+        if not self._settled:  # provisional: the static bounds (docstring)
+            self.caps["queries"] = delta.cap
+            self.caps["gather"] = sum(lvl.cap for lvl in view.post)
         qkeys, qlive = _unique_keys_impl(delta, nk)
         qkeys, qlive = trim_queries(ctx, self, qkeys, qlive)
         q_cap = qlive.shape[-1]
-        if not self.caps["gather"]:
-            self.caps["gather"] = max(64, 2 * q_cap)
 
         g, gtot = gather_levels(qkeys, qlive, view.post, self.caps["gather"])
         ctx.require(self, "gather", gtot)
         new_part = _topk_rows(g[0], qkeys, g[1], g[2], self.op.k,
                               self.op.largest, 1, q_cap)
-        # the consolidated out trace holds <= k live rows per key: exact cap
-        o = _gather_level_impl(qkeys, qlive, state, self.op.k * q_cap)[:3]
+        # each part holds at most k rows a touched key, and no more rows
+        # than it was selected from: the gather's, or the consolidated out
+        # trace's (<= k live rows a key). Both widths are exact
+        kq = self.op.k * q_cap
+        new_cap, old_cap = min(kq, self.caps["gather"]), min(kq, state.cap)
+        o = _gather_level_impl(qkeys, qlive, state, old_cap)[:3]
         old_part = _topk_rows(o[0], qkeys, o[1], o[2], self.op.k,
                               self.op.largest, -1, q_cap)
-        out = concat_batches([new_part, old_part]).consolidate()
+        # each part is one sorted run (by query slot, so by key, then
+        # value): cut to its width, the two are merged, not sorted as the
+        # gather's width. Consolidated, the live rows stand first: the
+        # capacity handed on is the output's own
+        out = concat_batches([new_part.with_cap(new_cap).tagged((new_cap,)),
+                              old_part.tagged((old_cap,))]).consolidate()
+        if not self._settled:  # on the power-of-two ladder, as its seed was
+            self.caps["out"] = bucket_cap(new_cap + old_cap)
+        ctx.require(self, "out", out.live_count())
+        out = out.with_cap(self.caps["out"])
         state2, required = static_append(state, out)
         ctx.require(self, "out_trace", required)
+        ctx.observe(self, "groups", jnp.sum(qlive))
+        ctx.observe(self, "gathered", gtot)
+        ctx.observe(self, "inserted", jnp.sum(out.weights > 0))
+        ctx.observe(self, "retracted", jnp.sum(out.weights < 0))
         return state2, out
+
+    def note_requirement(self, key: str, required: int) -> None:
+        self._read[key] = required
+
+    def settle(self) -> bool:
+        if self._settled:
+            return False
+        self._settled = True
+        for key in ("queries", "gather", "out"):
+            self.caps[key] = bucket_cap(max(64, 2 * self._read.get(key, 0)))
+        return True
+
+    def note_observations(self, values: Dict[str, int]) -> None:
+        from dbsp_tpu.timeseries import counters
+
+        self.observed = dict(values)
+        counters.note_topk(self.node.index, values["groups"],
+                           values["gathered"], self.caps["gather"],
+                           values["inserted"], values["retracted"],
+                           self.caps["queries"], self.op.k,
+                           len(self.op.schema[1]))
 
 
 class CDistinct(CNode):

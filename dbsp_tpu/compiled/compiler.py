@@ -328,15 +328,27 @@ class CompiledHandle:
             if isinstance(cn, cnodes.CTrace) and \
                     cn.node.index in self._windowed:
                 cn._counts_lives = True
-                # ... and keep the merged level 0: half of a delta cancels
-                # rows level 0 already holds, and a merge nets them at
-                # once. Slots also pin their size at the FIRST trace, when
-                # a producer's capacity is still its seed (an aggregate's
-                # 64 ``queries``: a delta of 128); once that has grown no
-                # delta matches the pin, every tick sorts level 0 on the
-                # fallback path and consumers probe it as cap / 128 slot
-                # runs (q5's by_window trace: 2,048 of them, and 262 s of
-                # a step program's compile for a v5e in that trace alone)
+        # A slotted level 0 pins its slot size at the FIRST trace. Behind a
+        # producer whose output capacity is still its SEED then, and is
+        # sized later by what validation reads (an aggregate's
+        # ``queries``: a delta of 128; a top-K's or a window's ``out``),
+        # the pin goes stale once presize or grow has raised it: no delta
+        # matches it, every tick sorts level 0 on the fallback path and
+        # consumers probe it as cap / 128 slot runs (q5's by_window trace:
+        # 2,048 of them, and 262 s of a step program's compile for a v5e
+        # in that trace alone; PERF.md 7, fault 12). Every trace downstream
+        # of one keeps the merged level 0 — a windowed view's traces among
+        # them, where half of a delta cancels rows level 0 already holds
+        # and a merge nets them at once; NEXmark q6's top-10 input trace,
+        # behind the top-1 per auction.
+        seeded: set = set()
+        for cn in self.cnodes:
+            if isinstance(cn, (cnodes.CAggregate, cnodes.CLinearAggregate,
+                               cnodes.CTopK, cnodes.CWindow)) or \
+                    any(i in seeded for i in cn.node.inputs):
+                seeded.add(cn.node.index)
+        for cn in self.cnodes:
+            if isinstance(cn, cnodes.CTrace) and cn.node.index in seeded:
                 cn._no_slots = True
         # map host InputHandle ops -> node indices (for feeds dicts)
         self._op_to_index = {id(n.operator): n.index for n in self.order}
@@ -1243,6 +1255,9 @@ class CompiledHandle:
             seen.setdefault(cn, {})[key] = int(v)
         for cn, values in seen.items():
             cn.note_observations(values)
+        if any([cn.settle() for cn in self.cnodes]):
+            self._step_jit = None  # provisional capacities were set
+            self._scan_jits = {}
 
     def _req_value(self, cn: CNode, key: str) -> Optional[int]:
         """The last validated requirement for (cn, key), if any."""
@@ -1512,7 +1527,7 @@ class CompiledHandle:
         # levels under headroom — every transition logged with its cause
         changed |= self._enforce_residency(cause="budget")
         if self._observed:
-            self._record_time_tick()
+            self._record_tick()
         if stats["rows_moved"] > rows_before:
             self._note_cause("maintain")
         if changed:
@@ -1526,29 +1541,57 @@ class CompiledHandle:
         interval (``timeseries/counters.py`` has them per node): the args
         of the driver's ``tick.validate`` span and, with the traces' rows,
         a record of ``VALIDATED_TICKS``. Empty without time nodes."""
-        if not self._observed:
-            return {}
         nodes = {cn for cn, _ in self._observed}
         wins = [cn for cn in nodes if isinstance(cn, cnodes.CWindow)]
         marks = [cn.watermark_ms for cn in nodes
                  if isinstance(cn, cnodes.CWatermark)]
+        if not wins and not marks:
+            return {}
         return {"retired_rows": sum(cn.slid_last["out"] for cn in wins),
                 "slid_in_rows": sum(cn.slid_last["in"] for cn in wins),
                 "watermark_ms": max(marks, default=0)}
 
-    def _record_time_tick(self) -> None:
+    def topk_facts(self) -> Dict[str, int]:
+        """What this circuit's top-K nodes observed in the last validated
+        interval, summed over them (``timeseries/counters.py`` has them
+        per node): groups touched, rows re-read from their input traces
+        against the gathers' capacity, rows inserted and retracted. Empty
+        without a ``CTopK``."""
+        tops = [cn for cn in self.cnodes
+                if isinstance(cn, cnodes.CTopK) and cn.observed]
+        if not tops:
+            return {}
+        return {
+            "topk_groups": sum(cn.observed["groups"] for cn in tops),
+            "topk_gathered_rows": sum(cn.observed["gathered"]
+                                      for cn in tops),
+            "topk_gather_capacity_rows": sum(cn.caps["gather"]
+                                             for cn in tops),
+            "topk_inserted_rows": sum(cn.observed["inserted"]
+                                      for cn in tops),
+            "topk_retracted_rows": sum(cn.observed["retracted"]
+                                       for cn in tops)}
+
+    def _record_tick(self) -> None:
+        """One record of ``VALIDATED_TICKS`` for a circuit with time nodes
+        or top-K nodes: the facts of each kind it has."""
         from dbsp_tpu.timeseries import counters
 
-        traces = [cn for cn in self.cnodes
-                  if getattr(cn, "_counts_lives", False)]
-        gcd = [counters.TRACE_GC_ROWS.get(cn.node.index, {})
-               for cn in traces if getattr(cn, "_gc_refresh", False)]
-        counters.VALIDATED_TICKS.append({
-            **self.time_facts(),
-            "gc_live_rows": sum(g.get("live", 0) for g in gcd),
-            "gc_capacity_rows": sum(g.get("capacity", 0) for g in gcd),
-            "gc_truncated_rows": sum(g.get("truncated", 0) for g in gcd),
-            "trace_live_rows": sum(cn.live_rows for cn in traces)})
+        record = self.time_facts()
+        if record:
+            traces = [cn for cn in self.cnodes
+                      if getattr(cn, "_counts_lives", False)]
+            gcd = [counters.TRACE_GC_ROWS.get(cn.node.index, {})
+                   for cn in traces if getattr(cn, "_gc_refresh", False)]
+            record.update({
+                "gc_live_rows": sum(g.get("live", 0) for g in gcd),
+                "gc_capacity_rows": sum(g.get("capacity", 0) for g in gcd),
+                "gc_truncated_rows": sum(g.get("truncated", 0)
+                                         for g in gcd),
+                "trace_live_rows": sum(cn.live_rows for cn in traces)})
+        record.update(self.topk_facts())
+        if record:
+            counters.VALIDATED_TICKS.append(record)
 
     def _windows_filling(self) -> bool:
         """True while a window of this circuit's windowed view has yet to

@@ -220,6 +220,9 @@ class CompiledCircuitDriver:
             if facts:
                 sp.note(retired_rows=facts["retired_rows"],
                         watermark_ms=facts["watermark_ms"])
+            tops = self.ch.topk_facts()  # of a circuit with top-K nodes
+            if tops:
+                sp.note(topk_gathered_rows=tops["topk_gathered_rows"])
         self.ch.host_overhead_ns["validate"].append(sp.elapsed_ns)
         stats = self.ch.maintain_stats
         drains0 = stats["drains"] + stats["partial_drains"]

@@ -283,6 +283,14 @@ def q6(persons: Stream, auctions: Stream, bids: Stream) -> Stream:
     return prices.aggregate(Average(0), name="q6-avg")
 
 
+#: q6 under NEXmark's own name for it ("Average Selling Price by Seller").
+#: The served deployment ``nexmark-q6`` (benchmark/configs) asks for the
+#: query by this name, new in PR 38: a tree from before it can build q6 but
+#: sizes its top-K nodes' buffers from a projection of the whole stream
+#: (PERF.md 6, PR 38) and, asked for a name it lacks, fails at once instead.
+average_selling_price_by_seller = q6
+
+
 # ---------------------------------------------------------------------------
 # q12-q22
 # ---------------------------------------------------------------------------

@@ -205,6 +205,45 @@ def export_time_counters(registry: MetricsRegistry) -> None:
     registry.register_collector(_collect)
 
 
+def export_topk_counters(registry: MetricsRegistry) -> None:
+    """Register a collector mirroring the top-K nodes' process-wide
+    counters (``timeseries/counters.py`` ``TOPK_ROWS``, filled at
+    validation): per ``CTopK`` the rows it re-read from its input trace
+    (``dbsp_tpu_topk_gathered_rows_total{node}``), the groups it touched
+    (``dbsp_tpu_topk_groups_total{node}``), the rows it inserted and
+    retracted (``dbsp_tpu_topk_changed_rows_total{node}``) and the
+    capacity of its gather (``dbsp_tpu_topk_gather_capacity_rows{node}``)."""
+    if getattr(registry, "_topk_counters_exported", False):
+        return
+    registry._topk_counters_exported = True
+    gathered = registry.counter(
+        "dbsp_tpu_topk_gathered_rows_total",
+        "Rows a top-K node re-read from its input trace: the whole "
+        "histories of the groups each tick touched", labels=("node",))
+    groups = registry.counter(
+        "dbsp_tpu_topk_groups_total",
+        "Groups whose top-K a top-K node recomputed", labels=("node",))
+    changed = registry.counter(
+        "dbsp_tpu_topk_changed_rows_total",
+        "Rows a top-K node emitted, insertions and retractions",
+        labels=("node",))
+    capacity = registry.gauge(
+        "dbsp_tpu_topk_gather_capacity_rows",
+        "Capacity of a top-K node's gather: the buffer its re-read "
+        "histories are sorted in every tick", labels=("node",))
+
+    def _collect() -> None:
+        from dbsp_tpu.timeseries import counters
+
+        for node, ent in list(counters.TOPK_ROWS.items()):
+            gathered.labels(node=str(node)).set_total(ent["gathered_total"])
+            groups.labels(node=str(node)).set_total(ent["groups_total"])
+            changed.labels(node=str(node)).set_total(ent["changed_total"])
+            capacity.labels(node=str(node)).set(ent["capacity"])
+
+    registry.register_collector(_collect)
+
+
 def _gid_str(gid: Tuple[int, ...]) -> str:
     return ".".join(map(str, gid))
 
@@ -270,6 +309,7 @@ class CircuitInstrumentation:
         export_kernel_dispatch(registry)
         export_exchange_overflows(registry)
         export_time_counters(registry)
+        export_topk_counters(registry)
         circuit.register_scheduler_event_handler(self._on_event)
         # mark exchange operators so they accumulate rows/bytes moved —
         # this costs one scalar device->host sync per exchange per tick
@@ -462,6 +502,7 @@ class CompiledInstrumentation:
         export_kernel_dispatch(registry)
         export_exchange_overflows(registry)
         export_time_counters(registry)
+        export_topk_counters(registry)
         if spans is not None:
             driver.spans = spans  # driver records tick/validate spans
 

@@ -38,13 +38,16 @@ from dbsp_tpu.zset import kernels
 from dbsp_tpu.zset.batch import Batch, concat_batches
 
 
-def _topk_rows_impl(qrow, qkeys, val_cols, w, k: int, largest: bool,
-                    weight_sign: int, q_cap: int) -> Batch:
+@kernels._scoped
+def topk_rows(qrow, qkeys, val_cols, w, k: int, largest: bool,
+              weight_sign: int, q_cap: int) -> Batch:
     """Select the top-K present rows per q segment; emit with ±1 weights.
 
     Segment ids are query-slot indices in [0, q_cap) — sized by q_cap (like
     aggregate's _reduce_groups), NOT by the gathered-row count, which can be
-    smaller when the gather capacity cache was trained on denser deltas."""
+    smaller when the gather capacity cache was trained on denser deltas.
+    Runs under the scope ``k.topk_rows``: a device trace names it inside
+    the compiled ``CTopK``'s node scope."""
     cols, w = kernels.consolidate_cols((qrow, *val_cols), w)
     qrow, val_cols = cols[0], cols[1:]
     present = w > 0
@@ -73,13 +76,13 @@ def _topk_rows_impl(qrow, qkeys, val_cols, w, k: int, largest: bool,
     return Batch(out_cols[:nk], out_cols[nk:], out_w)
 
 
-_topk_rows_jit = jax.jit(_topk_rows_impl,
+_topk_rows_jit = jax.jit(topk_rows,
                          static_argnames=("k", "largest", "weight_sign",
                                           "q_cap"))
 
 
 def _topk_rows_factory(k: int, largest: bool, weight_sign: int, q_cap: int):
-    return lambda qrow, qkeys, val_cols, w: _topk_rows_impl(
+    return lambda qrow, qkeys, val_cols, w: topk_rows(
         qrow, qkeys, val_cols, w, k, largest, weight_sign, q_cap)
 
 

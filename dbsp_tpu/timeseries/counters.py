@@ -1,5 +1,6 @@
 """Process-wide counters of the time nodes of compiled circuits: what the
-windows slid, what the trace-bound GC truncated, where the watermarks stand.
+windows slid, what the trace-bound GC truncated, where the watermarks stand;
+and of their top-K nodes: what each re-read and changed (since PR 38).
 
 Filled at validation from scalars that ride the requirement vector the
 handle fetches anyway (``compiler._Ctx.observe``): no device sync of their
@@ -9,7 +10,11 @@ cadence above one, "the last tick" is the largest tick of the interval.
 Exported by ``obs/instrument.py::export_time_counters`` as
 ``dbsp_tpu_window_slide_rows_total{node,dir}``,
 ``dbsp_tpu_trace_gc_rows_total{node}``, ``dbsp_tpu_trace_gc_live_rows{node}``
-and ``dbsp_tpu_watermark_ms{node}``. Empty for a circuit without time nodes.
+and ``dbsp_tpu_watermark_ms{node}``; by ``export_topk_counters`` as
+``dbsp_tpu_topk_gathered_rows_total{node}``, ``dbsp_tpu_topk_groups_total``,
+``dbsp_tpu_topk_changed_rows_total`` and
+``dbsp_tpu_topk_gather_capacity_rows``. Empty for a circuit without such
+nodes.
 """
 
 from __future__ import annotations
@@ -30,12 +35,21 @@ TRACE_GC_ROWS: Dict[int, Dict[str, int]] = {}
 # CWatermark node -> {"ms": the watermark, "advance": over the last tick}
 WATERMARK_MS: Dict[int, Dict[str, int]] = {}
 
-# one record per validated interval of a circuit with time nodes, oldest
-# first, bounded (``CompiledHandle.maintain`` appends it: the sums over
-# that circuit's nodes): ``retired_rows`` / ``slid_in_rows`` (windows),
-# ``gc_live_rows`` / ``gc_capacity_rows`` / ``gc_truncated_rows`` (traces
-# under a GC bound), ``trace_live_rows`` (every leveled trace of the
-# windowed view, counted in the step program), ``watermark_ms``
+# CTopK node -> {"groups": touched, "gathered": rows re-read from its input
+# trace, "capacity": of its gather, "inserted" / "retracted": rows it
+# emitted} of the last validated tick, and the running "*_total" of each;
+# with the node's shape as it ran that tick: "queries" (capacity), "k" and
+# "values" (value columns)
+TOPK_ROWS: Dict[int, Dict[str, int]] = {}
+
+# one record per validated interval of a circuit with time nodes or top-K
+# nodes, oldest first, bounded (``CompiledHandle.maintain`` appends it: the
+# sums over that circuit's nodes): ``retired_rows`` / ``slid_in_rows``
+# (windows), ``gc_live_rows`` / ``gc_capacity_rows`` / ``gc_truncated_rows``
+# (traces under a GC bound), ``trace_live_rows`` (every leveled trace of the
+# windowed view, counted in the step program), ``watermark_ms``;
+# ``topk_groups`` / ``topk_gathered_rows`` / ``topk_gather_capacity_rows``
+# / ``topk_inserted_rows`` / ``topk_retracted_rows`` (top-K nodes)
 VALIDATED_TICKS: collections.deque = collections.deque(maxlen=4096)
 
 
@@ -57,3 +71,17 @@ def note_watermark(node: int, ms: int) -> None:
     before = WATERMARK_MS.get(node, {}).get("ms", 0)
     WATERMARK_MS[node] = {"ms": ms,
                           "advance": ms - before if before else 0}
+
+
+def note_topk(node: int, groups: int, gathered: int, capacity: int,
+              inserted: int, retracted: int, queries: int, k: int,
+              values: int) -> None:
+    ent = TOPK_ROWS.setdefault(node, {"groups_total": 0,
+                                      "gathered_total": 0,
+                                      "changed_total": 0})
+    ent.update(groups=groups, gathered=gathered, capacity=capacity,
+               inserted=inserted, retracted=retracted, queries=queries, k=k,
+               values=values)
+    ent["groups_total"] += groups
+    ent["gathered_total"] += gathered
+    ent["changed_total"] += inserted + retracted

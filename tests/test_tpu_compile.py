@@ -134,6 +134,30 @@ def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
         assert ("probe_ladder", "xla_merge") in took
 
 
+def test_topk_rows_compiles_for_v5e_at_the_q6_cells_capacities(compile_for):
+    """The top-1 per auction's selection (``operators.topk.topk_rows``: the
+    sort of the re-read histories by group and value, the per-group ranks,
+    the compaction of the kept rows) at the capacities the cell
+    ``nexmark-q6.saturated`` reaches: 262,144 gathered rows and 16,384
+    queries (``benchmark/metrics/topk_rows_roofline.py``). Plain XLA, no
+    Mosaic call; 11.4 s to compile here (PR 38)."""
+    import time
+
+    from dbsp_tpu.operators.topk import topk_rows
+
+    gather, queries = 262_144, 16_384
+    args = (compile_for.shape(gather, I32), (compile_for.shape(queries),),
+            tuple(compile_for.shape(gather) for _ in range(5)),
+            compile_for.shape(gather))
+    t0 = time.monotonic()
+    compiled = compile_for(lambda q, k, v, w: topk_rows(
+        q, k, v, w, 1, True, 1, queries), *args)
+    seconds = time.monotonic() - t0
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text  # no Mosaic kernel
+    assert seconds < 240, seconds
+
+
 def _sort_of(n):
     """The signature a sort of ``n`` bids rows prints after its comparator."""
     operands = ", ".join(f"tensor<{n}x{'i32' if d == I32 else 'i64'}>"
