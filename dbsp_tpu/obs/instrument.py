@@ -88,7 +88,9 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
         "a sort of more than SORT_CHUNK_ROWS rows; xla_shift = the shift "
         "compaction behind kernel=compact; xla_merge = a level of "
         "kernel=probe_ladder in which sorted queries were ranked by one "
-        "merge, its xla rows the levels searched); the fused ladder-consumer "
+        "merge, its xla rows the levels searched; xla_flat = a "
+        "kernel=gather from the levels laid end to end, its xla rows one "
+        "gather a level); the fused ladder-consumer "
         "megakernels report as kernel=join_ladder / gather_ladder / "
         "old_weights and the reduction offensive as kernel=segment_reduce "
         "/ agg_ladder / join_sorted, whose xla rows are the stitched-chain "

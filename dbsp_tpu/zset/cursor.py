@@ -34,6 +34,12 @@ This module collapses that fan-out (the engine's answer to the reference's
   query row, source row) through a single searchsorted over the cross-level
   prefix sums. Level-major order, so the output layout matches the old
   offset-scatter scheme exactly.
+* :func:`_select_gather` — each slot's cell of every gathered column, the
+  weights with the values in one call. On an accelerator one of two
+  formulations, the same cells bit for bit: one clamped gather per level
+  per column and a select, or — where ``kernels.gather_flat`` prices it
+  cheaper from the shapes (a wide gather from a deep ladder) — one gather
+  a column from the levels laid end to end.
 * :func:`join_ladder` / :func:`gather_ladder` / :func:`old_weights_ladder`
   — the three hot consumers (incremental join, aggregate group gather,
   distinct old-weight lookup) as single fused kernels over the ladder.
@@ -167,13 +173,13 @@ def expand_ladder(lo: jnp.ndarray, hi: jnp.ndarray, out_cap: int):
 def _select_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
                    src: jnp.ndarray) -> Cols:
     """Gather column values from the level each output slot resolved to:
-    one clamped gather per level per column, combined by level-id select
-    (no scatters, no per-level buffers). On CPU with the native library the
-    whole select tree is ONE C++ pass reading exactly the (level, src) cell
-    each slot resolved to (ZsetGatherImpl) — bit-identical values, clamped
-    reads on dead slots included."""
-    if not cols_per_level[0]:
-        return ()
+    the cell ``(level, clip(src, 0, cap[level] - 1))`` of every column, so
+    dead slots read a clamped cell too. Three formulations, bit-identical:
+    on CPU with the native library ONE C++ pass (ZsetGatherImpl); on an
+    accelerator where :func:`kernels.gather_flat` prices it cheaper, one
+    gather a column from the levels laid end to end
+    (:func:`_flat_gather`); else one clamped gather per level per column,
+    combined by level-id select (no scatters, no per-level buffers)."""
     if level.ndim == 1 and kernels.native_kernel("gather"):
         from dbsp_tpu.zset import native_merge
 
@@ -182,7 +188,19 @@ def _select_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
             kernels.count_kernel_dispatch("gather", "native")
             return native_merge.gather_levels_native(cols_per_level, level,
                                                      src)
+    caps = [cols[0].shape[0] for cols in cols_per_level]
+    if kernels.accelerator() and kernels.gather_flat(
+            level.shape[0], caps, len(cols_per_level[0])):
+        kernels.count_kernel_dispatch("gather", "xla_flat")
+        return _flat_gather(cols_per_level, level, src)
     kernels.count_kernel_dispatch("gather", "xla")
+    return _level_gather(cols_per_level, level, src)
+
+
+def _level_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
+                  src: jnp.ndarray) -> Cols:
+    """One clamped gather per level per column, combined by level-id
+    select: a slot whose level is out of range keeps level 0's cell."""
     outs: List[jnp.ndarray] = []
     for ci in range(len(cols_per_level[0])):
         acc = None
@@ -192,6 +210,33 @@ def _select_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
             acc = v if acc is None else jnp.where(level == k, v, acc)
         outs.append(acc)
     return tuple(outs)
+
+
+def _flat_gather(cols_per_level: Sequence[Cols], level: jnp.ndarray,
+                 src: jnp.ndarray) -> Cols:
+    """The accelerator's flat gather: each column's levels concatenated
+    along the row axis, then ONE gather at ``base[level] + clip(src, 0,
+    cap[level] - 1)``, where ``base`` are the static offsets of the levels
+    in the concatenation. ``base`` and ``cap`` are picked per slot by an
+    int32 select over the levels, in the per-level form's order (a slot
+    whose level is out of range reads level 0), so each slot reads the
+    cell that form selects. The concatenation is a materialized buffer,
+    which the gather reads once a slot."""
+    caps = [cols[0].shape[0] for cols in cols_per_level]
+    assert sum(caps) < 2 ** 31, "flat gather: offsets overflow int32"
+    offsets = [0]
+    for cap in caps[:-1]:
+        offsets.append(offsets[-1] + cap)
+    base = jnp.full(level.shape, offsets[0], jnp.int32)
+    hi = jnp.full(level.shape, caps[0] - 1, jnp.int32)
+    for k in range(1, len(caps)):
+        base = jnp.where(level == k, offsets[k], base)
+        hi = jnp.where(level == k, caps[k] - 1, hi)
+    g = base + jnp.clip(src.astype(jnp.int32), 0, hi)
+    return tuple(
+        jnp.concatenate([cols[ci] for cols in cols_per_level]).at[g].get(
+            mode="promise_in_bounds", wrap_negative_indices=False)
+        for ci in range(len(cols_per_level[0])))
 
 
 # ---------------------------------------------------------------------------
@@ -282,12 +327,12 @@ def join_ladder(delta: Batch, levels: Sequence[Batch], nk: int, fn,
     lo = jnp.where(live[None, :], lo, 0)
     hi = jnp.where(live[None, :], hi, lo)
     level, qrow, src, valid, total = expand_ladder(lo, hi, out_cap)
-    (lw,) = _select_gather([(lvl.weights,) for lvl in levels], level, src)
+    lw, *rvals = _select_gather([(lvl.weights, *lvl.vals) for lvl in levels],
+                                level, src)
     w = jnp.where(valid, delta.weights[qrow] * lw, 0)
     key_cols = tuple(c[qrow] for c in dk)
     lvals = tuple(c[qrow] for c in delta.vals)
-    rvals = _select_gather([lvl.vals for lvl in levels], level, src)
-    return _finish_join(fn, key_cols, lvals, rvals, w, valid, total)
+    return _finish_join(fn, key_cols, lvals, tuple(rvals), w, valid, total)
 
 
 def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
@@ -348,12 +393,12 @@ def gather_ladder(qkeys: Cols, qlive: jnp.ndarray, levels: Sequence[Batch],
     # with qhi_keys=None hi >= lo always holds and the clamp is a no-op
     hi = jnp.where(qlive[None, :], jnp.maximum(hi, lo), lo)
     level, qrow, src, valid, total = expand_ladder(lo, hi, out_cap)
-    (lw,) = _select_gather([(lvl.weights,) for lvl in levels], level, src)
+    lw, *gathered = _select_gather(
+        [(lvl.weights, *lvl.keys[nk - gather_keys:nk], *lvl.vals)
+         for lvl in levels], level, src)
     w = jnp.where(valid, lw, 0)
-    gcols = [(*lvl.keys[nk - gather_keys:nk], *lvl.vals) for lvl in levels] \
-        if gather_keys else [lvl.vals for lvl in levels]
     vals = tuple(jnp.where(valid, v, kernels.sentinel_for(v.dtype))
-                 for v in _select_gather(gcols, level, src))
+                 for v in gathered)
     qrow = jnp.where(valid, qrow, jnp.int32(q_cap)).astype(jnp.int32)
     return (qrow, vals, w), total
 

@@ -78,7 +78,8 @@ def count_consolidate_path(path: str) -> None:
 # not the CPU's, its name: "xla_bitonic" (the merge network behind "merge"
 # and "sort_merge", a sort of more than SORT_CHUNK_ROWS rows) and
 # "xla_shift" (the shift compaction of "compact"), "xla_merge" (a level of
-# "probe_ladder" ranked by one merge; that kernel counts per LEVEL).
+# "probe_ladder" ranked by one merge; that kernel counts per LEVEL),
+# "xla_flat" (a "gather" from the levels laid end to end).
 # Same counting convention as CONSOLIDATE_COUNTS (eager calls per eval,
 # traced calls per trace); exported by obs as
 # ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` and embedded in
@@ -131,6 +132,37 @@ def rank_by_merge(m: int, cap: int, nk: int) -> bool:
     total = 1 << (cap + m - 1).bit_length()
     merge = (total.bit_length() - 1) * total * (nk + 4) * PROBE_PASS_NS
     return merge < search
+
+
+# The rate of the copy behind :func:`gather_flat`, read on a TPU v5 lite
+# with each form of the ladder gather alone and warm
+# (``tools/probe_rates.py``; PERF.md 6): laying levels of 5,570,560 rows in
+# all end to end costs 0.029 ns a row a column inside the flat form (0.98
+# ms for six int64 columns at 64 slots, the gather a few us of it; the
+# concatenation timed alone, its outputs written to buffers of their own,
+# 0.042). A gathered element costs either form about PROBE_GATHER_NS:
+# 13.5-17 ns from 16,384 to 262,144 slots. Below that the per-level form
+# also pays ~20 us a gather that the rule leaves unpriced, so from 2,048
+# to 3,072 slots against those levels it keeps the per-level form where
+# the flat one is up to 0.35 ms faster.
+GATHER_COPY_NS = 0.029
+
+
+def gather_flat(out_cap: int, caps: Sequence[int], ncols: int) -> bool:
+    """Is gathering ``out_cap`` slots of ``ncols`` columns from a ladder of
+    levels with capacities ``caps`` cheaper from the levels laid end to end
+    (one gather a column from their concatenation) than by one clamped
+    gather per level a column, on an accelerator? A static cost of the
+    shapes and nothing else, priced by ``PROBE_GATHER_NS`` and
+    ``GATHER_COPY_NS``: the per-level form gathers ``len(caps) * out_cap``
+    elements a column; the flat form gathers ``out_cap`` and first copies
+    every level's rows into the concatenation. So wide gathers from a deep
+    ladder take the flat form, and a few hundred lanes against levels of
+    millions of rows — or a single level — keep the per-level one."""
+    per_level = len(caps) * out_cap * ncols * PROBE_GATHER_NS
+    flat = (out_cap * ncols * PROBE_GATHER_NS
+            + sum(caps) * ncols * GATHER_COPY_NS)
+    return flat < per_level
 
 
 def native_kernel(kernel: str) -> bool:

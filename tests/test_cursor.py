@@ -17,6 +17,8 @@ it replaced:
   (the dryrun_multichip path) via the sharded host join.
 """
 
+from collections import Counter
+
 import numpy as np
 import pytest
 
@@ -260,11 +262,11 @@ def test_rank_sorted_widens_a_narrow_table_as_the_search_does(
 
 
 def _primitives(jaxpr, into=None):
-    """Names of every primitive of a jaxpr, those of its loop bodies and
+    """How often each primitive occurs in a jaxpr, its loop bodies and
     inner programs included."""
-    into = set() if into is None else into
+    into = Counter() if into is None else into
     for eqn in jaxpr.eqns:
-        into.add(eqn.primitive.name)
+        into[eqn.primitive.name] += 1
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (tuple, list)) else (v,)):
                 inner = getattr(sub, "jaxpr", sub)
@@ -423,6 +425,142 @@ def test_range_gather_never_ranks_by_merge(accelerator_dispatch):
     (qrow0, vals0, w0), total0 = want
     for a, b in zip((qrow, *vals, w, total), (qrow0, *vals0, w0, total0)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# the gather's second formulation: one gather from the levels laid end to end
+# ---------------------------------------------------------------------------
+
+
+def _mixed(rng, n_live, cap, key_range=30):
+    """A consolidated batch of two int64 keys and an int32 and a bool
+    value column."""
+    k = [rng.integers(0, key_range, n_live) for _ in range(2)]
+    v32 = rng.integers(-5, 5, n_live).astype(np.int32)
+    vb = rng.integers(0, 2, n_live).astype(bool)
+    w = rng.integers(1, 3, n_live).astype(np.int64)
+    return Batch.from_columns([jnp.asarray(c) for c in k],
+                              [jnp.asarray(v32), jnp.asarray(vb)],
+                              jnp.asarray(w), cap=cap)
+
+
+def _all_dead(b: Batch) -> Batch:
+    """``b`` with every weight 0 and its keys kept: probes still match its
+    rows, and the gather reads weight 0 and their values."""
+    return Batch(b.keys, b.vals, jnp.zeros_like(b.weights), b.runs)
+
+
+def _select_case(k):
+    """``_select_gather`` alone: K levels of mixed capacities (one of a
+    single row at K = 5), int64, int32 and bool columns, slots spread over
+    the levels with ``src`` below twice the largest capacity, so most slots
+    of the smaller levels read past their level's end."""
+    caps = {1: (48,), 2: (64, 16), 3: (256, 8, 32), 4: (256, 64, 32, 16),
+            5: (512, 128, 1, 64, 16)}[k]
+    rng = np.random.default_rng(40 + k)
+    levels = [(jnp.asarray(rng.integers(-9, 9, c)),
+               jnp.asarray(rng.integers(-9, 9, c).astype(np.int32)),
+               jnp.asarray(rng.integers(0, 2, c).astype(bool)))
+              for c in caps]
+    level = jnp.asarray(rng.integers(0, k, 200).astype(np.int32))
+    src = jnp.asarray(rng.integers(0, 2 * max(caps), 200).astype(np.int32))
+    return lambda: cursor._select_gather(levels, level, src)
+
+
+def _ladder_case(case):
+    rng = np.random.default_rng(50)
+    fn = lambda k, lv, rv: (k, (*lv, *rv))  # noqa: E731
+    if case == "join_empty_and_dead_levels":
+        levels = (_mixed(rng, 90, 256), Batch.empty(
+            (jnp.int64, jnp.int64), (jnp.int32, jnp.bool_), cap=64),
+            _all_dead(_mixed(rng, 20, 32)), _mixed(rng, 10, 16))
+        delta = _mixed(rng, 25, 64)
+        # most of the 2,048 slots are dead and resolve past their level
+        return lambda: cursor.join_ladder(delta, levels, 2, fn, 2048)
+    levels = _ladder(rng)
+    delta = _consolidated(rng, 24, 32)
+    if case == "gather_ladder":
+        return lambda: cursor.gather_ladder(
+            delta.keys, delta.weights != 0, levels, 1024,
+            sorted_queries=True)
+    if case == "range_gather_keys":
+        qhi = (delta.keys[0], delta.keys[1] + 5)
+        return lambda: cursor.gather_ladder(
+            delta.keys, delta.weights != 0, levels, 1024, qhi_keys=qhi,
+            gather_keys=1)
+    assert case == "lifted_per_worker"
+    from dbsp_tpu.parallel.exchange import spmd
+    from dbsp_tpu.parallel.mesh import make_mesh
+
+    def stack(batches):
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *batches)
+
+    w = 4
+    wlevels = tuple(stack([_consolidated(rng, max(2, c // 3), c)
+                           for _ in range(w)]) for c in (128, 32, 16))
+    wdelta = stack([_consolidated(rng, 20, 32) for _ in range(w)])
+    # a new SPMD callable each call: each dispatch traces its own program
+    return lambda: jax.jit(spmd(make_mesh(w), lambda d, lv: cursor.join_ladder(
+        d, lv, 2, fn, 512)))(wdelta, wlevels)
+
+
+FLAT_CASES = (*(f"select_k{k}" for k in range(1, 6)),
+              "join_empty_and_dead_levels", "gather_ladder",
+              "range_gather_keys", "lifted_per_worker")
+
+
+@pytest.mark.parametrize("case", FLAT_CASES)
+def test_flat_gather_equals_per_level_bit_for_bit(case, accelerator_dispatch,
+                                                  monkeypatch):
+    """Both accelerator formulations of ``_select_gather`` read the same
+    cell per slot, dead slots' clamped reads included: every output array,
+    sentinels and garbage too, is equal bit for bit and of one dtype."""
+    call = _select_case(int(case[-1])) if case.startswith("select_") \
+        else _ladder_case(case)
+    outs = {}
+    for flat in (False, True):
+        monkeypatch.setattr(kernels, "gather_flat",
+                            lambda *a, flat=flat: flat)
+        before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+        outs[flat] = [np.asarray(x) for x in jax.tree.leaves(call())]
+        took = {b for k, b in _took(before) if k == "gather"}
+        assert took == {"xla_flat" if flat else "xla"}, (case, took)
+    assert len(outs[True]) == len(outs[False])
+    for a, b in zip(outs[False], outs[True]):
+        assert a.dtype == b.dtype, case
+        np.testing.assert_array_equal(a, b, err_msg=case)
+
+
+def test_gather_rule_on_the_cells_shapes():
+    """What ``kernels.gather_flat`` takes at the shapes of the cells' step
+    programs (PERF.md 5)."""
+    flat = kernels.gather_flat
+    # q6's top-1: 262,144 slots from the joined bids' four levels
+    q6_levels = (4_194_304, 1_048_576, 262_144, 65_536)
+    assert flat(262_144, q6_levels, 6)
+    # q4's join: 131,072 slots from K = 6 levels of the auctions' trace
+    assert flat(131_072, (262_144, 131_072, 32_768, 4_096, 4_096, 4_096), 5)
+    # a few dozen lanes against levels of millions: one gather a level
+    assert not flat(64, q6_levels, 6)
+    # one level: the direct gather, whatever the width
+    assert not flat(262_144, (4_194_304,), 6)
+
+
+@pytest.mark.parametrize("flat", [True, False])
+def test_flat_gather_program_gathers_once_a_column(flat, monkeypatch,
+                                                   accelerator_dispatch):
+    """The flat form's program gathers each column once, from one
+    concatenation; the per-level form gathers each column once a level."""
+    monkeypatch.setattr(kernels, "gather_flat", lambda *a: flat)
+    rng = np.random.default_rng(60)
+    caps, ncols = (256, 64, 32, 16), 3
+    levels = [tuple(jnp.asarray(rng.integers(0, 9, c)) for _ in range(ncols))
+              for c in caps]
+    level = jnp.zeros(100, jnp.int32)
+    jaxpr = jax.make_jaxpr(cursor._select_gather)(levels, level, level).jaxpr
+    used = _primitives(jaxpr)
+    assert used["gather"] == ncols * (1 if flat else len(caps))
+    assert used["concatenate"] == (ncols if flat else 0)
 
 
 # ---------------------------------------------------------------------------

@@ -129,9 +129,20 @@ def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     took = {k for k, n in kernels.KERNEL_DISPATCH_COUNTS.items()
             if n > before.get(k, 0)}
     assert (took or name == "rank_sorted") and {b for _, b in took} <= {
-        "xla", "xla_bitonic", "xla_shift", "xla_merge"}, took
+        "xla", "xla_bitonic", "xla_shift", "xla_merge", "xla_flat"}, took
     if name == "join_ladder":  # a sorted delta of its levels' order: merged
         assert ("probe_ladder", "xla_merge") in took
+    if name in ("join_ladder", "gather_ladder"):
+        # 131,072 slots from three levels: gathered from the levels laid
+        # end to end, one gather a 32-bit half of each int64 column (the
+        # weights and the level's vals) over one materialized buffer; a
+        # K-way select of K reads fused back would gather three times as
+        # often
+        assert ("gather", "xla_flat") in took
+        words = {"join_ladder": 2 * 4, "gather_ladder": 2 * 2}[name]
+        gathers = [line for line in compiled.as_text().splitlines()
+                   if " gather(" in line and "k._select_gather/" in line]
+        assert len(gathers) == words, gathers
 
 
 def test_topk_rows_compiles_for_v5e_at_the_q6_cells_capacities(compile_for):
@@ -298,3 +309,40 @@ def test_maintenance_keeps_levels_on_their_workers(name, four_chips,
     for s in out:
         assert not s.is_fully_replicated, s
         assert s.is_equivalent_to(sharded, 2), s
+
+
+def test_flat_gather_stays_on_its_worker_on_four_chips(four_chips,
+                                                       no_persistent_cache,
+                                                       accelerator_dispatch):
+    """q4-4w's join lifted per worker (a bids delta's share against a
+    quarter of the auctions' trace): the levels are laid end to end on each
+    chip, and the SPMD program holds no collective at all."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dbsp_tpu.parallel import exchange
+
+    sharded = NamedSharding(four_chips, P("workers"))
+
+    def batch(cap, key_dts, val_dts):
+        def shape(dtype=I64):
+            return jax.ShapeDtypeStruct((WORKERS, cap), dtype,
+                                        sharding=sharded)
+
+        return Batch(tuple(shape(d) for d in key_dts),
+                     tuple(shape(d) for d in val_dts), shape(), runs=(cap,))
+
+    def fn(k, bv, av):
+        return (k[0], av[0]), (bv[1], bv[3], av[1], av[2])
+
+    share = CAP // WORKERS
+    before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+    text = jax.jit(exchange.spmd(four_chips, lambda d, lv: cursor.join_ladder(
+        d, lv, 1, fn, 2 * share))).lower(
+        batch(share, (I64,), (I64, I64, I32, I64)),
+        [batch(n, (I64,), (I64, I64, I64)) for n in (share, CAP, 4 * CAP)],
+    ).compile().as_text()
+    assert kernels.KERNEL_DISPATCH_COUNTS.get(("gather", "xla_flat"), 0) > \
+        before.get(("gather", "xla_flat"), 0)
+    for collective in ("all-to-all", "all-gather", "all-reduce",
+                       "collective-permute", "reduce-scatter"):
+        assert f" {collective}" not in text, collective

@@ -534,22 +534,24 @@ def test_accelerator_merge_under_shard_map(accelerator_dispatch):
 # prints in its ``kernel_dispatch`` line (PERF.md 3)
 # ---------------------------------------------------------------------------
 
-# every pair a q4 run on the chip has printed (my chip runs, PR 33; PR 37
-# for the probe's merge)
+# every pair a q4 run on the chip has printed (PERF.md 6)
 CHIP_PAIRS = {
     ("agg_ladder", "xla"), ("compact", "xla_shift"), ("consolidate", "xla"),
-    ("expand", "xla"), ("gather", "xla"), ("gather_ladder", "xla"),
+    ("expand", "xla"), ("gather", "xla"), ("gather", "xla_flat"),
+    ("gather_ladder", "xla"),
     ("join_ladder", "xla"), ("merge", "xla_bitonic"), ("probe", "xla"),
     ("probe_ladder", "xla"), ("probe_ladder", "xla_merge"),
     ("rank_fold", "xla"), ("segment_reduce", "xla"),
     ("sort_merge", "xla_bitonic")}
 
 _NET = {("consolidate", "xla"), ("compact", "xla_shift")}
-_CHAIN = {("probe_ladder", "xla"), ("expand", "xla"), ("gather", "xla")}
+# at these few rows 64 slots from two levels are cheaper gathered from the
+# levels laid end to end (kernels.gather_flat)
+_CHAIN = {("probe_ladder", "xla"), ("expand", "xla"), ("gather", "xla_flat")}
 # a consumer that states its queries are sorted: at these few rows every
 # level is cheaper by the merge (kernels.rank_by_merge)
 _CHAIN_SORTED = {("probe_ladder", "xla_merge"), ("expand", "xla"),
-                 ("gather", "xla")}
+                 ("gather", "xla_flat")}
 ACCELERATOR_PAIRS = {
     "consolidate_cols": _NET | {("sort_merge", "xla_bitonic")},
     "consolidate_cols_one_chunk": _NET,  # SORT_CHUNK_ROWS rows: lax.sort
@@ -562,6 +564,9 @@ ACCELERATOR_PAIRS = {
     "lex_probe_ladder": {("probe_ladder", "xla")},
     "join_ladder": _CHAIN_SORTED | {("join_ladder", "xla")},
     "gather_ladder": _CHAIN | {("gather_ladder", "xla")},
+    # 4 slots against a level of 60,000 rows: one gather a level
+    "gather_ladder_narrow": _CHAIN - {("gather", "xla_flat")} | {
+        ("gather", "xla"), ("gather_ladder", "xla")},
     "old_weights_ladder": {("old_weights", "xla"),
                            ("probe_ladder", "xla_merge")},
     "segment_reduce": {("segment_reduce", "xla")},
@@ -575,11 +580,12 @@ def _dispatch_call(name):
     from dbsp_tpu.operators.aggregate import Max, segment_reduce
     from dbsp_tpu.zset import cursor
 
-    def batch(keys, vals=()):
+    def batch(keys, vals=(), consolidated=False):
         keys = np.sort(np.asarray(keys, np.int64))
         return Batch.from_columns(
             [keys, keys % 3], [keys + v for v in vals],
-            np.ones(len(keys), np.int64), cap=2 * len(keys))
+            np.ones(len(keys), np.int64), cap=2 * len(keys),
+            consolidated=consolidated)
 
     delta, levels = batch(range(0, 20, 2), (1,)), [
         batch(range(40), (2,)), batch(range(5, 25), (3,))]
@@ -603,6 +609,9 @@ def _dispatch_call(name):
         "join_ladder": lambda: cursor.join_ladder(delta, levels, 2, fn, 64),
         "gather_ladder": lambda: cursor.gather_ladder(
             delta.keys, delta.weights != 0, levels, 64),
+        "gather_ladder_narrow": lambda: cursor.gather_ladder(
+            delta.keys, delta.weights != 0,
+            [levels[0], batch(range(30_000), (2,), consolidated=True)], 4),
         "old_weights_ladder": lambda: cursor.old_weights_ladder(
             delta, levels),
         "segment_reduce": lambda: segment_reduce(
