@@ -137,6 +137,8 @@ STATE_SCHEMA: Dict[str, Dict[str, str]] = {
         # every trace), the nodes of a windowed view (from the graph), and
         # the GC'd levels the last snapshot copied (a span's arg)
         "_observed": "derived",
+        # how a tick's record names each check (re-made by every trace)
+        "_check_names": "derived",
         "_windowed": "derived",
         "snapshot_gc_levels": "derived",
         "_req": "derived",

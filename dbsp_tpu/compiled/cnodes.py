@@ -430,6 +430,11 @@ class CNode:
 
     MONOTONE_CAPS: frozenset = frozenset()
 
+    # the capacities that size a state carried from tick to tick, which
+    # ``repad_state`` refits after a grow: a single-batch trace's buffer and
+    # (``sizes_state``) every level of a leveled trace
+    STATE_CAPS: Tuple[str, ...] = ("trace", "out_trace", "acc_trace", "state")
+
     def __init__(self, node, op):
         self.node = node
         self.op = op
@@ -461,11 +466,19 @@ class CNode:
     def init_state(self):
         return None
 
+    def sizes_state(self, key: str) -> bool:
+        """True where capacity ``key`` sizes state carried across ticks
+        (what :meth:`repad_state` refits); False where it sizes a buffer
+        the step program fills anew each tick (an input, a join's fan-out,
+        an aggregate's or top-K's queries and gather, a window's slides,
+        an exchange's buckets)."""
+        return key in self.STATE_CAPS or \
+            key in getattr(self, "level_keys", ())
+
     def repad_state(self, st):
         """Re-fit a snapshotted state to the CURRENT capacities (after a
         grow); default handles the single-Batch trace states."""
-        cap_key = next((k for k in ("trace", "out_trace", "acc_trace")
-                        if k in self.caps), None)
+        cap_key = next((k for k in self.caps if self.sizes_state(k)), None)
         if cap_key and isinstance(st, Batch) and st.cap != self.caps[cap_key]:
             return st.with_cap(self.caps[cap_key])
         return st

@@ -127,6 +127,12 @@ class _Ctx:
         self.obs.append(jnp.asarray(scalar, jnp.int64))
 
 
+def node_scope(cn: CNode) -> str:
+    """The scope that names every operation of a node in the lowered step
+    program and the device trace: ``n<index>.<CNode class>``."""
+    return f"n{cn.node.index}.{type(cn).__name__}"
+
+
 def _cnode_for(node) -> CNode:
     from dbsp_tpu.operators.aggregate import AggregateOp
     from dbsp_tpu.operators.aggregate_linear import LinearAggregateOp
@@ -383,6 +389,9 @@ class CompiledHandle:
         # what the nodes observe (``_Ctx.observe``): behind the
         # requirements in the same vector
         self._observed: List[Tuple[CNode, str]] = []
+        # per check, what a tick's record names it by: (node index, device
+        # scope, capacity key, class)
+        self._check_names: List[Tuple[int, str, str, str]] = []
         self._req = None          # device running-max of requirements
         self._max_jit = jax.jit(jnp.maximum)
         self.last_outputs: Dict[int, Batch] = {}
@@ -947,10 +956,7 @@ class CompiledHandle:
         for cn in self.cnodes:
             ins = [values[i] for i in cn.node.inputs]
             st = states.get(str(cn.node.index))
-            # the scope names every operation of this node in the lowered
-            # program and the device trace: n<index>.<CNode class>
-            with jax.named_scope(
-                    f"n{cn.node.index}.{type(cn).__name__}"):
+            with jax.named_scope(node_scope(cn)):
                 st2, out = cn.eval(ctx, st, ins)
             if st2 is not None:
                 new_states[str(cn.node.index)] = st2
@@ -976,6 +982,10 @@ class CompiledHandle:
         req = (jnp.stack(ctx.reqs + ctx.obs) if ctx.reqs or ctx.obs
                else jnp.zeros((0,), jnp.int64))
         self._checks = ctx.req_index  # same order every trace
+        self._check_names = [
+            (cn.node.index, node_scope(cn), key,
+             "state" if cn.sizes_state(key) else "tick")
+            for cn, key in ctx.req_index]
         self._observed = ctx.obs_index
         return new_states, ctx.outputs, req
 
@@ -1248,6 +1258,7 @@ class CompiledHandle:
         self._req = jnp.zeros_like(self._req)
         if items:
             raise CompiledOverflow(items)
+        checked = self._checked_caps(self.last_req.tolist())
         # what the nodes observed, a node at a time: only of an interval
         # that stands (an overflowed one is replayed and validated again)
         seen: Dict[CNode, Dict[str, int]] = {}
@@ -1255,9 +1266,19 @@ class CompiledHandle:
             seen.setdefault(cn, {})[key] = int(v)
         for cn, values in seen.items():
             cn.note_observations(values)
+        self._record_tick(checked)
         if any([cn.settle() for cn in self.cnodes]):
             self._step_jit = None  # provisional capacities were set
             self._scan_jits = {}
+
+    def _checked_caps(self, reqs: List[int]) -> List[Tuple]:
+        """``(node, scope, key, class, required, capacity)`` of every sized
+        check (``class``: ``state`` where the capacity sizes state carried
+        across ticks, ``CNode.sizes_state``, else ``tick``), as the
+        interval that stood was validated against it."""
+        return [(*name, r, cn.caps[key]) for (cn, key), name, r
+                in zip(self._checks, self._check_names, reqs)
+                if cn.caps[key]]
 
     def _req_value(self, cn: CNode, key: str) -> Optional[int]:
         """The last validated requirement for (cn, key), if any."""
@@ -1526,8 +1547,6 @@ class CompiledHandle:
         # re-heated (and anything newly over budget), promote re-hot
         # levels under headroom — every transition logged with its cause
         changed |= self._enforce_residency(cause="budget")
-        if self._observed:
-            self._record_tick()
         if stats["rows_moved"] > rows_before:
             self._note_cause("maintain")
         if changed:
@@ -1572,9 +1591,13 @@ class CompiledHandle:
             "topk_retracted_rows": sum(cn.observed["retracted"]
                                        for cn in tops)}
 
-    def _record_tick(self) -> None:
-        """One record of ``VALIDATED_TICKS`` for a circuit with time nodes
-        or top-K nodes: the facts of each kind it has."""
+    def _record_tick(self, caps: List[Tuple]) -> None:
+        """One record of ``VALIDATED_TICKS`` per interval that stood (none
+        for an interval that overflowed: its replay validates and records),
+        for every circuit: each checked capacity against what the interval
+        required of it (``caps``, from :meth:`_checked_caps`), and the facts
+        of the time and top-K nodes it has. Host bookkeeping over the
+        requirement vector validation fetched."""
         from dbsp_tpu.timeseries import counters
 
         record = self.time_facts()
@@ -1590,8 +1613,13 @@ class CompiledHandle:
                                          for g in gcd),
                 "trace_live_rows": sum(cn.live_rows for cn in traces)})
         record.update(self.topk_facts())
-        if record:
-            counters.VALIDATED_TICKS.append(record)
+        tick = [c for c in caps if c[3] == "tick"]
+        record.update(
+            capacities=[c[1:] for c in caps],
+            tick_live_rows=sum(c[4] for c in tick),
+            tick_capacity_rows=sum(c[5] for c in tick))
+        counters.VALIDATED_TICKS.append(record)
+        counters.note_capacities(caps)
 
     def _windows_filling(self) -> bool:
         """True while a window of this circuit's windowed view has yet to

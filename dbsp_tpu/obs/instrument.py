@@ -213,8 +213,8 @@ def export_topk_counters(registry: MetricsRegistry) -> None:
     validation): per ``CTopK`` the rows it re-read from its input trace
     (``dbsp_tpu_topk_gathered_rows_total{node}``), the groups it touched
     (``dbsp_tpu_topk_groups_total{node}``), the rows it inserted and
-    retracted (``dbsp_tpu_topk_changed_rows_total{node}``) and the
-    capacity of its gather (``dbsp_tpu_topk_gather_capacity_rows{node}``)."""
+    retracted (``dbsp_tpu_topk_changed_rows_total{node}``). The capacity
+    of its gather is ``export_capacities``' ``{node, kind="gather"}``."""
     if getattr(registry, "_topk_counters_exported", False):
         return
     registry._topk_counters_exported = True
@@ -229,10 +229,6 @@ def export_topk_counters(registry: MetricsRegistry) -> None:
         "dbsp_tpu_topk_changed_rows_total",
         "Rows a top-K node emitted, insertions and retractions",
         labels=("node",))
-    capacity = registry.gauge(
-        "dbsp_tpu_topk_gather_capacity_rows",
-        "Capacity of a top-K node's gather: the buffer its re-read "
-        "histories are sorted in every tick", labels=("node",))
 
     def _collect() -> None:
         from dbsp_tpu.timeseries import counters
@@ -241,7 +237,40 @@ def export_topk_counters(registry: MetricsRegistry) -> None:
             gathered.labels(node=str(node)).set_total(ent["gathered_total"])
             groups.labels(node=str(node)).set_total(ent["groups_total"])
             changed.labels(node=str(node)).set_total(ent["changed_total"])
-            capacity.labels(node=str(node)).set(ent["capacity"])
+
+    registry.register_collector(_collect)
+
+
+def export_capacities(registry: MetricsRegistry) -> None:
+    """Register a collector mirroring how full each checked capacity of a
+    compiled circuit was at its last validated interval
+    (``timeseries/counters.py`` ``CAPACITY_ROWS``): per node and capacity
+    key (``kind``, a closed set per node class) the static capacity
+    (``dbsp_tpu_capacity_rows``) and the interval's requirement
+    (``dbsp_tpu_capacity_required_rows``). A requirement near its capacity
+    is the next grow and replay; one far below it is padding every tick
+    carries."""
+    if getattr(registry, "_capacities_exported", False):
+        return
+    registry._capacities_exported = True
+    capacity = registry.gauge(
+        "dbsp_tpu_capacity_rows",
+        "Static capacity of a compiled node's buffer (kind = its capacity "
+        "key), rows a worker, at the last validated interval",
+        labels=("node", "kind"))
+    required = registry.gauge(
+        "dbsp_tpu_capacity_required_rows",
+        "Rows the last validated interval required of a compiled node's "
+        "capacity (the worst worker's); above the capacity it overflows "
+        "and replays", labels=("node", "kind"))
+
+    def _collect() -> None:
+        from dbsp_tpu.timeseries import counters
+
+        for node, kinds in list(counters.CAPACITY_ROWS.items()):
+            for kind, (rows, cap) in list(kinds.items()):
+                capacity.labels(node=str(node), kind=kind).set(cap)
+                required.labels(node=str(node), kind=kind).set(rows)
 
     registry.register_collector(_collect)
 
@@ -312,6 +341,7 @@ class CircuitInstrumentation:
         export_exchange_overflows(registry)
         export_time_counters(registry)
         export_topk_counters(registry)
+        export_capacities(registry)
         circuit.register_scheduler_event_handler(self._on_event)
         # mark exchange operators so they accumulate rows/bytes moved —
         # this costs one scalar device->host sync per exchange per tick
@@ -505,6 +535,7 @@ class CompiledInstrumentation:
         export_exchange_overflows(registry)
         export_time_counters(registry)
         export_topk_counters(registry)
+        export_capacities(registry)
         if spans is not None:
             driver.spans = spans  # driver records tick/validate spans
 

@@ -284,7 +284,7 @@ def test_counters_of_the_top_k_nodes():
                 f'{ent["groups_total"]}',
                 f'dbsp_tpu_topk_changed_rows_total{{node="{node}"}} '
                 f'{ent["changed_total"]}',
-                f'dbsp_tpu_topk_gather_capacity_rows{{node="{node}"}} '
+                f'dbsp_tpu_capacity_rows{{node="{node}",kind="gather"}} '
                 f'{ent["capacity"]}'):
             assert line in text, line
 
