@@ -882,8 +882,11 @@ class CLinearAggregate(CNode):
         cnt_delta = cnt_delta[..., :q_cap]
 
         # the consolidated accumulator trace holds one live row per key, so
-        # a q_cap expansion is exact — no requirement check needed
-        qrow, vals, w, _ = _gather_level_impl(qkeys, qlive, state, q_cap)
+        # a q_cap expansion is exact — no requirement check needed; the
+        # unique keys of a consolidated delta, front-packed, are sorted
+        qrow, vals, w, _ = _gather_level_impl(
+            qkeys, qlive, state, q_cap,
+            sorted_queries=delta.sorted_runs == 1)
         old = _net_state_impl(((qrow, vals, w),), q_cap)
         out, sdiff = _combine_diff_impl(qkeys, qlive, tuple(acc_delta),
                                         cnt_delta, *old, agg, nk)
@@ -976,7 +979,8 @@ class CTopK(CNode):
         # trace's (<= k live rows a key). Both widths are exact
         kq = self.op.k * q_cap
         new_cap, old_cap = min(kq, self.caps["gather"]), min(kq, state.cap)
-        o = _gather_level_impl(qkeys, qlive, state, old_cap)[:3]
+        o = _gather_level_impl(qkeys, qlive, state, old_cap,
+                               sorted_queries=delta.sorted_runs == 1)[:3]
         old_part = _topk_rows(o[0], qkeys, o[1], o[2], self.op.k,
                               self.op.largest, -1, q_cap)
         # each part is one sorted run (by query slot, so by key, then

@@ -87,8 +87,9 @@ def export_kernel_dispatch(registry: MetricsRegistry) -> None:
         "bitonic merge network behind kernel=merge and kernel=sort_merge, "
         "a sort of more than SORT_CHUNK_ROWS rows; xla_shift = the shift "
         "compaction behind kernel=compact; xla_merge = a level of "
-        "kernel=probe_ladder in which sorted queries were ranked by one "
-        "merge, its xla rows the levels searched; xla_flat = a "
+        "kernel=probe_ladder, or one side of a single-table kernel=probe, "
+        "in which sorted queries were ranked by one merge, its xla rows "
+        "the ones searched; xla_flat = a "
         "kernel=gather from the levels laid end to end, its xla rows one "
         "gather a level); the fused ladder-consumer "
         "megakernels report as kernel=join_ladder / gather_ladder / "

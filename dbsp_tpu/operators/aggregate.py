@@ -358,7 +358,8 @@ def _unique_keys(delta: Batch, nk: int):
 
 
 def _gather_level_impl(qkeys: Tuple[jnp.ndarray, ...], qlive: jnp.ndarray,
-                       level: Batch, out_cap: int):
+                       level: Batch, out_cap: int,
+                       sorted_queries: bool = False):
     """Expand one spine level's matching rows for the query keys.
 
     Returns (qrow ids, gathered val cols, weights, total). The output is
@@ -366,11 +367,15 @@ def _gather_level_impl(qkeys: Tuple[jnp.ndarray, ...], qlive: jnp.ndarray,
     rows keep the level's (key, vals) order; dead slots carry qrow ==
     q_cap (the trash segment) + sentinel vals, so they sort last. That
     ordering is what lets cross-level results combine with a rank-merge
-    instead of a sort."""
+    instead of a sort. ``sorted_queries`` states that ``qkeys`` are sorted
+    (the front-packed unique keys of a consolidated delta): both probes
+    may then rank by merge (:func:`kernels.lex_probe`)."""
     nk = len(qkeys)
     q_cap = qkeys[0].shape[0]
-    lo = kernels.lex_probe(level.keys[:nk], qkeys, side="left")
-    hi = kernels.lex_probe(level.keys[:nk], qkeys, side="right")
+    lo = kernels.lex_probe(level.keys[:nk], qkeys, side="left",
+                           sorted_queries=sorted_queries)
+    hi = kernels.lex_probe(level.keys[:nk], qkeys, side="right",
+                           sorted_queries=sorted_queries)
     lo = jnp.where(qlive, lo, 0)
     hi = jnp.where(qlive, hi, lo)
     row, src, valid, total = kernels.expand_ranges(lo, hi, out_cap)

@@ -96,7 +96,6 @@ def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
     own number of steps.
     """
     assert tables, "lex_probe_ladder: empty ladder"
-    m = query_cols[0].shape[-1]
     flat = query_cols[0].ndim == 1
     if flat:
         dts = [c.dtype for t in tables for c in t]
@@ -108,17 +107,9 @@ def lex_probe_ladder(tables: Sequence[Cols], query_cols: Cols,
                 kernels.count_kernel_dispatch("probe_ladder", "native")
                 return native_merge.lex_probe_ladder_native(
                     tables, query_cols, side)
-    may_merge = sorted_queries and flat and kernels.accelerator()
-    out = []
-    for t in tables:
-        if may_merge and kernels.rank_by_merge(m, t[0].shape[0],
-                                               len(query_cols)):
-            kernels.count_kernel_dispatch("probe_ladder", "xla_merge")
-            out.append(kernels.rank_sorted(t, query_cols, side))
-        else:
-            kernels.count_kernel_dispatch("probe_ladder", "xla")
-            out.append(kernels._probe_search(t, query_cols, side))
-    return jnp.stack(out)
+    return jnp.stack([kernels.rank_or_search(
+        t, query_cols, side, sorted_queries and flat, "probe_ladder")
+        for t in tables])
 
 
 # ---------------------------------------------------------------------------
@@ -488,9 +479,11 @@ def _agg_ladder_stitched(delta: Batch, nk: int, out_trace: Batch, levels,
     qlive = qlive_full[..., :q_cap]
 
     # previous outputs: the out trace holds one live row per present key,
-    # so a q_cap expansion is exact
+    # so a q_cap expansion is exact; the unique keys of a consolidated
+    # delta, front-packed, are sorted
+    claim = delta.sorted_runs == 1
     oqrow, ovals, ow, _ = A._gather_level_impl(qkeys, qlive, out_trace,
-                                               q_cap)
+                                               q_cap, sorted_queries=claim)
     old_vals, old_present = A._reduce_groups_impl(
         ((oqrow, ovals, ow),), A._TupleMax(len(agg.out_dtypes)), q_cap)
 
@@ -504,9 +497,8 @@ def _agg_ladder_stitched(delta: Batch, nk: int, out_trace: Batch, levels,
     else:
         d_vals, d_present = None, None  # general path never reads them
     mask = qlive & jnp.broadcast_to(flag, qlive.shape)
-    # the unique keys of a consolidated delta, front-packed: sorted
     part, gtot = gather_ladder(qkeys, mask, levels, gather_cap,
-                               sorted_queries=delta.sorted_runs == 1)
+                               sorted_queries=claim)
     lad_vals, lad_present = A._reduce_groups_impl(
         (part,), agg, q_cap, net=len(levels) > 1)
     return (qkeys, qlive, nq, old_vals, old_present, lad_vals, lad_present,

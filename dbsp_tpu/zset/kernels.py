@@ -78,8 +78,9 @@ def count_consolidate_path(path: str) -> None:
 # not the CPU's, its name: "xla_bitonic" (the merge network behind "merge"
 # and "sort_merge", a sort of more than SORT_CHUNK_ROWS rows) and
 # "xla_shift" (the shift compaction of "compact"), "xla_merge" (a level of
-# "probe_ladder" ranked by one merge; that kernel counts per LEVEL),
-# "xla_flat" (a "gather" from the levels laid end to end).
+# "probe_ladder" ranked by one merge, that kernel counting per LEVEL, or
+# one side of a single-table "probe" ranked so), "xla_flat" (a "gather"
+# from the levels laid end to end).
 # Same counting convention as CONSOLIDATE_COUNTS (eager calls per eval,
 # traced calls per trace); exported by obs as
 # ``dbsp_tpu_zset_kernel_dispatch_total{kernel,backend}`` and embedded in
@@ -719,7 +720,8 @@ def _lex_le_rows(table_cols, idx, query_cols, strict: bool):
 @_scoped
 def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
               query_cols: Tuple[jnp.ndarray, ...],
-              side: str = "left") -> jnp.ndarray:
+              side: str = "left", sorted_queries: bool = False
+              ) -> jnp.ndarray:
     """Delta-proportional searchsorted: O(m log n) vectorized binary search.
 
     The hot-path probe used by incremental operators to look a delta's keys up
@@ -728,10 +730,17 @@ def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
     (which sorts table+query together, O(n+m)), cost here scales with the
     *delta*, preserving DBSP's per-step cost model; the trace is only gathered
     at log2(n) probe indices per query. Unrolled loop — n is static under jit.
+
+    ``sorted_queries`` is the caller's statement that ``query_cols`` are
+    sorted (dead sentinel lanes at the tail). On an accelerator the same
+    insertion points then come by one merge (:func:`rank_sorted`) where
+    :func:`rank_by_merge` prices it cheaper for the two shapes — the rule
+    each level of ``cursor.lex_probe_ladder`` follows — counted as
+    ``probe/xla_merge``.
     """
     assert table_cols, "lex_probe requires at least one key column"
-    if table_cols[0].ndim == 1 and query_cols[0].ndim == 1 and \
-            native_kernel("probe"):
+    flat = table_cols[0].ndim == 1 and query_cols[0].ndim == 1
+    if flat and native_kernel("probe"):
         from dbsp_tpu.zset import native_merge
 
         if native_merge.supports(c.dtype for c in (*table_cols,
@@ -739,7 +748,23 @@ def lex_probe(table_cols: Tuple[jnp.ndarray, ...],
             count_kernel_dispatch("probe", "native")
             return native_merge.lex_probe_native(table_cols, query_cols,
                                                  side)
-    count_kernel_dispatch("probe", "xla")
+    return rank_or_search(table_cols, query_cols, side,
+                          sorted_queries and flat, "probe")
+
+
+def rank_or_search(table_cols, query_cols, side: str, sorted_queries: bool,
+                   kernel: str) -> jnp.ndarray:
+    """The insertion points by whichever XLA formulation is cheaper: one
+    merge (:func:`rank_sorted`) for queries the caller states are sorted,
+    on an accelerator, where :func:`rank_by_merge` prices it below the
+    search; the binary search (:func:`_probe_search`) else. Counted under
+    ``kernel`` as ``xla_merge`` or ``xla``."""
+    if sorted_queries and accelerator() and rank_by_merge(
+            query_cols[0].shape[0], table_cols[0].shape[0],
+            len(query_cols)):
+        count_kernel_dispatch(kernel, "xla_merge")
+        return rank_sorted(table_cols, query_cols, side)
+    count_kernel_dispatch(kernel, "xla")
     return _probe_search(table_cols, query_cols, side)
 
 

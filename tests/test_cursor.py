@@ -409,6 +409,114 @@ def test_ladder_steps_carry_their_scopes(accelerator_dispatch):
         assert scope in text, scope
 
 
+def _table(rng, n_live, cap, nk, key_range):
+    """A consolidated level of ``nk`` int64 keys and one value: few keys,
+    so a key holds several rows; a sentinel tail past the live rows."""
+    cols = [rng.integers(0, key_range, n_live) for _ in range(nk + 1)]
+    return Batch.from_columns([jnp.asarray(c) for c in cols[:nk]],
+                              [jnp.asarray(cols[nk])],
+                              jnp.asarray(rng.integers(1, 3, n_live)),
+                              cap=cap)
+
+
+# (nk, table live rows, table capacity, query capacity, the rule's choice)
+GATHER_LEVEL_CASES = {
+    "nk1_merge": (1, 90, 128, 64, True),
+    "nk2_merge": (2, 90, 128, 64, True),
+    "nk1_search": (1, 3_000, 1 << 15, 64, False),
+    "nk2_search": (2, 3_000, 1 << 15, 64, False),
+    "empty_table_merge": (2, 0, 128, 64, True),
+}
+
+
+@pytest.mark.parametrize("case", GATHER_LEVEL_CASES)
+def test_gather_level_claim_of_sorted_queries_holds(case,
+                                                    accelerator_dispatch):
+    """``_gather_level_impl`` told its queries are sorted gives what the
+    search gives, lane for lane: the front-packed unique keys of a
+    consolidated delta, some absent from the table, dead sentinel lanes
+    behind them, against a level whose keys repeat and whose tail is dead.
+    Each side takes the formulation ``rank_by_merge`` prices cheaper."""
+    from dbsp_tpu.operators.aggregate import (_gather_level_impl,
+                                              _unique_keys_impl)
+
+    nk, n_live, cap, m, merges = GATHER_LEVEL_CASES[case]
+    assert kernels.rank_by_merge(m, cap, nk) == merges
+    rng = np.random.default_rng(cap + n_live + nk)
+    key_range = 12 if cap < 1024 else 400
+    level = _table(rng, n_live, cap, nk, key_range)
+    # queries over twice the table's key range: about half are absent
+    delta = _table(rng, 40, m, nk, 2 * key_range)
+    qkeys, qlive = _unique_keys_impl(delta, nk)
+    assert 0 < int(jnp.sum(qlive)) < m  # live lanes, then dead ones
+    outs = {}
+    for claim in (True, False):
+        before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+        outs[claim] = [np.asarray(x) for x in jax.tree.leaves(
+            _gather_level_impl(qkeys, qlive, level, 4 * cap,
+                               sorted_queries=claim))]
+        counts = {k: v - before.get(k, 0)
+                  for k, v in kernels.KERNEL_DISPATCH_COUNTS.items()
+                  if k[0] == "probe" and v != before.get(k, 0)}
+        took = "xla_merge" if claim and merges else "xla"
+        assert counts == {("probe", took): 2}, (case, claim, counts)
+    for a, b in zip(outs[True], outs[False]):
+        np.testing.assert_array_equal(a, b, err_msg=case)
+    assert int(outs[True][-1]) > 0 or n_live == 0  # rows were gathered
+
+
+@pytest.mark.parametrize("op", ["linear_count", "max", "topk"])
+def test_compiled_aggregate_equals_its_search_form(op, accelerator_dispatch,
+                                                   monkeypatch):
+    """A compiled ``CLinearAggregate`` (a count), ``CAggregate`` (a max)
+    and ``CTopK`` (a top-2) over a stream with retractions give, tick for
+    tick, what they give with every single-level probe searched: the form
+    before their sorted queries were stated."""
+    from dbsp_tpu.circuit import Runtime
+    from dbsp_tpu.compiled.driver import CompiledCircuitDriver
+    from dbsp_tpu.operators import LinearCount, Max, add_input_zset
+
+    def build(c):
+        s, h = add_input_zset(c, (jnp.int64, jnp.int64), (jnp.int64,))
+        view = {"linear_count": lambda: s.aggregate(LinearCount()),
+                "max": lambda: s.aggregate(Max(0)),
+                "topk": lambda: s.topk(2)}[op]()
+        return h, view.output()
+
+    rng = np.random.default_rng(11)
+    ticks, live = [], []
+    for n in (90, 15, 15):
+        rows = [((int(rng.integers(0, 9)), int(rng.integers(0, 3)),
+                  int(rng.integers(0, 50))), 1) for _ in range(n)]
+        gone = [live[i] for i in rng.choice(len(live), min(20, len(live)),
+                                            replace=False)] if live else []
+        live = [r for r in live if r not in gone] + [r for r, _ in rows]
+        ticks.append(rows + [(r, -1) for r in gone])
+
+    def run():
+        handle, (h, out) = Runtime.init_circuit(1, build)
+        driver = CompiledCircuitDriver(handle)
+        before = dict(kernels.KERNEL_DISPATCH_COUNTS)
+        seen = []
+        for rows in ticks:
+            h.extend(rows)
+            driver.step()
+            seen.append(out.to_dict())
+        return seen, ("probe", "xla_merge") in _took(before)
+
+    claimed, merged = run()
+    assert merged
+    lex_probe = kernels.lex_probe
+    monkeypatch.setattr(kernels, "lex_probe",
+                        lambda t, q, side="left", sorted_queries=False:
+                        lex_probe(t, q, side))
+    searched, merged = run()
+    assert not merged
+    assert claimed == searched
+    # every tick changed the view, and the later ones retracted rows
+    assert all(claimed) and all(min(d.values()) < 0 for d in claimed[1:])
+
+
 def test_range_gather_never_ranks_by_merge(accelerator_dispatch):
     rng = np.random.default_rng(8)
     levels = _ladder(rng)

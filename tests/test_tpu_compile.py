@@ -103,6 +103,15 @@ def _case(name, c):
     if name == "rank_sorted":  # a bids delta's keys in a 262,144-row level
         return (lambda t, q: kernels.rank_sorted(t, q, "right")), (
             (c.shape(4 * CAP),), (c.shape(CAP),))
+    if name == "rank_q5_count":
+        # q5's Count per (window, auction): its 131,072 sorted unique keys
+        # in its 1,048,576-row accumulator, both ends of each key's range
+        # (aggregate._gather_level_impl, stated sorted)
+        def probes(t, q):
+            return tuple(kernels.lex_probe(t, q, side, sorted_queries=True)
+                         for side in ("left", "right"))
+        return probes, ((c.shape(16 * CAP), c.shape(16 * CAP)),
+                        (c.shape(2 * CAP), c.shape(2 * CAP)))
     if name == "join_ladder":  # q4-join: bids delta x auctions trace
         def fn(k, bv, av):
             return (k[0], av[0]), (bv[1], bv[3], av[1], av[2])
@@ -119,8 +128,8 @@ def _case(name, c):
 
 @pytest.mark.parametrize("name", [
     "consolidate", "consolidate_block", "consolidate_drain", "merge_sorted",
-    "merge_sorted_drain", "lex_probe", "rank_sorted", "join_ladder",
-    "gather_ladder"])
+    "merge_sorted_drain", "lex_probe", "rank_sorted", "rank_q5_count",
+    "join_ladder", "gather_ladder"])
 def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
     fn, args = _case(name, compile_for)
     before = dict(kernels.KERNEL_DISPATCH_COUNTS)
@@ -132,6 +141,8 @@ def test_plain_xla_kernel_compiles_for_v5e(name, compile_for):
         "xla", "xla_bitonic", "xla_shift", "xla_merge", "xla_flat"}, took
     if name == "join_ladder":  # a sorted delta of its levels' order: merged
         assert ("probe_ladder", "xla_merge") in took
+    if name == "rank_q5_count":  # both sides by the merge, none searched
+        assert took == {("probe", "xla_merge")}, took
     if name in ("join_ladder", "gather_ladder"):
         # 131,072 slots from three levels: gathered from the levels laid
         # end to end, one gather a 32-bit half of each int64 column (the
@@ -177,8 +188,8 @@ def _sort_of(n):
 
 
 @pytest.mark.parametrize("name", ["merge_sorted", "merge_sorted_drain",
-                                  "rank_sorted", "consolidate",
-                                  "consolidate_drain"])
+                                  "rank_sorted", "rank_q5_count",
+                                  "consolidate", "consolidate_drain"])
 def test_merges_of_sorted_runs_gather_nothing_on_tpu(name, compile_for):
     """Off the CPU a merge of sorted runs, the merge levels of a large
     sort, and the netting and compaction behind both are elementwise

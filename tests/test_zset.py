@@ -540,9 +540,9 @@ CHIP_PAIRS = {
     ("expand", "xla"), ("gather", "xla"), ("gather", "xla_flat"),
     ("gather_ladder", "xla"),
     ("join_ladder", "xla"), ("merge", "xla_bitonic"), ("probe", "xla"),
-    ("probe_ladder", "xla"), ("probe_ladder", "xla_merge"),
-    ("rank_fold", "xla"), ("segment_reduce", "xla"),
-    ("sort_merge", "xla_bitonic")}
+    ("probe", "xla_merge"), ("probe_ladder", "xla"),
+    ("probe_ladder", "xla_merge"), ("rank_fold", "xla"),
+    ("segment_reduce", "xla"), ("sort_merge", "xla_bitonic")}
 
 _NET = {("consolidate", "xla"), ("compact", "xla_shift")}
 # at these few rows 64 slots from two levels are cheaper gathered from the
@@ -570,9 +570,10 @@ ACCELERATOR_PAIRS = {
     "old_weights_ladder": {("old_weights", "xla"),
                            ("probe_ladder", "xla_merge")},
     "segment_reduce": {("segment_reduce", "xla")},
+    # the out trace's probe of the delta's sorted unique keys: the merge
     "agg_ladder": _CHAIN_SORTED | _NET | {
-        ("agg_ladder", "xla"), ("gather_ladder", "xla"), ("probe", "xla"),
-        ("segment_reduce", "xla")},
+        ("agg_ladder", "xla"), ("gather_ladder", "xla"),
+        ("probe", "xla_merge"), ("segment_reduce", "xla")},
 }
 
 
